@@ -24,6 +24,10 @@ import numpy as np
 from jax.sharding import Mesh
 
 
+# the checkout root (the directory holding the ``bigdl_tpu`` package)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def _default_retry_times() -> int:
     from bigdl_tpu.utils.config import get_config
     return get_config().failure_retry_times
@@ -76,6 +80,28 @@ class Engine:
     @classmethod
     def is_initialized(cls) -> bool:
         return cls._state.initialized
+
+    @staticmethod
+    def enable_compile_cache() -> str:
+        """Turn on JAX's persistent compilation cache for this process
+        and return its directory — the ONE place the repo decides where
+        compiled programs are kept (entry scripts call this before
+        their first jit; the test suite never does).
+
+        Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+        it and nothing else is set here, so the cache can be placed
+        from outside.  Unset, the cache lives at the fixed
+        ``<checkout>/.jax_cache``: the path is part of the cache key's
+        world, so a directory that moves (tempfile, pid, timestamp)
+        never hits.  The minimum compile time is dropped to zero (the
+        entry-size floor already is) so every program is stored — the
+        minutes-long ResNet-50 step and the sub-second serving bucket
+        executables alike."""
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir",
+                              os.path.join(_CHECKOUT, ".jax_cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        return jax.config.jax_compilation_cache_dir
 
     @classmethod
     def reset(cls) -> None:
@@ -227,95 +253,23 @@ class Engine:
     )
 
     @classmethod
-    def set_xla_async_collectives(cls, enable: bool = True,
-                                  force: bool = False) -> None:
+    def set_xla_async_collectives(cls, enable: bool = True) -> None:
         """Arm (or disarm) XLA's async-collective / latency-hiding
         scheduler flags via ``XLA_FLAGS``.  Call BEFORE the first jax
-        computation — XLA reads the env once at backend init.
+        computation — XLA reads the env once at backend init, so a
+        later call only reaches processes started afterwards.
 
-        The flags are TPU-build flags, and XLA ABORTS the whole process
-        at backend init on flags its build doesn't know ("Unknown flags
-        in XLA_FLAGS") — so before committing them to the environment
-        this PROBES a throwaway subprocess with the new env; if that
-        child cannot initialize jax, the intent is recorded
-        (:meth:`xla_async_collectives`) but the env is left alone.
-        Once this process's backend is live the probe is no longer
-        trustworthy either (on a single-tenant TPU the child cannot
-        acquire the chip the parent holds and would read as a bogus
-        refusal), so a late call refuses with that diagnosis.
-        ``force=True`` writes the flags with no probe in both cases
-        (images known to accept them, or tests exercising the
-        plumbing); after backend init they then apply to child
-        processes only."""
+        No acceptance probe: these are TPU-build flags, and a jaxlib
+        whose backend does not know them aborts the process at backend
+        init ("Unknown flags in XLA_FLAGS") — loudly, at start, which
+        is the wanted failure.  (Probing in a child process would need
+        the child to take the chip this process is about to use.)"""
         cls._state.xla_async_collectives = bool(enable)
         flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
                  if f.split("=")[0] not in cls._ASYNC_COLLECTIVE_FLAGS]
         val = "true" if enable else "false"
         flags += [f"{f}={val}" for f in cls._ASYNC_COLLECTIVE_FLAGS]
-        new_flags = " ".join(flags)
-        import logging
-        log = logging.getLogger("bigdl_tpu.engine")
-        if os.environ.get("XLA_FLAGS", "") == new_flags:
-            return  # already committed — nothing to probe or rewrite
-        if not force:
-            if cls._backend_live():
-                log.warning(
-                    "set_xla_async_collectives(%s) after backend init: "
-                    "cannot probe flag acceptance safely (a TPU probe "
-                    "child would fight this process for the chip) nor "
-                    "retrofit the live backend — intent recorded, "
-                    "XLA_FLAGS untouched.  Call before the first jax "
-                    "computation, or force=True to write the flags for "
-                    "child processes only", enable)
-                return
-            if not cls._xla_flags_survive(new_flags):
-                log.warning(
-                    "set_xla_async_collectives(%s): this jaxlib fatally "
-                    "rejects the async-collective flags — intent "
-                    "recorded, XLA_FLAGS untouched (force=True "
-                    "overrides)", enable)
-                return
-        os.environ["XLA_FLAGS"] = new_flags
-        if cls._backend_live():
-            log.warning(
-                "set_xla_async_collectives(%s) after backend init: flags "
-                "apply to child processes only (XLA reads XLA_FLAGS once)",
-                enable)
-
-    @staticmethod
-    def _backend_live() -> bool:
-        """Whether this process's jax backend has already initialized
-        (and therefore already consumed ``XLA_FLAGS``)."""
-        try:
-            from jax._src import xla_bridge
-            return bool(getattr(xla_bridge, "_backends", None))
-        except Exception:  # pragma: no cover - jax internals moved
-            return False
-
-    @staticmethod
-    def _xla_flags_survive(xla_flags: str) -> bool:
-        """Probe whether a jax process on this machine survives the
-        given ``XLA_FLAGS`` (XLA's flag parser aborts the PROCESS on
-        unknown flags, so this cannot be tested in-process)."""
-        import subprocess
-        import sys
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = xla_flags
-        # the probe inherits the DEFAULT backend choice: the known-flag
-        # registry is per backend binary (libtpu knows --xla_tpu_*
-        # flags, a CPU-only jaxlib does not), so a CPU-pinned child
-        # would reject flags the real target accepts.  Tradeoff: on a
-        # single-tenant TPU the child must be able to acquire the chip,
-        # which is why this surface is documented as
-        # call-before-the-first-jax-computation; a child that cannot
-        # init reads as "refuse" (safe: flags just stay unset).
-        try:
-            r = subprocess.run(
-                [sys.executable, "-c", "import jax; jax.devices()"],
-                env=env, capture_output=True, timeout=300)
-        except Exception:  # pragma: no cover - probe infrastructure
-            return False
-        return r.returncode == 0
+        os.environ["XLA_FLAGS"] = " ".join(flags)
 
     @classmethod
     def xla_async_collectives(cls) -> Optional[bool]:

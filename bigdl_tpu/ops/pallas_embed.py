@@ -10,8 +10,10 @@ This kernel runs gather + scale + segment-accumulate in ONE pass: the
 output accumulator lives in VMEM for the whole kernel, table rows are
 double-buffered per-row async DMAs from HBM, and the per-chunk
 row/col/value streams ride SMEM block specs — the ``(nnz, D)``
-intermediate never exists.  HBM traffic per step drops to the gathered
-table rows + the flat index/value streams + one output write.
+intermediate never exists.  A table narrower than one lane group is
+zero-padded to 128 lanes by the wrapper first (one extra ``(V, 128)``
+HBM write per call): Mosaic refuses a DMA whose lane extent is not
+tile-aligned.
 
 Accumulation is f32 in VMEM regardless of operand dtype; the output is
 cast to the same promoted dtype the XLA path produces.  Because the
@@ -51,34 +53,42 @@ from bigdl_tpu.ops.pallas_util import (interpret_default as
                                        _interpret_default,
                                        lane_pad as _lane_pad)
 
-# nnz entries processed per grid step; the SMEM footprint per step is
-# 3 streams x _CHUNK x 4 B = 3 KB (SMEM is small — never block a whole
-# nnz stream into it, PALLAS_NOTES.md)
-_CHUNK = 256
+# nnz entries processed per grid step.  1024 is not a tuning choice:
+# XLA lays a 1-D s32/f32 operand out in T(1024) tiles and Mosaic
+# verifies each SMEM block against that layout — a (256,) block is
+# refused ("XLA layout ({0:T(1024)}) does not match Mosaic layout
+# ({0:T(256)})", PALLAS_NOTES.md).  The wrapper pads every stream to a
+# multiple of this, so the operands are always whole T(1024) tiles.
+# SMEM footprint per step: 3 streams x 1024 x 4 B = 12 KB (SMEM is
+# small — never block a whole nnz stream into it).
+_CHUNK = 1024
 
-# VMEM element budget for the resident (n_rows, lane-padded D) output
-# accumulator: the census Wide&Deep wide path (8192 x pad(1)=128 =
-# 1.05M elements, 4.2 MB f32) must pass with headroom for the DMA
-# buffers; bigger outputs silently keep the XLA segment-sum.
-# PROVISIONAL pending on-chip validation (carried measurement debt,
-# ROADMAP item 2a): pallas_pool's 410K compile-abort budget was
-# measured on 5-D spatial blocks, not a flat 2-D accumulator — and the
-# D=1 wide path's padded count is tile padding, not live data (8192
-# rows x 128 lanes = 4.2 MB physical, far under VMEM).  If on-chip
-# Mosaic balks, lowering THIS constant is the one-line fix the
-# supported() gate makes safe (oversize sites fall back to XLA).
+# VMEM element budget for the resident (n_rows, lane-padded D) f32
+# output accumulator: the census Wide&Deep wide path (8192 x pad(1)=128
+# = 1.05M elements, 4.2 MB) must pass; bigger outputs keep the XLA
+# segment-sum.  What the v5e compiler says (libtpu 0.0.34, described
+# v5e:2x2, PR 21): a 2,560,000-element accumulator (10.2 MB) compiles,
+# a 4,194,304-element one (16.8 MB) is refused with RESOURCE_EXHAUSTED
+# "Ran out of memory in memory space vmem" — the bound is the 16 MiB
+# scoped-VMEM default in BYTES, not pallas_pool's element-count abort.
+# The gate sits at half the largest size seen compiling.
 _OUT_ELEMENT_BUDGET = 1_300_000
 
 
 def supported(nnz: int, n_rows: int, table_shape, dtype) -> bool:
     """Whether the fused bag covers this (nnz, N, table, dtype) config.
 
-    Static and conservative: f32/bf16 tables, feature dim either
-    lane-aligned or within one lane group (narrow-D rows ride the DMA
-    path, which is byte- not lane-granular), and the VMEM output
-    accumulator within the element budget."""
-    if np.dtype(dtype) not in (np.dtype(jnp.float32),
-                               np.dtype(jnp.bfloat16)):
+    Static and conservative: f32 tables only, feature dim either
+    lane-aligned or within one lane group (the wrapper pads it to 128
+    lanes), and the VMEM output accumulator within the element budget.
+
+    bf16 tables are refused: XLA tiles a bf16 operand (8,128)(2,1) —
+    two rows packed per sublane — and Mosaic cannot slice ONE row out
+    of a packed pair for the gather DMA ("Slice shape along dimension 0
+    must be aligned to tiling (8), but is 1"; as (V, 1, D):
+    "... dimension 1 must be aligned to tiling (2), but is 1"), so a
+    bf16 table takes the XLA chain (PALLAS_NOTES.md)."""
+    if np.dtype(dtype) != np.dtype(jnp.float32):
         return False
     if nnz < 1 or n_rows < 1:
         return False
@@ -99,10 +109,11 @@ def _bag_kernel(rows_ref, cols_ref, vals_ref, table_ref, out_ref, buf,
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     def dma(slot, j):
-        # one table row HBM -> VMEM; byte-granular, so any D is legal
+        # one lane-padded table row HBM -> VMEM: the wrapper pads D to
+        # a 128 multiple because Mosaic refuses a DMA slice whose lane
+        # extent is not tile-aligned (PALLAS_NOTES.md)
         return pltpu.make_async_copy(
-            table_ref.at[pl.ds(cols_ref[j], 1), :],
-            buf.at[slot], sem.at[slot])
+            table_ref.at[cols_ref[j]], buf.at[slot], sem.at[slot])
 
     dma(0, 0).start()
 
@@ -117,7 +128,7 @@ def _bag_kernel(rows_ref, cols_ref, vals_ref, table_ref, out_ref, buf,
         dma(slot, j).wait()
         r = rows_ref[j]
         contrib = vals_ref[j] * buf[slot].astype(jnp.float32)
-        # read-modify-write on an unstrided (1, D) sub-range — the
+        # read-modify-write on an unstrided (1, Dp) sub-range — the
         # Mosaic-legal accumulate (no scatter-add primitive)
         out_ref[pl.ds(r, 1), :] = out_ref[pl.ds(r, 1), :] + contrib
         return 0
@@ -146,26 +157,35 @@ def _bag_fn(n_rows: int, interpret: bool):
             cols = jnp.pad(cols, (0, pad))
             values = jnp.pad(values, (0, pad))
         D = table.shape[1]
+        Dp = _lane_pad(D)
+        if Dp != D:
+            # zero lanes: they gather zeros and are sliced off below
+            table = jnp.pad(table, ((0, 0), (0, Dp - D)))
+        # (V, 1, Dp): the gathered row is then a slice along an UNTILED
+        # leading dim — a (1, Dp) row of a 2-D (V, Dp) operand is only
+        # sliceable when XLA happens to pick a (1, 128) HBM tiling
+        # (f32, Dp == 128); bf16 and wider tables get (8, 128) tiles
+        # and Mosaic refuses the 1-row slice (PALLAS_NOTES.md)
+        table = table.reshape(table.shape[0], 1, Dp)
         grid = (rows.shape[0] // _CHUNK,)
         kern = functools.partial(_bag_kernel, chunk=_CHUNK)
+        stream = pl.BlockSpec((_CHUNK,), lambda i: (i,),
+                              memory_space=pltpu.SMEM)
         out = pl.pallas_call(
             kern,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((_CHUNK,), lambda i: (i,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((_CHUNK,), lambda i: (i,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((_CHUNK,), lambda i: (i,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # table stays HBM
+                stream, stream, stream,
+                pl.BlockSpec(memory_space=pl.ANY),  # table stays HBM
             ],
-            out_specs=pl.BlockSpec((n_rows, D), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((n_rows, D), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((2, 1, D), table.dtype),
+            out_specs=pl.BlockSpec((n_rows, Dp), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((n_rows, Dp), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, 1, Dp), table.dtype),
                             pltpu.SemaphoreType.DMA((2,))],
             interpret=interpret,
         )(rows, cols, values, table)
+        if Dp != D:
+            out = out[:, :D]
         return out.astype(out_dtype)
 
     def _fwd(rows, cols, values, table):

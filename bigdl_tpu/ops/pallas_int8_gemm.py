@@ -61,12 +61,16 @@ from bigdl_tpu.ops.pallas_util import (interpret_default as
 
 # VMEM element budget for the resident int8 weight panel (K x O int8 =
 # 1 byte/element, vs 4 for pallas_lstm's f32 panel).  6M elements = 6 MB
-# of the ~16 MB/core VMEM, leaving room for the <=128-row activation and
-# f32 output blocks (128 x (K + O) elements at the gated sizes).
-# PROVISIONAL pending on-chip validation, same provenance trail as
-# pallas_lstm._W_ELEMENT_BUDGET: lowering this constant is the one-line
-# fix the supported() gate makes safe (oversize panels fall back to the
-# bitwise-identical XLA path).  Documented in ops/PALLAS_NOTES.md §int8.
+# of the 16 MiB scoped VMEM, leaving room for the <=128-row activation
+# and f32 output blocks (128 x (K + O) elements at the gated sizes).
+# What the chip said (PR 21): a 2048x2048 panel (4.19M elements)
+# compiles for v5e in both modes at 256 rows
+# (tests/test_chip_compile.py), and weight_only ran on a v5e at row
+# buckets 1..64 inside the serving executables, within the documented
+# int8 bar of the f32 twin (chip_smoke.py).  Nothing between 4.19M and
+# the gate has been compiled or run; an oversize panel takes the
+# bitwise-identical XLA path through supported().  Notes:
+# ops/PALLAS_NOTES.md §int8.
 _W_ELEMENT_BUDGET_INT8 = 6_000_000
 
 MODES = ("weight_only", "dynamic")
@@ -93,7 +97,7 @@ def supported(batch: int, in_features: int, out_features: int, x_dtype,
     output dims so the pallas path stays bitwise-identical to the XLA
     fallback (module docstring); odd shapes silently keep the XLA
     quantized chain.  f32/bf16 activations only, and the int8 weight
-    panel must fit the PROVISIONAL VMEM element budget."""
+    panel must fit the VMEM element budget."""
     if mode not in MODES:
         return False
     if np.dtype(x_dtype) not in (np.dtype(jnp.float32),

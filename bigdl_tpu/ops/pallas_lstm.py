@@ -51,15 +51,15 @@ from bigdl_tpu.ops.pallas_util import (interpret_default as
 
 # VMEM element budget for the resident recurrent weight panel
 # (H_pad x 4*H_pad).  PTB-medium (H=650 -> 768x3072 = 2.36M elements,
-# 9.4 MB f32) must pass; 16 MB/core VMEM also holds the per-block
-# activations, so gate with headroom below the next power step
-# (H=1024 -> 4.2M elements falls back to XLA).  PROVISIONAL pending
-# on-chip validation (the carried measurement debt, ROADMAP item 2a):
-# pallas_pool's measured 410K compile-abort budget was taken on its
-# 5-D spatial blocks, and whether Mosaic treats a flat 2-D matmul
-# panel the same is exactly what the on-chip round must answer — if it
-# balks, lowering THIS constant is the one-line fix the supported()
-# gate exists to make safe (oversize sites just fall back to XLA).
+# 9.4 MB f32) must pass; the 16 MiB scoped VMEM also holds the
+# per-block activations, so gate with headroom below the next power
+# step (H=1024 -> 4.2M elements falls back to XLA).  What the chip
+# said (PR 21): the 2.36M-element panel compiles for v5e in f32 and
+# bf16, forward and backward (tests/test_chip_compile.py), and the f32
+# forward + backward ran on a v5e inside the PTB-medium train step with
+# losses within 3e-7 of the XLA chain (chip_smoke.py).  Nothing between
+# 2.36M and the gate has been compiled or run; an oversize site takes
+# the XLA chain through supported(), never an exception handler.
 _W_ELEMENT_BUDGET = 3_000_000
 
 

@@ -111,25 +111,27 @@ def _lane_pad(C: int) -> int:
 def supported(x_shape, kernel, stride, pads):
     """Whether the pallas backward covers this pooling config.
 
-    Besides the structural conditions, a per-block ELEMENT budget gate:
-    the pinned toolchain's Mosaic aborts compilation (compile-helper
-    exit 1, no diagnostic) for the large-spatial blocks an earlier
-    toolchain accepted — re-verify per bump, keyed to the bench
-    ``toolchain`` stamp.  The limit is element count, not bytes —
-    measured on v5e: 802,816-element blocks fail in BOTH f32 (112²×64,
-    56²×192) and bf16 (112²×64, i.e. half the bytes), while
-    401,408-element blocks (28²×480-pad-512, 56²×128) compile in both
-    dtypes — consistent with bf16's (2,1) sublane packing keeping vreg
-    footprint proportional to elements (the canonical budget note
-    lives in ops/PALLAS_NOTES.md).  Gate at 410,000 elements (just
-    above the largest measured-good block) so bigger sites silently
-    take the documented reduce_window fallback instead of a runtime
-    compile error."""
+    Besides the structural conditions, a per-block ELEMENT budget gate.
+    Its history: an earlier toolchain's Mosaic aborted compilation
+    (compile-helper exit 1, no diagnostic) on 802,816-element blocks in
+    both f32 and bf16, and the gate sat at 410,000.  Asked again for a
+    described v5e:2x2 under jax 0.9.0 / libtpu 0.0.34 (PR 21,
+    ``tests/test_chip_compile.py``) that abort is gone: blocks of
+    401,408 (56²×128), 802,816 (112²×64, 56²×256, 28²×1024) and
+    1,605,632 elements (112²×128, 56²×512) compile in f32 and bf16;
+    at 3,211,264 elements 112²×256 compiles but 224²×64 is refused —
+    ``RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem`` —
+    so past that point the answer depends on shape, not count.  Gate
+    just above the largest count that compiled in every shape tried;
+    bigger sites take the documented reduce_window fallback instead of
+    a compile error.  Compile-only evidence: no block size has RUN on
+    this toolchain's chip (the kernel is opt-in, off the main path).
+    Re-ask per toolchain bump (canonical note: ops/PALLAS_NOTES.md)."""
     _, H, W, C = x_shape
     (kh, kw), (sh, sw) = kernel, stride
     if not (H % sh == 0 and W % sw == 0 and kh >= sh and kw >= sw):
         return False
-    return H * W * _lane_pad(C) <= 410_000
+    return H * W * _lane_pad(C) <= 1_700_000
 
 
 def maxpool_bwd_nhwc(x, y, g, kernel, stride, pads):

@@ -413,8 +413,8 @@ class DistriOptimizer(Optimizer):
             out_specs = (tmap(lambda _: P(), params),
                          tmap(lambda _: P(), mstate),
                          os_spec, P())
-            fn = grad_sync.shard_map_compat(body, mesh, in_specs,
-                                            out_specs)
+            fn = grad_sync.shard_map_unchecked(body, mesh, in_specs,
+                                               out_specs)
             return fn(params, mstate, ostate, xs, ys, lrs, steps, rngs)
 
         return block_fn

@@ -59,7 +59,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
 from bigdl_tpu.utils.precision import stochastic_round
 
@@ -373,15 +373,9 @@ def sync_model_state(mstate, axis_name: str):
         mstate)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions, with replication checking off
-    (grad_sync outputs are replicated by construction — psum/pmean/
-    all-gather — which the static checker cannot always prove)."""
-    try:
-        from jax import shard_map  # jax >= 0.8 (check_rep renamed)
-        kw = {"check_vma": False}
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``shard_map`` with replication checking off (grad_sync outputs
+    are replicated by construction — psum/pmean/all-gather — which the
+    static checker cannot always prove)."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
+                     check_vma=False)

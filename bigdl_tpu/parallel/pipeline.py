@@ -34,7 +34,7 @@ from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.nn.module import Module, Sequential
@@ -200,12 +200,6 @@ class GPipe(Module):
                 if has_state else {}
             return outs, st_out
 
-        try:
-            from jax import shard_map  # jax >= 0.8 (check_rep renamed)
-            kw = {"check_vma": False}
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-            kw = {"check_rep": False}
         fn = shard_map(
             pipeline_rank, mesh=self.mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: P(self.axis), params),
@@ -214,7 +208,7 @@ class GPipe(Module):
             out_specs=(P(self.axis),
                        jax.tree_util.tree_map(lambda _: P(self.axis),
                                               state)),
-            **kw)
+            check_vma=False)
         outs, new_state = fn(params, state, input)
         return outs, new_state
 

@@ -31,6 +31,9 @@ def main():
     if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
+    else:  # compiles for the chip are worth keeping
+        from bigdl_tpu.engine import Engine
+        Engine.enable_compile_cache()
 
     import numpy as np
 

@@ -238,10 +238,10 @@ class TestGradSyncNumerics:
             p2, os2 = method.update(g, p, os_, 0.05, it)
             return p2, ms2, os2, lax.pmean(loss, "data")
 
-        f_gs = jax.jit(gs.shard_map_compat(
+        f_gs = jax.jit(gs.shard_map_unchecked(
             gs_step, mesh, (repl, replm, gspec, P("data"), P("data"),
                             P()), (repl, replm, gspec, P())))
-        f_ps = jax.jit(gs.shard_map_compat(
+        f_ps = jax.jit(gs.shard_map_unchecked(
             psum_step, mesh, (repl, replm, ospec, P("data"), P("data"),
                               P()), (repl, replm, ospec, P())))
 
@@ -418,37 +418,16 @@ class TestConfigEngineSurface:
         monkeypatch.setenv("XLA_FLAGS", "--foo=1")
         prev = Engine._state.xla_async_collectives
         try:
-            # this process's backend IS live (conftest initialized jax):
-            # an unforced late call must refuse — no probe child fights
-            # for a chip, no env mutation, intent still recorded
-            assert Engine._backend_live()
-            Engine.set_xla_async_collectives(True)
-            assert os.environ["XLA_FLAGS"] == "--foo=1"
-            assert Engine.xla_async_collectives() is True
-            # pre-init path, probe refuses: env still untouched (probe
-            # outcomes are pinned so the test is deterministic — the
-            # real probe spawns a jax subprocess)
-            monkeypatch.setattr(Engine, "_backend_live",
-                                staticmethod(lambda: False))
-            monkeypatch.setattr(Engine, "_xla_flags_survive",
-                                staticmethod(lambda _f: False))
-            Engine.set_xla_async_collectives(True)
-            assert os.environ["XLA_FLAGS"] == "--foo=1"
-            # pre-init path, probe survives: flags committed
-            monkeypatch.setattr(Engine, "_xla_flags_survive",
-                                staticmethod(lambda _f: True))
+            # no probe child, no private jax read: the flags are
+            # written, and a backend that rejects them fails at start
             Engine.set_xla_async_collectives(True)
             flags = os.environ["XLA_FLAGS"]
             assert "--foo=1" in flags
             assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
                 in flags
-            # identical re-call short-circuits (no second probe)
-            monkeypatch.setattr(
-                Engine, "_xla_flags_survive",
-                staticmethod(lambda _f: pytest.fail("re-probed")))
-            Engine.set_xla_async_collectives(True)
-            # force=True writes with no probe and never duplicates
-            Engine.set_xla_async_collectives(False, force=True)
+            assert Engine.xla_async_collectives() is True
+            # a re-call with the other value replaces, never duplicates
+            Engine.set_xla_async_collectives(False)
             flags = os.environ["XLA_FLAGS"].split()
             assert flags.count("--xla_tpu_enable_latency_hiding_"
                                "scheduler=false") == 1

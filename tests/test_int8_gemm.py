@@ -68,7 +68,7 @@ class TestSupportedGate:
         # K and O must already be 128-multiples (no contraction padding)
         assert not supported(4, 130, 128, jnp.float32)
         assert not supported(4, 128, 100, jnp.float32)
-        # panel element budget (PROVISIONAL, PALLAS_NOTES.md §int8)
+        # panel element budget (PALLAS_NOTES.md §int8)
         assert not supported(4, 2048, 4096, jnp.float32)  # 8.4M > 6M
         assert supported(4, 2048, 2048, jnp.float32)      # 4.2M fits
         # degenerate dims

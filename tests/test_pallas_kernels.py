@@ -160,7 +160,12 @@ class TestEmbeddingBagParity:
     @pytest.mark.parametrize("name,N,V,D,nnz,dtype,tol", CASES)
     def test_forward_and_grad_match_xla(self, name, N, V, D, nnz, dtype,
                                         tol):
-        assert pallas_embed.supported(nnz, N, (V, D), dtype)
+        # bf16 tables are refused by the gate (the v5e compiler cannot
+        # slice one row out of a packed bf16 sublane pair — see
+        # supported()); the kernel body itself stays parity-tested in
+        # interpret mode so the gate can be lifted without a rewrite
+        assert pallas_embed.supported(nnz, N, (V, D), dtype) \
+            == (dtype == jnp.float32)
         rng = np.random.default_rng(abs(hash(name)) % 2 ** 31)
         rows = jnp.asarray(rng.integers(0, N, nnz).astype(np.int32))
         cols = jnp.asarray(rng.integers(0, V, nnz).astype(np.int32))

@@ -660,6 +660,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return 0
     if not args.workload:
         ap.error("--workload is required (or --list)")
+    from bigdl_tpu.engine import Engine
+    Engine.enable_compile_cache()
     result = tune(args.workload, budget=args.budget, eta=args.eta,
                   full_windows=args.full_windows, smoke=args.smoke,
                   out=args.out, dry_run=args.dry_run)
