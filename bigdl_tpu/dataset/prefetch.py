@@ -75,8 +75,9 @@ class DeviceBlockStager:
         self._place = place_block
         self._held = None  # batch pulled but deferred to the next block
         # telemetry (optional): a bigdl_tpu.telemetry.Tracer records the
-        # host-stack vs H2D-staging split of every take() — host-side
-        # clock reads only, inert when None
+        # split of every take() — host_stack (with each pull from the
+        # host pipeline and the K-axis copy inside it) vs H2D staging —
+        # host-side clock reads only, inert when None
         self._tracer = tracer
 
     def reset(self, batch_iter) -> None:
@@ -113,7 +114,12 @@ class DeviceBlockStager:
                     b, self._held = self._held, None
                 else:
                     try:
-                        b = next(self._it)
+                        # with MTSampleToMiniBatch a wait on the
+                        # assembler thread's queue; with an inline
+                        # assembler the assembly itself
+                        with span("batch_pull", cat="batch_pull",
+                                  n=len(batches)) if span else _NOOP_CM:
+                            b = next(self._it)
                     except StopIteration:
                         break
                 if not isinstance(b, MiniBatch):
@@ -135,15 +141,22 @@ class DeviceBlockStager:
                     "AbstractDataSet.data)")
             import jax
             tmap = jax.tree_util.tree_map
-            xs = tmap(lambda *ls: np.stack([np.asarray(l) for l in ls]),
-                      *[b.input for b in batches])
-            if batches[0].target is None:
-                ys = None
-            else:
-                ys = tmap(lambda *ls: np.stack([np.asarray(l) for l in ls]),
-                          *[b.target for b in batches])
-        with span("h2d_stage", cat="stage", k=len(batches)) if span \
-                else _NOOP_CM:
+
+            def stack(*leaves):
+                return np.stack([np.asarray(l) for l in leaves])
+
+            # bytes of the block: what block_stack copies and h2d_stage
+            # hands to the device (telemetry only)
+            nbytes = sum(np.asarray(l).nbytes for b in batches
+                         for l in jax.tree_util.tree_leaves(
+                             (b.input, b.target))) if span else None
+            with span("block_stack", cat="block_stack", bytes=nbytes) \
+                    if span else _NOOP_CM:
+                xs = tmap(stack, *[b.input for b in batches])
+                ys = None if batches[0].target is None else \
+                    tmap(stack, *[b.target for b in batches])
+        with span("h2d_stage", cat="stage", k=len(batches),
+                  bytes=nbytes) if span else _NOOP_CM:
             # the device_put underneath is ASYNCHRONOUS — this span times
             # the host-side staging cost, not the DMA itself (the DMA
             # overlaps the in-flight block's compute by design)

@@ -138,18 +138,21 @@ class _Staged:
         self.lrs, self.lrs_dev = lrs, lrs_dev
         self.steps_dev, self.rngs_dev = steps_dev, rngs_dev
         self.sync = sync  # a trigger/epoch/end boundary ends this block
-        self.stage_s = stage_s  # host time spent planning+staging (telemetry)
+        # host time inside stager.take — the interval of the tracer's
+        # ``stage`` category and of metrics.time("data") (telemetry)
+        self.stage_s = stage_s
 
 
 class _InFlight:
     """A dispatched block whose per-step losses are still on device."""
 
-    __slots__ = ("losses", "sizes", "lrs", "t0", "stage_s", "dispatch_s",
-                 "first_compile")
+    __slots__ = ("losses", "sizes", "lrs", "t0", "index", "stage_s",
+                 "dispatch_s", "first_compile")
 
-    def __init__(self, losses, sizes, lrs, t0, stage_s=0.0,
+    def __init__(self, losses, sizes, lrs, t0, index=0, stage_s=0.0,
                  dispatch_s=0.0, first_compile=False):
         self.losses, self.sizes, self.lrs, self.t0 = losses, sizes, lrs, t0
+        self.index = index            # dispatch index (telemetry: block=)
         self.stage_s = stage_s        # staging host time (telemetry)
         self.dispatch_s = dispatch_s  # jit enqueue host time (telemetry)
         self.first_compile = first_compile  # dispatch included a compile
@@ -236,6 +239,7 @@ class Optimizer:
         self._fault_injector: Optional[FaultInjector] = None
         self._guard_policy = "off"  # resolved per run by _train_driver
         self._dispatch_count = 0  # jit dispatches issued (observability)
+        self._replaying = None    # dispatch index of the block in replay
         self._stager: Optional[DeviceBlockStager] = None
         self._epoch_size = 0
         # elastic training (bigdl_tpu/resilience/membership): None —
@@ -728,7 +732,8 @@ class Optimizer:
         if self.checkpoint_trigger and self.checkpoint_path \
                 and self.checkpoint_trigger(self.state):
             with self._tel_span("checkpoint", "trigger",
-                                neval=self.state["neval"]):
+                                neval=self.state["neval"],
+                                block=self._replaying):
                 self._do_checkpoint(params, mstate, ostate)
 
     def _do_checkpoint(self, params, mstate, ostate,
@@ -758,7 +763,8 @@ class Optimizer:
                 and self.validation_trigger(self.state)):
             return None
         with self._tel_span("validation", "trigger",
-                            neval=self.state["neval"]):
+                            neval=self.state["neval"],
+                            block=self._replaying):
             results = self.evaluate_with(params, mstate)
         for name, res in results.items():
             logger.info("validation %s = %s", name, res)
@@ -1044,45 +1050,58 @@ class Optimizer:
             """Plan (trigger probe + epoch budget) and stage one block.
             Runs right after a dispatch, so the host stacking and the
             asynchronous host→device transfer overlap the in-flight
-            block's compute — the double buffer."""
+            block's compute — the double buffer.  The whole body is one
+            top-level ``stage_next`` span carrying the index the block
+            will have at its dispatch."""
             nonlocal bsz_hint
-            t_stage0 = time.perf_counter()
-            probe_state = dict(state)
-            probe_state.update(
-                neval=p_neval, epoch=p_epoch,
-                records_processed_this_epoch=p_records)
-            fire = probe_fire_step(probe_state, k_max, bsz_hint * scale,
-                                   epoch_size, triggers)
-            k_plan = fire if fire is not None else k_max
-            budget = max(1, -(-(epoch_size - p_records) // scale))
-            with self.metrics.time("data"):
-                xs, ys, sizes = stager.take(k_plan, budget)
-            k = len(sizes)
-            if faults is not None:
-                # batch-poison fault site (corrupt_batch/nonfinite_grads
-                # clauses, keyed by global iteration number) — only ever
-                # reached with a live plan
-                xs = faults.corrupt_staged(xs, p_neval, k)
-            bsz_hint = sizes[0]
-            # per-step host scalars, one current_lr call per iteration in
-            # order (schedules and the retry tests rely on that cadence)
-            lrs = [float(self.optim_method.current_lr(p_neval + j, p_epoch))
-                   for j in range(k)]
-            # per-step dropout keys are a PURE FUNCTION of (run key,
-            # iteration number) — fold_in, not sequential splits — so a
-            # mid-epoch resume re-derives exactly the keys the
-            # uninterrupted run used (bitwise-resume contract of
-            # bigdl_tpu.checkpoint), and the derivation is K-invariant
-            keys = [jax.random.fold_in(rng, p_neval + j)
-                    for j in range(k)]
-            ends_epoch = p_records + sum(sizes) * scale >= epoch_size
-            sync = ends_epoch or fire == k
-            return _Staged(xs, ys, sizes, lrs,
-                           jnp.asarray(np.asarray(lrs, np.float32)),
-                           jnp.asarray(np.arange(p_neval, p_neval + k,
-                                                 dtype=np.int32)),
-                           jnp.stack(keys), sync,
-                           stage_s=time.perf_counter() - t_stage0)
+            with self._tel_span("stage_next", "stage_next",
+                                block=self._dispatch_count):
+                with self._tel_span("plan", "plan"):
+                    probe_state = dict(state)
+                    probe_state.update(
+                        neval=p_neval, epoch=p_epoch,
+                        records_processed_this_epoch=p_records)
+                    fire = probe_fire_step(probe_state, k_max,
+                                           bsz_hint * scale, epoch_size,
+                                           triggers)
+                    k_plan = fire if fire is not None else k_max
+                    budget = max(1, -(-(epoch_size - p_records) // scale))
+                t_take0 = time.perf_counter()
+                with self.metrics.time("data"):
+                    xs, ys, sizes = stager.take(k_plan, budget)
+                stage_s = time.perf_counter() - t_take0
+                k = len(sizes)
+                if faults is not None:
+                    # batch-poison fault site (corrupt_batch/
+                    # nonfinite_grads clauses, keyed by global iteration
+                    # number) — only ever reached with a live plan
+                    xs = faults.corrupt_staged(xs, p_neval, k)
+                bsz_hint = sizes[0]
+                # the eager device calls below queue behind the running
+                # block, so this span is where a device-bound driver
+                # waits outside ``device_wait``
+                with self._tel_span("step_args", "step_args", k=k):
+                    # per-step host scalars, one current_lr call per
+                    # iteration in order (schedules and the retry tests
+                    # rely on that cadence)
+                    lrs = [float(self.optim_method.current_lr(
+                        p_neval + j, p_epoch)) for j in range(k)]
+                    # per-step dropout keys are a PURE FUNCTION of (run
+                    # key, iteration number) — fold_in, not sequential
+                    # splits — so a mid-epoch resume re-derives exactly
+                    # the keys the uninterrupted run used (bitwise-resume
+                    # contract of bigdl_tpu.checkpoint), and the
+                    # derivation is K-invariant
+                    keys = [jax.random.fold_in(rng, p_neval + j)
+                            for j in range(k)]
+                    lrs_dev = jnp.asarray(np.asarray(lrs, np.float32))
+                    steps_dev = jnp.asarray(np.arange(
+                        p_neval, p_neval + k, dtype=np.int32))
+                    rngs_dev = jnp.stack(keys)
+                ends_epoch = p_records + sum(sizes) * scale >= epoch_size
+                sync = ends_epoch or fire == k
+                return _Staged(xs, ys, sizes, lrs, lrs_dev, steps_dev,
+                               rngs_dev, sync, stage_s=stage_s)
 
         pending: Optional[_InFlight] = None
         staged: Optional[_Staged] = None
@@ -1192,7 +1211,8 @@ class Optimizer:
                 spmdcheck.note("dispatch", axis=f"k{k}", payload=staged.xs)
                 t0 = time.perf_counter()
                 with self._tel_span("dispatch", "dispatch", k=k,
-                                    compile=new_fn):
+                                    compile=new_fn,
+                                    block=self._dispatch_count):
                     if faults is None:
                         params, mstate, ostate, losses = fn(
                             params, mstate, ostate, staged.xs, staged.ys,
@@ -1220,6 +1240,7 @@ class Optimizer:
                     tel.recompile.observe(("block_fn", k),
                                           jit_cache_size(fn))
                 block = _InFlight(losses, staged.sizes, staged.lrs, t0,
+                                  index=self._dispatch_count - 1,
                                   stage_s=staged.stage_s,
                                   dispatch_s=time.perf_counter() - t0,
                                   first_compile=new_fn)
@@ -1377,13 +1398,14 @@ class Optimizer:
         checkpoint triggers at their exact iteration numbers, and the
         end_when check.  Returns True when training should stop."""
         tel = self._telemetry
+        self._replaying = block.index
         t_wait0 = time.perf_counter()
         # spmdcheck: the fetch syncs the producing block on every
         # process — a one-sided fetch deadlocks the block's collectives
         spmdcheck.note("block_fetch", payload=block.losses)
         with self.metrics.time("computing"), \
                 self._tel_span("device_wait", "device_wait",
-                               steps=len(block.sizes)):
+                               steps=len(block.sizes), block=block.index):
             # the driver's one and only device→host sync: the
             # one-block-behind loss fetch (GL107-safe — the span wraps
             # the fetch the driver already performs, never adds one).
@@ -1402,13 +1424,15 @@ class Optimizer:
             # overlapping the host phases without breaking span nesting
             tel.tracer.record("block_inflight", int(block.t0 * 1e9),
                               int(t_wait1 * 1e9), cat="pipeline",
-                              track="device", steps=len(block.sizes))
+                              track="device", steps=len(block.sizes),
+                              block=block.index)
         per_step = (time.perf_counter() - block.t0) / len(block.sizes)
         state = self.state
         scale = self._records_scale()
         ended = False
         t_replay0 = time.perf_counter()
-        with self._tel_span("replay", "replay", steps=len(block.sizes)):
+        with self._tel_span("replay", "replay", steps=len(block.sizes),
+                            block=block.index):
             for j, n_local in enumerate(block.sizes):
                 n = n_local * scale
                 state["neval"] += 1

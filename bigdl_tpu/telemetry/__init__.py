@@ -4,10 +4,13 @@ The observability substrate under the training driver and the serving
 engine (ISSUE 6; the foundation BigDL 2.0's cluster pipeline and TVM's
 measurement-driven tuning both stand on):
 
-- :class:`Tracer` — step-timeline spans (host-stack, H2D staging, jit
-  dispatch, device wait, one-block-behind loss fetch, triggers),
-  exported as Chrome-trace JSON; summarize with
-  ``python -m tools.trace_report trace.json``;
+- :class:`Tracer` — step-timeline spans (four top-level spans tile a
+  block of the driver's loop: stage_next, dispatch, device_wait,
+  replay; planning, host stacking, batch pulls, H2D staging, step
+  arguments and triggers nest inside them — ``PHASE_CATS``), exported
+  as Chrome-trace JSON (summarize with ``python -m tools.trace_report
+  trace.json``) and mirrored as ``jax.profiler.TraceAnnotation``s, so
+  any profiler capture holds them beside the device's timeline;
 - :class:`MetricRegistry` — counters, gauges, reservoir histograms with
   p50/p95/p99; ``utils/metrics.Metrics`` and
   ``serving/metrics.ServingMetrics`` are veneers over it;
@@ -43,7 +46,8 @@ from bigdl_tpu.telemetry.flight import FlightRecorder
 from bigdl_tpu.telemetry.hooks import DriverTelemetry
 from bigdl_tpu.telemetry.registry import (Counter, Gauge, Histogram,
                                           MetricRegistry, Reservoir)
-from bigdl_tpu.telemetry.tracer import NULL_SPAN, PHASE_CATS, Tracer
+from bigdl_tpu.telemetry.tracer import (NULL_SPAN, PHASE_CATS,
+                                        TOP_LEVEL_CATS, Tracer)
 from bigdl_tpu.telemetry.watchdog import (MemoryWatermark,
                                           RecompileWatchdog, StallDetector,
                                           jit_cache_size)
@@ -52,6 +56,7 @@ __all__ = [
     "AdminServer", "Counter", "DriverTelemetry", "FlightRecorder", "Gauge",
     "Histogram", "MemoryWatermark", "MetricRegistry", "NULL_SPAN",
     "PHASE_CATS", "RecompileWatchdog", "RequestContext", "Reservoir",
-    "StallDetector", "Tracer", "jit_cache_size", "new_trace_id",
+    "StallDetector", "TOP_LEVEL_CATS", "Tracer", "jit_cache_size",
+    "new_trace_id",
     "render_prometheus",
 ]

@@ -19,9 +19,11 @@ the first HTTP beachhead for ROADMAP item 1's RPC front end:
   as Chrome-trace JSON (one pid per source, mergeable in Perfetto).
 - ``GET /flight`` — the flight-recorder ring as JSON.
 - ``GET /profile?seconds=N`` — on-demand ``jax.profiler`` capture via
-  the ``utils/profiling`` bridge; returns the xplane log dir.  The one
-  endpoint that may sync the device — it exists to be the opt-in deep
-  dive, never scraped.
+  the ``utils/profiling`` bridge; returns the xplane log dir.  The
+  capture holds the spans of every enabled tracer of the process
+  (``bigdl:<category>:<name>`` on the host plane, beside the device's
+  own timeline).  The one endpoint that may sync the device — it exists
+  to be the opt-in deep dive, never scraped.
 
 Security posture (documented in the README): binds ``127.0.0.1`` ONLY
 by default and is OFF by default (``Config.admin_port = 0``); there is
@@ -263,10 +265,7 @@ class AdminServer:
             raise RuntimeError("a profile capture is already running")
         try:
             from bigdl_tpu.utils.profiling import profile_window
-            with self._lock:
-                tracer = next(iter(self._tracers.values()), None)
-            log_dir = profile_window(seconds, log_dir=self.profile_dir,
-                                     tracer=tracer)
+            log_dir = profile_window(seconds, log_dir=self.profile_dir)
             return {"log_dir": log_dir, "seconds": seconds}
         finally:
             self._profile_lock.release()

@@ -9,10 +9,23 @@ tracer records exactly those phases as spans and exports them as
 Chrome-trace JSON (open in Perfetto / ``chrome://tracing``, summarize
 with ``tools/trace_report.py``).
 
+Every span of an enabled tracer is ALSO a
+``jax.profiler.TraceAnnotation`` named ``bigdl:<category>:<name>`` with
+the span's scalar arguments as its keywords.  The annotation records
+nothing unless a profiler session is running; when one is (the
+benchmark's traced run, an operator's ``/profile?seconds=N`` capture of
+a live process) the program's spans land on the ``/host:CPU`` plane of
+the xplane, one line a thread, nested as entered, on the device trace's
+own clock — so a device idle gap can be laid against what the host was
+doing in it (``benchmarks/host_spans.py``).  ``record()``, ``instant()``
+and the flow events have no live scope and are not mirrored.
+
 The hard contract — telemetry is PROVABLY INERT:
 
-- a span is two ``time.perf_counter_ns()`` reads and one list append —
-  no jax import, no device work, no host↔device sync, ever;
+- a span is two ``time.perf_counter_ns()`` reads, one list append and
+  one annotation — no device work, no host↔device sync, ever;
+  ``jax.profiler`` is imported when the first enabled tracer is made,
+  never on the off path;
 - spans around device fetches wrap fetches the driver already performs
   (the one-block-behind loss fetch — the GL107-safe pattern), never
   introduce one;
@@ -48,13 +61,31 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
-# phase categories the driver emits; trace_report computes time shares
-# over these (plus "other" for unaccounted wall time)
-PHASE_CATS = ("stage", "dispatch", "device_wait", "replay", "trigger")
+# The span categories the training driver and its stager emit, each with
+# whether it is TOP-LEVEL: the four top-level categories tile every
+# iteration of the driver's loop (one span of each a block, carrying
+# ``block=<dispatch index>``); the others lie inside a top-level span
+# (``plan``, ``stage``, ``step_args`` in ``stage_next``; ``batch_pull``
+# and ``block_stack`` in the ``stage`` span ``host_stack``; ``trigger``
+# in ``replay``).  tools/trace_report.py takes the list from here.
+PHASE_CATS = {
+    "stage_next": True, "plan": False, "stage": False,
+    "batch_pull": False, "block_stack": False, "step_args": False,
+    "dispatch": True, "device_wait": True, "replay": True,
+    "trigger": False,
+}
+TOP_LEVEL_CATS = tuple(c for c, top in PHASE_CATS.items() if top)
+
+_SCALARS = (bool, int, float, str)
+
+
+def _trace_annotation():
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: Optional[str],
                  args: Optional[dict]):
@@ -64,11 +95,19 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        # the annotation encloses the clock reads, so spans nest in the
+        # xplane exactly as they nest on the host clock
+        scalars = {k: v for k, v in self.args.items()
+                   if isinstance(v, _SCALARS)} if self.args else {}
+        self._ann = self._tr._annotation(
+            f"bigdl:{self.cat or ''}:{self.name}", **scalars)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         self._tr._record("X", self.name, self.cat, self._t0,
                          t1 - self._t0, self.args)
         return False
@@ -93,6 +132,10 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Tuple] = []  # guarded-by: _lock
         self._dropped = 0               # write-guarded-by: _lock
+        # the mirror's annotation class: imported when a tracer is made
+        # enabled (or first spans after ``enabled`` was switched on —
+        # bench.py does that), never on the off path
+        self._annotation = _trace_annotation() if self.enabled else None
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, cat: Optional[str] = None, **args):
@@ -101,6 +144,8 @@ class Tracer:
         into the Chrome-trace ``args`` field (keep them cheap scalars)."""
         if not self.enabled:
             return NULL_SPAN
+        if self._annotation is None:
+            self._annotation = _trace_annotation()
         return _Span(self, name, cat, args or None)
 
     def instant(self, name: str, cat: str = "watchdog", **args) -> None:

@@ -105,13 +105,14 @@ def format_times(times: List[LayerTime]) -> str:
     return "\n".join(lines)
 
 
-def profile_window(seconds: float, log_dir: Optional[str] = None,
-                   tracer=None) -> str:
+def profile_window(seconds: float, log_dir: Optional[str] = None) -> str:
     """Wall-clock ``jax.profiler`` capture: whatever the process is
     doing for the next ``seconds`` lands in the xplane trace (open with
-    TensorBoard).  The admin plane's ``/profile?seconds=N`` endpoint is
-    a thin shim over this — the on-demand deep dive for a live serving
-    process, where there is no single ``step_fn`` to hand to
+    TensorBoard) — the spans of every enabled telemetry ``Tracer``
+    included, as ``bigdl:<category>:<name>`` events on the host plane.
+    The admin plane's ``/profile?seconds=N`` endpoint is a thin shim
+    over this — the on-demand deep dive for a live serving process,
+    where there is no single ``step_fn`` to hand to
     :func:`profile_step`.  Returns the log dir.
 
     Same divergence note as :func:`profile_step`: this is the opt-in,
@@ -119,45 +120,27 @@ def profile_window(seconds: float, log_dir: Optional[str] = None,
     surfaces are the tracer and /metrics, which never sync)."""
     import tempfile
     import time as _time
-    from contextlib import nullcontext
 
     if log_dir is None:
         log_dir = tempfile.mkdtemp(prefix="bigdl_tpu_profile_")
-    span = (tracer.span("jax_profiler_window", cat="profiler",
-                        log_dir=log_dir, seconds=seconds)
-            if tracer is not None else nullcontext())
-    with span:
-        with jax.profiler.trace(log_dir):
-            _time.sleep(float(seconds))
+    with jax.profiler.trace(log_dir):
+        _time.sleep(float(seconds))
     return log_dir
 
 
-def profile_step(step_fn, *args, log_dir: str, steps: int = 3,
-                 tracer=None):
+def profile_step(step_fn, *args, log_dir: str, steps: int = 3):
     """Run ``step_fn(*args)`` under the jax profiler (xplane trace in
     ``log_dir``; open with TensorBoard).  The jit'd step's per-op times
     carry the layer names annotated by jit tracing.
 
-    ``tracer``: optional :class:`bigdl_tpu.telemetry.Tracer` bridge —
-    the profiled region and each profiled step also land as spans in
-    the telemetry Chrome trace, so the step timeline links to the
-    xplane capture (the span's ``log_dir`` arg is the pointer).  The
-    deliberate divergence from the driver's inertness rule: this
+    The deliberate divergence from the driver's inertness rule: this
     function exists to sync (``block_until_ready`` per step) — it is
     the opt-in, off-the-hot-path deep dive, never the always-on path.
     """
-    from contextlib import nullcontext
-
-    def span(name, **kw):
-        return tracer.span(name, cat="profiler", **kw) if tracer \
-            else nullcontext()
-
     # warmup/compile outside the trace
     _block(step_fn(*args))
-    with span("jax_profiler_trace", log_dir=log_dir, steps=steps):
-        with jax.profiler.trace(log_dir):
-            out = None
-            for i in range(steps):
-                with span("profiled_step", i=i):
-                    out = _block(step_fn(*args))
+    with jax.profiler.trace(log_dir):
+        out = None
+        for _ in range(steps):
+            out = _block(step_fn(*args))
     return out

@@ -13,27 +13,36 @@
 - THE INERTNESS GATE: with telemetry enabled, the per-step loss
   sequence is BITWISE identical and the dispatch count equal to
   telemetry-off, for K ∈ {1, 4};
+- driver span coverage: the four top-level categories tile the driver
+  loop, children nest in their parents, one block number rides from a
+  block's staging to its replay (K ∈ {1, 4} × inline / threaded batch
+  assembly); "stage" means ``stager.take`` for the stall detector too;
+- the profiler mirror: every span of an enabled tracer comes back from
+  a ``jax.profiler`` capture as ``bigdl:<cat>:<name>``;
 - trace_report: fixture-driven summary (phase shares sum to ~1,
   self-time attribution, watchdog events) and CLI exit codes.
 """
 
+import glob
 import json
 import math
 import os
 import threading
+import time
 
 import jax
 import numpy as np
 import pytest
 
 from bigdl_tpu import nn, optim
-from bigdl_tpu.dataset import DataSet, SampleToMiniBatch
+from bigdl_tpu.dataset import (DataSet, MTSampleToMiniBatch,
+                               SampleToMiniBatch)
 from bigdl_tpu.dataset import image, mnist
 from bigdl_tpu.optim.optimizer import LocalOptimizer
 from bigdl_tpu.telemetry import (MemoryWatermark, MetricRegistry,
                                  RecompileWatchdog, Reservoir,
                                  StallDetector, Tracer, jit_cache_size)
-from bigdl_tpu.telemetry.tracer import NULL_SPAN
+from bigdl_tpu.telemetry.tracer import NULL_SPAN, TOP_LEVEL_CATS
 from bigdl_tpu.utils.metrics import Metrics
 from tools import trace_report
 
@@ -358,6 +367,48 @@ class TestStallDetector:
             det.record_block(0.5, 0.2, 0.0, 0.0)
         assert det.starvation_count == 0 and det.sync_stall_count == 0
 
+    def test_slow_step_args_is_not_stager_starvation(self):
+        """One boundary for "stage": the detector's staging time is the
+        time inside ``stager.take`` (the tracer's ``stage`` category),
+        not the whole of ``stage_next()``.  A driver whose step
+        arguments are slow (a schedule that takes 5 ms a call) over a
+        fast input pipeline is not starved by its stager."""
+
+        class SlowScheduleSGD(optim.SGD):
+            def current_lr(self, *a, **kw):
+                time.sleep(0.005)
+                return super().current_lr(*a, **kw)
+
+        class SlowSummary(RecordingSummary):
+            # a replay of some length, so that the block's host time is
+            # not made of the (fast) take alone
+            def add_train_step(self, *a):
+                time.sleep(0.002)
+                super().add_train_step(*a)
+
+        opt = (LocalOptimizer(small_mlp(), mnist_pipeline(256, 32),
+                              nn.ClassNLLCriterion())
+               .set_optim_method(SlowScheduleSGD(1e-2))
+               .set_train_summary(SlowSummary())
+               .set_steps_per_dispatch(4)
+               .set_end_when(optim.max_iteration(24))
+               .set_telemetry(True))
+        opt.optimize()
+        tel = opt._telemetry
+        totals = tel.tracer.phase_totals()
+        # the premise: step_args dwarfs the take
+        assert totals["step_args"] > 2 * totals["stage"]
+        assert tel.stalls.blocks_observed == 6
+        assert tel.stalls.starvation_count == 0
+        assert not [e for e in tel.tracer.events()
+                    if e[1] == "stager_starvation"]
+        # gauge and tracer describe one interval: the detector's stage
+        # seconds are the tracer's ``stage`` seconds (two clock reads
+        # apart), nowhere near ``stage_next``'s
+        stage_s = tel.stalls._totals["stage"]
+        assert stage_s == pytest.approx(totals["stage"], rel=0.1)
+        assert stage_s < 0.5 * totals["stage_next"]
+
 
 class TestMemoryWatermark:
     def test_degrades_silently_without_backend_stats(self):
@@ -553,6 +604,219 @@ class TestTelemetryInert:
         assert len(tel_first.tracer.events()) == events_after_on
 
 
+# ==========================================================================
+# driver span coverage + the profiler mirror
+# ==========================================================================
+def wide_mlp():
+    # wide enough that a step takes milliseconds on the CPU: blocks of
+    # tens of milliseconds, beside which the loop's own bookkeeping
+    # (tens of microseconds) cannot make the coverage floor unsteady
+    return (nn.Sequential()
+            .add(nn.Reshape((784,)))
+            .add(nn.Linear(784, 1024)).add(nn.ReLU())
+            .add(nn.Linear(1024, 1024)).add(nn.ReLU())
+            .add(nn.Linear(1024, 10)).add(nn.LogSoftMax()))
+
+
+def _spans(tracer):
+    """Host spans as dicts, the virtual device track apart."""
+    host, device = [], []
+    for ph, name, cat, t0, dur, tid, args, _flow in tracer.events():
+        if ph != "X":
+            continue
+        row = dict(name=name, cat=cat, t0=t0, t1=t0 + dur, tid=tid,
+                   args=args or {})
+        (device if tid == "device" else host).append(row)
+    return host, device
+
+
+def _inside(child, parent):
+    return (child["tid"] == parent["tid"] and parent["t0"] <= child["t0"]
+            and child["t1"] <= parent["t1"])
+
+
+class TestDriverSpanCoverage:
+    PARENT_OF = {"plan": "stage_next", "host_stack": "stage_next",
+                 "h2d_stage": "stage_next", "step_args": "stage_next",
+                 "batch_pull": "host_stack", "block_stack": "host_stack",
+                 "validation": "replay"}
+
+    @pytest.mark.parametrize("assembler", [SampleToMiniBatch,
+                                           MTSampleToMiniBatch],
+                             ids=["inline", "threaded"])
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_top_level_spans_tile_the_driver_loop(self, k, assembler):
+        imgs, labels = mnist.synthetic_mnist(1024, seed=0)
+        base = (DataSet.array(mnist.to_samples(imgs, labels))
+                >> image.BytesToGreyImg()
+                >> image.GreyImgNormalizer(mnist.TRAIN_MEAN,
+                                           mnist.TRAIN_STD))
+        opt = (LocalOptimizer(wide_mlp(), base >> assembler(256),
+                              nn.ClassNLLCriterion())
+               .set_optim_method(optim.SGD(1e-2))
+               .set_steps_per_dispatch(k)
+               .set_validation(optim.several_iteration(8),
+                               base >> SampleToMiniBatch(256),
+                               [optim.Top1Accuracy()])
+               .set_end_when(optim.max_iteration(16))
+               .set_telemetry(True))
+        opt.optimize()
+        tracer = opt._telemetry.tracer
+        assert tracer.dropped_events == 0
+        host, device = _spans(tracer)
+        n_blocks = opt._dispatch_count
+        assert n_blocks == 16 // k
+
+        # -- one block number from staging to replay
+        for cat in TOP_LEVEL_CATS:
+            rows = [s for s in host if s["cat"] == cat]
+            assert [s["name"] for s in rows] == [cat] * n_blocks
+            assert sorted(s["args"]["block"] for s in rows) == \
+                list(range(n_blocks)), cat
+        assert sorted(s["args"]["block"] for s in device
+                      if s["name"] == "block_inflight") == \
+            list(range(n_blocks))
+        driver_tid = {s["tid"] for s in host
+                      if s["cat"] in TOP_LEVEL_CATS}
+        assert len(driver_tid) == 1  # all four on the driver's thread
+        by_block = {(s["cat"], s["args"]["block"]): s for s in host
+                    if s["cat"] in TOP_LEVEL_CATS}
+        for b in range(n_blocks):
+            # staged (one block ahead), dispatched, waited for, replayed
+            assert (by_block["stage_next", b]["t1"]
+                    <= by_block["dispatch", b]["t0"]
+                    <= by_block["device_wait", b]["t0"]
+                    <= by_block["replay", b]["t0"])
+        for s in host:
+            if s["name"] == "validation":
+                assert any(p["name"] == "replay" and _inside(s, p)
+                           and p["args"]["block"] == s["args"]["block"]
+                           for p in host)
+
+        # -- every child lies inside its parent
+        seen = set()
+        for s in host:
+            parent = self.PARENT_OF.get(s["name"])
+            if parent is None:
+                assert s["cat"] in TOP_LEVEL_CATS, s
+                continue
+            seen.add(s["name"])
+            assert any(p["name"] == parent and _inside(s, p)
+                       for p in host), s
+        assert seen == set(self.PARENT_OF)
+        pulls = [s for s in host if s["name"] == "batch_pull"]
+        assert len(pulls) >= 16
+        assert all(0 <= s["args"]["n"] < k for s in pulls)
+        for s in host:
+            if s["name"] in ("block_stack", "h2d_stage"):
+                # k (or fewer) batches of 256 f32 images and i32 labels
+                assert s["args"]["bytes"] % (256 * (784 * 4 + 4)) == 0
+
+        # -- the four top-level categories cover the driver's time
+        tops = sorted((s for s in host if s["cat"] in TOP_LEVEL_CATS),
+                      key=lambda s: s["t0"])
+        assert tops[0]["name"] == "stage_next"
+        assert tops[-1]["name"] == "replay"
+        for a, b in zip(tops, tops[1:]):
+            assert a["t1"] <= b["t0"]  # they tile, never overlap
+        covered = sum(s["t1"] - s["t0"] for s in tops)
+        wall = tops[-1]["t1"] - tops[0]["t0"]
+        assert wall / n_blocks > 5e6  # blocks of milliseconds (ns)
+        assert covered / wall >= 0.90, (covered, wall)
+
+
+def _bigdl_lines(log_dir):
+    """``{line name: [(name, start_ns, end_ns, stats)]}`` of the
+    ``bigdl:`` events on the host plane of a capture."""
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not found:
+        return {}
+    out = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("bigdl:")]
+            if evs:
+                out[(i, line.name)] = evs
+    return out
+
+
+class TestProfilerMirror:
+    @staticmethod
+    def _drive(tracer):
+        with tracer.span("stage_next", cat="stage_next", block=3):
+            time.sleep(0.002)
+            with tracer.span("host_stack", cat="stage", k=4,
+                             label="x", skipped=[1, 2]):
+                time.sleep(0.002)
+            time.sleep(0.002)
+
+        def other():
+            with tracer.span("wire_request", cat="serving", rows=2):
+                time.sleep(0.002)
+
+        th = threading.Thread(target=other, name="second")
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+        tracer.instant("recompile", key="k")
+        tracer.record("block_inflight", 0, 10, cat="pipeline",
+                      track="device")
+
+    def test_spans_land_in_a_profiler_capture(self, tmp_path):
+        tracer = Tracer()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1  # what the train runner sets
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            self._drive(tracer)
+        finally:
+            jax.profiler.stop_trace()
+        lines = _bigdl_lines(str(tmp_path))
+        assert len(lines) == 2  # one line a thread
+        events = {name: (t0, t1, stats, line)
+                  for line, evs in lines.items()
+                  for name, t0, t1, stats in evs}
+        # spans only: instants, explicit records and flows have no live
+        # scope and are not mirrored
+        assert set(events) == {"bigdl:stage_next:stage_next",
+                               "bigdl:stage:host_stack",
+                               "bigdl:serving:wire_request"}
+        p0, p1, pstats, pline = events["bigdl:stage_next:stage_next"]
+        c0, c1, cstats, cline = events["bigdl:stage:host_stack"]
+        assert pline == cline and p0 <= c0 and c1 <= p1  # nested
+        assert c1 - c0 >= 2e6 and p1 - p0 >= 6e6
+        assert pstats == {"block": 3}
+        assert cstats == {"k": 4, "label": "x"}  # scalars only
+        _w0, _w1, wstats, wline = events["bigdl:serving:wire_request"]
+        assert wline != pline and wstats == {"rows": 2}
+        # the Chrome-trace side is what it was: same spans, all args
+        by = {e[1]: e for e in tracer.events()}
+        assert by["host_stack"][6] == {"k": 4, "label": "x",
+                                      "skipped": [1, 2]}
+
+    def test_no_session_no_capture_and_off_is_off(self, tmp_path):
+        tracer = Tracer()
+        self._drive(tracer)  # no profiler session: annotations are inert
+        assert _bigdl_lines(str(tmp_path)) == {}
+        assert len([e for e in tracer.events() if e[0] == "X"]) == 4
+        off = Tracer(enabled=False)
+        assert off.span("a", cat="stage", block=1) is NULL_SPAN
+        assert off._annotation is None  # jax.profiler left alone
+        # a tracer switched on after it was made (bench.py's warm-up
+        # windows) mirrors from its first span on
+        off.enabled = True
+        with off.span("a", cat="stage"):
+            pass
+        assert off._annotation is not None and len(off.events()) == 1
+
+
 class TestConfigSurface:
     def test_config_fields_exist(self):
         from bigdl_tpu.utils.config import Config
@@ -606,20 +870,32 @@ class TestTraceReport:
             trace_report.load_trace(self.FIXTURE))
         assert report["wall_s"] == pytest.approx(1.0)
         share = report["phase_share"]
-        # hand-built fixture: stage .2, dispatch .1, wait .5, replay .1
-        # with a nested 40ms trigger span (self-time split), other .1;
-        # the device-track pipeline span must NOT count
-        assert share == {"stage": 0.2, "dispatch": 0.1,
-                         "device_wait": 0.5, "replay": 0.06,
+        # hand-built fixture, one block of the driver's loop: stage_next
+        # .3 holding plan .01, the stage spans host_stack .12 (itself
+        # holding batch_pull .05 and block_stack .04) and h2d_stage .08,
+        # and step_args .07; dispatch .1, wait .4, replay .1 with a
+        # nested 40ms trigger span; other .1.  Self-time splits every
+        # parent; the device-track pipeline span must NOT count
+        assert share == {"stage_next": 0.02, "plan": 0.01, "stage": 0.11,
+                         "batch_pull": 0.05, "block_stack": 0.04,
+                         "step_args": 0.07, "dispatch": 0.1,
+                         "device_wait": 0.4, "replay": 0.06,
                          "trigger": 0.04, "other": 0.1}
         assert sum(share.values()) == pytest.approx(1.0)
-        assert report["stall"]["device_wait_fraction"] == 0.5
+        # every category of the fixture is one the tracer declares, and
+        # the top-level four cover what "other" does not
+        assert set(share) - {"other"} == set(trace_report.PHASE_CATS)
+        assert report["driver_coverage"] == 0.9
+        # the stall picture takes whole spans: the stager held the
+        # driver for .2, whoever worked inside
+        assert report["stall"]["host_stage_fraction"] == 0.2
+        assert report["stall"]["device_wait_fraction"] == 0.4
         assert report["watchdog_events"] == {"recompile": 2,
                                              "stager_starvation": 1}
         assert len(report["recompile_events"]) == 2
         top = report["top_spans"]
         assert top[0]["name"] == "device_wait"
-        assert top[0]["total_ms"] == 500.0
+        assert top[0]["total_ms"] == 400.0
 
     def test_cli_exit_codes(self, tmp_path, capsys):
         assert trace_report.main([self.FIXTURE]) == 0

@@ -4,11 +4,16 @@ Reads the Chrome-trace JSON the telemetry tracer emits
 (``Tracer.dump`` / ``Config.telemetry_trace_path``) and prints the
 driver-pipeline picture the raw timeline buries:
 
-- **per-phase time share** — self-time per span category (stage /
-  dispatch / device_wait / replay / trigger) over the trace wall clock,
-  plus ``other`` for unaccounted time, summing to ~1.  Self-time:
-  nested spans (a validation span inside a replay span) are charged to
-  the child, never double-counted;
+- **per-phase time share** — self-time per span category (the list is
+  ``telemetry.tracer.PHASE_CATS``) over the trace wall clock, plus
+  ``other`` for unaccounted time, summing to ~1.  Self-time: nested
+  spans (a validation span inside a replay span, ``batch_pull`` inside
+  ``host_stack`` inside ``stage_next``) are charged to the child, never
+  double-counted;
+- **driver coverage** — the whole (not self) time of the four
+  TOP-LEVEL categories (stage_next / dispatch / device_wait / replay),
+  which tile the driver's loop, over the wall clock: what is left is
+  host time no span names;
 - **top spans** — by total duration, with call counts and mean;
 - **stall picture** — device-wait fraction (host blocked on device —
   healthy when the device is the bottleneck) vs host-stage fraction
@@ -44,9 +49,10 @@ import sys
 from collections import defaultdict
 from typing import Dict, List
 
-# categories counted as host pipeline phases; spans on virtual tracks
-# (cat "pipeline") overlap the host timeline and are excluded
-PHASE_CATS = ("stage", "dispatch", "device_wait", "replay", "trigger")
+from bigdl_tpu.telemetry.tracer import PHASE_CATS, TOP_LEVEL_CATS
+
+# spans on virtual tracks (cat "pipeline") overlap the host timeline and
+# are excluded from the phase accounting
 _EXCLUDED_CATS = {"pipeline"}
 
 
@@ -103,10 +109,12 @@ def summarize(trace: dict, top: int = 10) -> dict:
 
     self_us = _self_times(host_spans)
     cat_us: Dict[str, float] = defaultdict(float)
+    whole_us: Dict[str, float] = defaultdict(float)  # children included
     name_rows: Dict[str, dict] = {}
     for i, s in enumerate(host_spans):
         cat = s.get("cat") or "uncategorized"
         cat_us[cat] += self_us[i]
+        whole_us[cat] += s.get("dur", 0.0)
         row = name_rows.setdefault(
             s["name"], {"name": s["name"], "cat": cat, "count": 0,
                         "total_us": 0.0})
@@ -117,6 +125,7 @@ def summarize(trace: dict, top: int = 10) -> dict:
              for c in sorted(cat_us)}
     accounted = sum(share.values())
     share["other"] = round(max(0.0, 1.0 - accounted), 4)
+    whole = {c: round(v / wall_us, 4) for c, v in whole_us.items()}
 
     top_spans = sorted(name_rows.values(),
                        key=lambda r: -r["total_us"])[:top]
@@ -155,10 +164,18 @@ def summarize(trace: dict, top: int = 10) -> dict:
         "phase_share": share,
         "phase_seconds": {c: round(v / 1e6, 6)
                           for c, v in sorted(cat_us.items())},
+        # the top-level categories tile the driver's loop, so their
+        # whole time over the wall clock is the share of the run that
+        # some span names (0.0 for a trace without a driver)
+        "driver_coverage": round(sum(whole.get(c, 0.0)
+                                     for c in TOP_LEVEL_CATS), 4),
         "stall": {
-            "device_wait_fraction": share.get("device_wait", 0.0),
-            "host_stage_fraction": share.get("stage", 0.0),
-            "dispatch_fraction": share.get("dispatch", 0.0),
+            # whole time: ``stage`` spans hold batch_pull/block_stack
+            # children, and the stall picture asks how long the stager
+            # held the driver, whoever did the work inside
+            "device_wait_fraction": whole.get("device_wait", 0.0),
+            "host_stage_fraction": whole.get("stage", 0.0),
+            "dispatch_fraction": whole.get("dispatch", 0.0),
             # the disruption fold (satellite of the admin-plane PR): a
             # wait spike with failovers behind it reads differently
             # from one without
@@ -179,10 +196,13 @@ def _render(report: dict, events: bool = False) -> str:
              f"{report['span_count']} spans"
              + (f" ({report['dropped_events']} dropped)"
                 if report["dropped_events"] else "")]
-    lines.append("phase share (self-time / wall):")
+    lines.append("phase share (self-time / wall; * = top-level):")
     for cat, frac in sorted(report["phase_share"].items(),
                             key=lambda kv: -kv[1]):
-        lines.append(f"  {cat:<14} {frac * 100:6.2f}%")
+        mark = "*" if PHASE_CATS.get(cat) else ""
+        lines.append(f"  {cat + mark:<14} {frac * 100:6.2f}%")
+    lines.append(f"driver coverage (top-level spans / wall): "
+                 f"{report['driver_coverage']:.3f}")
     st = report["stall"]
     lines.append(
         f"stall picture: device_wait {st['device_wait_fraction']:.3f} "
