@@ -25,9 +25,11 @@ The pipeline has TWO prefetch stages since the fused-dispatch rework:
 
 from __future__ import annotations
 
+import collections
 import itertools
 import queue
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterator, Optional
 
@@ -54,6 +56,16 @@ def batch_signature(batch: MiniBatch):
     return treedef, tuple(_leaf_meta(l) for l in leaves)
 
 
+def _may_alias_host(placed) -> bool:
+    """Whether a placed array may still be the host buffer it was put
+    from: the CPU backend's ``device_put`` can alias a numpy array
+    without a copy, and what is no device array at all says nothing of
+    where it lives."""
+    return any(getattr(a, "devices", None) is None
+               or any(d.platform == "cpu" for d in a.devices())
+               for a in placed)
+
+
 class DeviceBlockStager:
     """Device-prefetch stage: pulls MiniBatches from the host pipeline,
     stacks up to ``k`` of them along a new leading step axis, and hands
@@ -70,15 +82,26 @@ class DeviceBlockStager:
     epoch/trigger semantics exact under fusion.
     """
 
-    def __init__(self, batch_iter, place_block, tracer=None):
+    def __init__(self, batch_iter, place_block, tracer=None,
+                 registry=None):
         self._it = batch_iter
         self._place = place_block
         self._held = None  # batch pulled but deferred to the next block
         # telemetry (optional): a bigdl_tpu.telemetry.Tracer records the
         # split of every take() — host_stack (with each pull from the
         # host pipeline and the K-axis copy inside it) vs H2D staging —
-        # host-side clock reads only, inert when None
+        # host-side clock reads only, inert when None; a MetricRegistry
+        # counts the assembler's buffers by where they came from
         self._tracer = tracer
+        self._recycled = self._allocated = None
+        if registry is not None:
+            self._recycled = registry.counter("input/buffers_recycled")
+            self._allocated = registry.counter("input/buffers_allocated")
+        # placed blocks whose batches lent their buffers (``lease``):
+        # (device leaves, leases), oldest first.  A lease goes back to
+        # the assembler only once the device arrays placed from it are
+        # ready and none of them lives on the host platform
+        self._lent: collections.deque = collections.deque()
 
     def reset(self, batch_iter) -> None:
         """Point at a fresh iterator (epoch rollover: the driver
@@ -91,6 +114,33 @@ class DeviceBlockStager:
             close()
         self._it = batch_iter
         self._held = None
+        # the closed pipeline takes nothing back; its buffers stay with
+        # whatever transfer still reads them
+        self._lent.clear()
+
+    def _return_lent(self) -> None:
+        """Hand back the buffers of every placed block but the newest.
+        Those blocks were dispatched at least one block ago, so their
+        transfers are long over and the wait costs nothing."""
+        import jax
+        while len(self._lent) > 1:
+            placed, leases = self._lent.popleft()
+            if _may_alias_host(placed):
+                continue  # never written again
+            jax.block_until_ready(placed)
+            for lease in leases:
+                lease.release()
+
+    def _note_assembled(self, lease) -> None:
+        """The assembler's own work on a staged batch, from the stamps
+        it carries: a span on a track of its own, and the counters."""
+        if self._tracer is not None:
+            self._tracer.record("assemble", lease.t0_ns, lease.t1_ns,
+                                cat="batch_assemble", track="assembler",
+                                bytes=lease.nbytes,
+                                recycled=lease.recycled)
+        if self._recycled is not None:
+            (self._recycled if lease.recycled else self._allocated).inc()
 
     def take(self, k: int, records_budget: int):
         """Stage the next block: up to ``k`` consecutive same-signature
@@ -106,6 +156,11 @@ class DeviceBlockStager:
         tr = self._tracer
         span = tr.span if tr is not None else None
         with span("host_stack", cat="stage") if span else _NOOP_CM:
+            # where the driver waits for an earlier block's transfer
+            # should the runtime fall behind (under a profiler it does)
+            with span("buffer_return", cat="buffer_return") \
+                    if span else _NOOP_CM:
+                self._return_lent()
             batches = []
             sig = None
             total = 0
@@ -141,17 +196,23 @@ class DeviceBlockStager:
                     "AbstractDataSet.data)")
             import jax
             tmap = jax.tree_util.tree_map
+            one = len(batches) == 1
 
             def stack(*leaves):
+                # a block of one batch is the batch itself under a step
+                # axis of length 1: a view, nothing is copied
+                if one:
+                    return np.asarray(leaves[0])[None]
                 return np.stack([np.asarray(l) for l in leaves])
 
-            # bytes of the block: what block_stack copies and h2d_stage
-            # hands to the device (telemetry only)
+            # bytes of the block: what h2d_stage hands to the device,
+            # and what block_stack copies unless it is a view (telemetry
+            # only)
             nbytes = sum(np.asarray(l).nbytes for b in batches
                          for l in jax.tree_util.tree_leaves(
                              (b.input, b.target))) if span else None
-            with span("block_stack", cat="block_stack", bytes=nbytes) \
-                    if span else _NOOP_CM:
+            with span("block_stack", cat="block_stack",
+                      bytes=0 if one else nbytes) if span else _NOOP_CM:
                 xs = tmap(stack, *[b.input for b in batches])
                 ys = None if batches[0].target is None else \
                     tmap(stack, *[b.target for b in batches])
@@ -161,6 +222,12 @@ class DeviceBlockStager:
             # the host-side staging cost, not the DMA itself (the DMA
             # overlaps the in-flight block's compute by design)
             dev_xs, dev_ys = self._place(xs, ys)
+        leases = [b.lease for b in batches if b.lease is not None]
+        if leases:
+            for lease in leases:
+                self._note_assembled(lease)
+            self._lent.append(
+                (jax.tree_util.tree_leaves((dev_xs, dev_ys)), leases))
         return dev_xs, dev_ys, [b.size() for b in batches]
 
 
@@ -193,12 +260,101 @@ def fast_forward_records(batch_iter, skip: int) -> int:
     return skipped
 
 
-def _stack(samples) -> MiniBatch:
-    feats = np.stack([s.feature for s in samples])
-    if samples[0].label is None:
-        return MiniBatch(feats, None)
-    return MiniBatch(feats, np.stack([np.asarray(s.label)
-                                      for s in samples]))
+class _BufferPool:
+    """The recycled host buffers of one pass of ``MTSampleToMiniBatch``.
+
+    A fresh multi-hundred-megabyte array is new pages that the kernel
+    faults in while the copy runs; a buffer that came back is mapped
+    already.  The pool holds buffer sets of ONE signature (the first
+    batch's: length, and shape and dtype of every leaf) and never owns
+    more than ``bound`` of them; a batch of another signature (the
+    remainder), or one wanted while all ``bound`` are out, takes fresh
+    arrays that are not pooled.  A consumer that hands nothing back
+    keeps what it was given: the pool holds no reference to a buffer
+    that is out."""
+
+    def __init__(self, bound: int):
+        self._bound = bound
+        self._lock = threading.Lock()
+        self._sig = None    # guarded-by: _lock
+        self._free = []     # guarded-by: _lock
+        self._owned = 0     # guarded-by: _lock
+        self._open = True   # guarded-by: _lock
+
+    def take(self, sig):
+        """``(buffers, recycled, pooled)`` for a batch of ``sig`` =
+        ``((shape, dtype), ...)``, one entry a leaf."""
+        with self._lock:
+            if self._sig is None:
+                self._sig = sig
+            pooled = self._open and sig == self._sig
+            if pooled and self._free:
+                return self._free.pop(), True, True
+            pooled = pooled and self._owned < self._bound
+            if pooled:
+                self._owned += 1
+        return [np.empty(shape, dtype) for shape, dtype in sig], \
+            False, pooled
+
+    def give_back(self, bufs) -> None:
+        with self._lock:
+            if self._open:
+                self._free.append(bufs)
+
+    def close(self) -> None:
+        """Drop what is free; what is out is dropped as it comes back."""
+        with self._lock:
+            self._open = False
+            self._free.clear()
+
+
+class _Lease:
+    """What an assembled batch carries of its making: the stamps of the
+    assembler's work (``perf_counter_ns``), its bytes, whether its
+    arrays came back from an earlier batch, and the way back."""
+
+    __slots__ = ("t0_ns", "t1_ns", "nbytes", "recycled", "_pool", "_bufs")
+
+    def __init__(self, t0_ns, t1_ns, nbytes, recycled, pool, bufs):
+        self.t0_ns = t0_ns
+        self.t1_ns = t1_ns
+        self.nbytes = nbytes
+        self.recycled = recycled
+        self._pool = pool   # None: the arrays are not the pool's
+        self._bufs = bufs
+
+    def release(self) -> None:
+        """Nothing reads the batch's arrays any more: the next batch
+        may be written into them.  A second call does nothing."""
+        pool, self._pool = self._pool, None
+        if pool is not None:
+            pool.give_back(self._bufs)
+
+
+def _stack(samples, pool: _BufferPool) -> MiniBatch:
+    """One batch of ``samples``, every byte written once: stacked
+    straight into buffers of the pool where the samples are uniform,
+    into fresh arrays by ``np.stack`` alone where they are not."""
+    t0 = time.perf_counter_ns()
+    leaves = [[np.asarray(s.feature) for s in samples]]
+    if samples[0].label is not None:
+        leaves.append([np.asarray(s.label) for s in samples])
+    uniform = all(a.shape == col[0].shape and a.dtype == col[0].dtype
+                  for col in leaves for a in col)
+    if uniform:
+        sig = tuple(((len(col),) + col[0].shape, col[0].dtype)
+                    for col in leaves)
+        bufs, recycled, pooled = pool.take(sig)
+        for col, buf in zip(leaves, bufs):
+            np.stack(col, out=buf)
+    else:
+        bufs, recycled, pooled = [np.stack(col) for col in leaves], \
+            False, False
+    batch = MiniBatch(bufs[0], bufs[1] if len(bufs) > 1 else None)
+    batch.lease = _Lease(t0, time.perf_counter_ns(),
+                         sum(b.nbytes for b in bufs), recycled,
+                         pool if pooled else None, bufs)
+    return batch
 
 
 class MTSampleToMiniBatch(Transformer):
@@ -207,6 +363,16 @@ class MTSampleToMiniBatch(Transformer):
     ``transform`` maps one Sample → Sample (e.g. a composed augmentation
     pipeline applied per element); it runs on ``workers`` threads.  Up to
     ``prefetch`` assembled batches are buffered ahead of the consumer.
+
+    A batch is stacked into a buffer that an earlier batch of this pass
+    handed back through its ``lease`` (only ``DeviceBlockStager`` does,
+    once the device holds its own copy), and into a fresh array when
+    none has come back, so a consumer that releases nothing (user code,
+    validation, ``list(...)``) gets fresh arrays as ever.  The pass owns
+    at most ``prefetch + 3`` buffer sets (the queue, the two blocks a
+    training driver runs ahead, the one being written): five global
+    batches with the defaults, about 3 GB of host memory at 1,024
+    224x224x3 f32 images a batch.
     """
 
     def __init__(self, batch_size: int,
@@ -227,6 +393,7 @@ class MTSampleToMiniBatch(Transformer):
     def __call__(self, it: Iterator[Sample]) -> Iterator[MiniBatch]:
         out_q: queue.Queue = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
+        pool = _BufferPool(self.prefetch + 3)
         _END = object()
 
         def put_or_stop(item) -> bool:
@@ -259,13 +426,13 @@ class MTSampleToMiniBatch(Transformer):
         failure: list = [None]
 
         def producer():
-            pool = None
+            workers = None
             stream_ix = 0
             try:
                 # inside the try: a ThreadPoolExecutor that cannot start
                 # (resource exhaustion) must take the error path below,
                 # not kill this thread with the consumer still blocked
-                pool = ThreadPoolExecutor(max_workers=self.workers)
+                workers = ThreadPoolExecutor(max_workers=self.workers)
                 buf = []
                 # map the per-sample transform with bounded lookahead:
                 # chunks of one batch keep memory flat
@@ -280,27 +447,28 @@ class MTSampleToMiniBatch(Transformer):
                     if not chunk:
                         break
                     if self.transform is not None:
-                        chunk = list(pool.map(
+                        chunk = list(workers.map(
                             keyed_transform,
                             enumerate(chunk, start=stream_ix)))
                     stream_ix += len(chunk)
                     buf.extend(chunk)
                     while len(buf) >= self.batch_size:
-                        if not put_or_stop(_stack(buf[:self.batch_size])):
+                        if not put_or_stop(
+                                _stack(buf[:self.batch_size], pool)):
                             return
                         buf = buf[self.batch_size:]
                     if len(chunk) < self.batch_size:
                         break
                 if buf and not self.drop_remainder:
-                    put_or_stop(_stack(buf))
+                    put_or_stop(_stack(buf, pool))
             except BaseException as e:  # surface worker errors to consumer
                 failure[0] = e  # out-of-band first: survives a failed put
                 put_or_stop(e)
             finally:
                 # cancel queued per-sample work so idle workers exit now
                 # instead of grinding through a chunk nobody will read
-                if pool is not None:
-                    pool.shutdown(wait=False, cancel_futures=True)
+                if workers is not None:
+                    workers.shutdown(wait=False, cancel_futures=True)
                 # propagate shutdown upstream: in a chained pipeline the
                 # source is itself a generator (possibly another MT
                 # assembler) whose own cleanup must run NOW, on the one
@@ -347,6 +515,7 @@ class MTSampleToMiniBatch(Transformer):
                 yield item
         finally:
             stop.set()
+            pool.close()
             # drain so the producer can observe `stop` and exit, then
             # reap it DETERMINISTICALLY: close()/throw() mid-epoch must
             # not leave the thread (or its queued batches) behind.  The

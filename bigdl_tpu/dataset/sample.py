@@ -45,13 +45,20 @@ class Sample:
 
 
 class MiniBatch:
-    """Batched input/target pair (pytrees of arrays with leading batch dim)."""
+    """Batched input/target pair (pytrees of arrays with leading batch dim).
 
-    __slots__ = ("input", "target")
+    ``lease`` is set by an assembler that lends the batch its arrays
+    (``MTSampleToMiniBatch``): ``lease.release()`` hands them back for
+    the next batch to be written into, and is for the one consumer that
+    knows nothing reads them any more (``DeviceBlockStager``).  Everyone
+    else leaves it alone and owns the arrays as ever."""
+
+    __slots__ = ("input", "target", "lease")
 
     def __init__(self, input, target=None):
         self.input = input
         self.target = target
+        self.lease = None
 
     def size(self) -> int:
         leaf = self.input

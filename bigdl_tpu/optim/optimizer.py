@@ -1015,7 +1015,8 @@ class Optimizer:
         data_iter = self.dataset.data(train=True)
         self._fast_forward(data_iter, state)
         stager = DeviceBlockStager(data_iter, self._place_train_block,
-                                   tracer=tel.tracer if tel else None)
+                                   tracer=tel.tracer if tel else None,
+                                   registry=tel.registry if tel else None)
         self._stager = stager
         if self._resize_t0 is not None:
             # this run is the elastic resume: the driver is about to

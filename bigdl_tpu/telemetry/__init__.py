@@ -7,7 +7,8 @@ measurement-driven tuning both stand on):
 - :class:`Tracer` — step-timeline spans (four top-level spans tile a
   block of the driver's loop: stage_next, dispatch, device_wait,
   replay; planning, host stacking, batch pulls, H2D staging, step
-  arguments and triggers nest inside them — ``PHASE_CATS``), exported
+  arguments and triggers nest inside them, the assembler thread's work
+  lies beside them on a track of its own — ``PHASE_CATS``), exported
   as Chrome-trace JSON (summarize with ``python -m tools.trace_report
   trace.json``) and mirrored as ``jax.profiler.TraceAnnotation``s, so
   any profiler capture holds them beside the device's timeline;
@@ -46,8 +47,8 @@ from bigdl_tpu.telemetry.flight import FlightRecorder
 from bigdl_tpu.telemetry.hooks import DriverTelemetry
 from bigdl_tpu.telemetry.registry import (Counter, Gauge, Histogram,
                                           MetricRegistry, Reservoir)
-from bigdl_tpu.telemetry.tracer import (NULL_SPAN, PHASE_CATS,
-                                        TOP_LEVEL_CATS, Tracer)
+from bigdl_tpu.telemetry.tracer import (NULL_SPAN, OFF_DRIVER_CATS,
+                                        PHASE_CATS, TOP_LEVEL_CATS, Tracer)
 from bigdl_tpu.telemetry.watchdog import (MemoryWatermark,
                                           RecompileWatchdog, StallDetector,
                                           jit_cache_size)
@@ -55,8 +56,9 @@ from bigdl_tpu.telemetry.watchdog import (MemoryWatermark,
 __all__ = [
     "AdminServer", "Counter", "DriverTelemetry", "FlightRecorder", "Gauge",
     "Histogram", "MemoryWatermark", "MetricRegistry", "NULL_SPAN",
-    "PHASE_CATS", "RecompileWatchdog", "RequestContext", "Reservoir",
-    "StallDetector", "TOP_LEVEL_CATS", "Tracer", "jit_cache_size",
+    "OFF_DRIVER_CATS", "PHASE_CATS", "RecompileWatchdog", "RequestContext",
+    "Reservoir", "StallDetector", "TOP_LEVEL_CATS", "Tracer",
+    "jit_cache_size",
     "new_trace_id",
     "render_prometheus",
 ]

@@ -65,16 +65,23 @@ NULL_SPAN = _NullSpan()
 # whether it is TOP-LEVEL: the four top-level categories tile every
 # iteration of the driver's loop (one span of each a block, carrying
 # ``block=<dispatch index>``); the others lie inside a top-level span
-# (``plan``, ``stage``, ``step_args`` in ``stage_next``; ``batch_pull``
-# and ``block_stack`` in the ``stage`` span ``host_stack``; ``trigger``
-# in ``replay``).  tools/trace_report.py takes the list from here.
+# (``plan``, ``stage``, ``step_args`` in ``stage_next``;
+# ``buffer_return``, ``batch_pull`` and ``block_stack`` in the ``stage``
+# span ``host_stack``; ``trigger`` in ``replay``).  ``None`` marks a
+# category that lies in NO span of the driver: ``batch_assemble`` is the
+# assembler thread's work on a batch (``assemble``, on the ``assembler``
+# track, recorded by the stager from the stamps the batch carries),
+# which overlaps the driver's time.  tools/trace_report.py takes the
+# list from here.
 PHASE_CATS = {
     "stage_next": True, "plan": False, "stage": False,
-    "batch_pull": False, "block_stack": False, "step_args": False,
+    "buffer_return": False, "batch_pull": False, "block_stack": False,
+    "step_args": False,
     "dispatch": True, "device_wait": True, "replay": True,
-    "trigger": False,
+    "trigger": False, "batch_assemble": None,
 }
 TOP_LEVEL_CATS = tuple(c for c, top in PHASE_CATS.items() if top)
+OFF_DRIVER_CATS = tuple(c for c, top in PHASE_CATS.items() if top is None)
 
 _SCALARS = (bool, int, float, str)
 

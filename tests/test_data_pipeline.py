@@ -272,6 +272,178 @@ class TestMTPrefetch:
         assert 1 + len(rest) == 6
 
 
+class _FakePlaced:
+    """Stands in for a device array: a snapshot of what the host buffer
+    held when it was placed, the device's platform, and whether anyone
+    waited for it."""
+
+    def __init__(self, host, platform):
+        self.address = host.ctypes.data
+        self.snapshot = host.copy()
+        self.platform = platform
+        self.waited = False
+
+    def devices(self):
+        return {self}  # a device is what has a ``platform``
+
+    def block_until_ready(self):
+        self.waited = True
+        return self
+
+
+def _ramp_samples(n):
+    """Sample i is filled with i: any batch names its own place."""
+    for i in range(n):
+        yield Sample(np.full((64,), i, np.float32), np.int32(i))
+
+
+class TestRecycledBuffers:
+    """ISSUE 26: a block of one batch is staged as a view, and the
+    assembler stacks into buffers the stager hands back once the device
+    holds its own copy."""
+
+    @staticmethod
+    def _staged_blocks(platform, n_blocks, k=1, batch=4, registry=None):
+        from bigdl_tpu.dataset.prefetch import DeviceBlockStager
+        from bigdl_tpu.telemetry import Tracer
+        placed = []
+
+        def place(xs, ys):
+            placed.append(_FakePlaced(xs, platform))
+            return placed[-1], _FakePlaced(ys, platform)
+
+        tracer = Tracer()
+        mt = MTSampleToMiniBatch(batch, None, workers=2, prefetch=2)
+        it = mt(_ramp_samples(batch * k * n_blocks))
+        stager = DeviceBlockStager(it, place, tracer=tracer,
+                                   registry=registry)
+        for _ in range(n_blocks):
+            stager.take(k, 10**9)
+        it.close()
+        spans = [args for ph, name, _c, _t0, _d, tid, args, _f
+                 in tracer.events() if name == "assemble"]
+        return placed, spans, mt
+
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_one_batch_block_is_a_view_and_more_are_copied(self, k):
+        from bigdl_tpu.dataset.prefetch import DeviceBlockStager
+        batches = list(SampleToMiniBatch(4)(_ramp_samples(16)))
+        seen = []
+        stager = DeviceBlockStager(iter(batches),
+                                   lambda xs, ys: seen.append((xs, ys))
+                                   or (xs, ys))
+        _, _, sizes = stager.take(k, 10**9)
+        xs, ys = seen[0]
+        assert sizes == [4] * k and xs.shape == (k, 4, 64)
+        np.testing.assert_array_equal(
+            xs, np.stack([b.input for b in batches[:k]]))
+        np.testing.assert_array_equal(
+            ys, np.stack([b.target for b in batches[:k]]))
+        shares = [np.shares_memory(xs, b.input) for b in batches[:k]]
+        assert shares == ([True] if k == 1 else [False] * 4)
+        # what the data-parallel placer cuts from the view (axis 1 over
+        # the mesh) is contiguous memory: no hidden copy before the
+        # transfer (a cut through k stacked batches never was)
+        assert [xs[:, i:i + 2].flags.c_contiguous for i in (0, 2)] == \
+            [k == 1] * 2
+
+    def test_sharded_slices_of_the_view_are_contiguous(self):
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        view = np.zeros((8, 6, 6, 3), np.float32)[None]
+        sh = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("data",)),
+                           P(None, "data"))
+        cuts = sh.addressable_devices_indices_map(view.shape).values()
+        assert len(cuts) == 4
+        for ix in cuts:
+            assert view[ix].shape == (1, 2, 6, 6, 3)
+            assert view[ix].flags.c_contiguous
+
+    def test_buffers_come_back_from_a_device_and_are_reused(self):
+        from bigdl_tpu.telemetry import MetricRegistry
+        registry = MetricRegistry()
+        n, bound = 24, 2 + 3  # prefetch + 3
+        placed, spans, _ = self._staged_blocks("tpu", n,
+                                               registry=registry)
+        # every block held its own four samples when it was placed
+        for i, p in enumerate(placed):
+            np.testing.assert_array_equal(
+                p.snapshot[0, :, 0], np.arange(4 * i, 4 * i + 4))
+        assert len({p.address for p in placed}) <= bound
+        assert [a["recycled"] for a in spans[bound:]] == \
+            [True] * (n - bound)
+        assert not any(a["recycled"] for a in spans[:3])
+        assert all(a["bytes"] == 4 * (64 * 4 + 4) for a in spans)
+        counters = registry.snapshot()["counters"]
+        assert counters["input/buffers_allocated"] <= bound
+        assert counters["input/buffers_allocated"] \
+            + counters["input/buffers_recycled"] == n
+        # a buffer was written again only after its device copy was
+        # waited for
+        last_at = {}
+        for p in placed:
+            if p.address in last_at:
+                assert last_at[p.address].waited
+            last_at[p.address] = p
+
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_a_host_platform_never_recycles(self, k):
+        placed, spans, _ = self._staged_blocks("cpu", 12, k=k)
+        assert len(spans) == 12 * k
+        assert not any(a["recycled"] for a in spans)
+        assert not any(p.waited for p in placed)
+        if k == 1:  # the views: twelve buffers, none written twice
+            assert len({p.address for p in placed}) == 12
+
+    def test_a_consumer_that_hands_nothing_back_gets_fresh_arrays(self):
+        mt = MTSampleToMiniBatch(4, None, workers=2, prefetch=2)
+        batches = list(mt(_ramp_samples(24)))
+        assert len(batches) == 6
+        for i, b in enumerate(batches):
+            np.testing.assert_array_equal(b.input[:, 0],
+                                          np.arange(4 * i, 4 * i + 4))
+            np.testing.assert_array_equal(b.target,
+                                          np.arange(4 * i, 4 * i + 4))
+            assert b.lease.recycled is False
+            for other in batches[:i]:
+                assert not np.shares_memory(b.input, other.input)
+                assert not np.shares_memory(b.target, other.target)
+
+    def test_remainder_batch_is_not_pooled(self):
+        mt = MTSampleToMiniBatch(4, None, drop_remainder=False)
+        full, _, rest = list(mt(_ramp_samples(10)))
+        assert (full.size(), rest.size()) == (4, 2)
+        assert full.lease._pool is not None
+        assert rest.lease._pool is None and not rest.lease.recycled
+        rest.lease.release()  # nothing to hand back, nothing happens
+        np.testing.assert_array_equal(rest.target, [8, 9])
+
+    @pytest.mark.parametrize("how", ["close", "throw"])
+    def test_early_exit_with_buffers_out_reaps_the_thread(self, how):
+        import threading
+        before = threading.active_count()
+        mt = MTSampleToMiniBatch(4, None, workers=2, prefetch=1)
+        it = mt(_ramp_samples(4096))
+        out = [next(it), next(it), next(it)]
+        pool = out[0].lease._pool
+        out[0].lease.release()  # one came back, two stay out
+        if how == "close":
+            it.close()
+        else:
+            with pytest.raises(RuntimeError, match="step exploded"):
+                it.throw(RuntimeError("step exploded"))
+        deadline = time.time() + 5.0
+        while threading.active_count() > before and time.time() < deadline:
+            time.sleep(0.05)
+        assert threading.active_count() <= before
+        # what is out is dropped as it comes back, and stays whole
+        out[1].lease.release()
+        out[1].lease.release()
+        assert pool._free == []
+        np.testing.assert_array_equal(out[1].target, [4, 5, 6, 7])
+        np.testing.assert_array_equal(out[2].target, [8, 9, 10, 11])
+
+
 class TestReviewFixes:
     """Regressions for round-2 review findings on the data pipeline."""
 

@@ -281,6 +281,67 @@ class TestDevicePrefetchDeterminism:
                                    rtol=1e-6, atol=1e-7)
 
 
+class _FreshCopies:
+    """The input path as it was before buffers were lent: every batch
+    in fresh arrays of its own, with no way back to the assembler."""
+
+    def __call__(self, batches):
+        from bigdl_tpu.dataset.sample import MiniBatch
+        for b in batches:
+            yield MiniBatch(np.array(b.input), np.array(b.target))
+
+
+class TestRecycledBuffersTrainTheSame:
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_recycling_run_matches_the_copy_path_bitwise(self, k,
+                                                         monkeypatch):
+        """24 steps (an epoch of 20 and the start of the next) through
+        the threaded assembler with buffers really coming back (a
+        placer that copies, so the CPU backend cannot alias them, and is
+        believed to be a device) against the same steps fed fresh
+        arrays all the way: losses and parameters equal to the last
+        bit."""
+        import jax.numpy as jnp
+        from bigdl_tpu.dataset import prefetch
+        tmap = jax.tree_util.tree_map
+
+        def run(recycle):
+            data = mnist_pipeline(320, 16, mt=True)
+            if not recycle:
+                data = data >> _FreshCopies()
+            rec = RecordingSummary()
+            opt = (LocalOptimizer(small_mlp(), data,
+                                  nn.ClassNLLCriterion())
+                   .set_optim_method(optim.SGD(0.05, momentum=0.9))
+                   .set_steps_per_dispatch(k)
+                   .set_train_summary(rec)
+                   .set_telemetry(True)
+                   .set_end_when(optim.max_iteration(24)))
+            opt.optimize()
+            counters = opt._telemetry.registry.snapshot()["counters"]
+            return rec, opt, counters
+
+        ref, ref_opt, ref_counters = run(recycle=False)
+        assert ref_counters["input/buffers_recycled"] == 0
+        monkeypatch.setattr(
+            LocalOptimizer, "_place_train_block",
+            lambda self, xs, ys: (tmap(lambda a: jnp.asarray(np.array(a)),
+                                       xs),
+                                  tmap(lambda a: jnp.asarray(np.array(a)),
+                                       ys)))
+        monkeypatch.setattr(prefetch, "_may_alias_host",
+                            lambda placed: False)
+        got, got_opt, counters = run(recycle=True)
+        assert counters["input/buffers_recycled"] > 0
+        assert counters["input/buffers_recycled"] \
+            + counters["input/buffers_allocated"] == 24
+        assert got.steps == ref.steps == list(range(1, 25))
+        np.testing.assert_array_equal(got.losses, ref.losses)
+        for a, b in zip(jax.tree_util.tree_leaves(got_opt.model._params),
+                        jax.tree_util.tree_leaves(ref_opt.model._params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 class TestDispatchBudget:
     def test_fused_loop_dispatch_count_smoke(self, monkeypatch):
         """N iterations at steps_per_dispatch=K must issue

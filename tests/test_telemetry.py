@@ -42,7 +42,8 @@ from bigdl_tpu.optim.optimizer import LocalOptimizer
 from bigdl_tpu.telemetry import (MemoryWatermark, MetricRegistry,
                                  RecompileWatchdog, Reservoir,
                                  StallDetector, Tracer, jit_cache_size)
-from bigdl_tpu.telemetry.tracer import NULL_SPAN, TOP_LEVEL_CATS
+from bigdl_tpu.telemetry.tracer import (NULL_SPAN, OFF_DRIVER_CATS,
+                                        TOP_LEVEL_CATS)
 from bigdl_tpu.utils.metrics import Metrics
 from tools import trace_report
 
@@ -619,15 +620,16 @@ def wide_mlp():
 
 
 def _spans(tracer):
-    """Host spans as dicts, the virtual device track apart."""
-    host, device = [], []
+    """The driver thread's spans as dicts, the virtual tracks (the
+    device's blocks, the assembler thread's batches) apart."""
+    host, device, assembler = [], [], []
     for ph, name, cat, t0, dur, tid, args, _flow in tracer.events():
         if ph != "X":
             continue
         row = dict(name=name, cat=cat, t0=t0, t1=t0 + dur, tid=tid,
                    args=args or {})
-        (device if tid == "device" else host).append(row)
-    return host, device
+        {"device": device, "assembler": assembler}.get(tid, host).append(row)
+    return host, device, assembler
 
 
 def _inside(child, parent):
@@ -638,6 +640,7 @@ def _inside(child, parent):
 class TestDriverSpanCoverage:
     PARENT_OF = {"plan": "stage_next", "host_stack": "stage_next",
                  "h2d_stage": "stage_next", "step_args": "stage_next",
+                 "buffer_return": "host_stack",
                  "batch_pull": "host_stack", "block_stack": "host_stack",
                  "validation": "replay"}
 
@@ -663,7 +666,7 @@ class TestDriverSpanCoverage:
         opt.optimize()
         tracer = opt._telemetry.tracer
         assert tracer.dropped_events == 0
-        host, device = _spans(tracer)
+        host, device, assembled = _spans(tracer)
         n_blocks = opt._dispatch_count
         assert n_blocks == 16 // k
 
@@ -711,6 +714,26 @@ class TestDriverSpanCoverage:
             if s["name"] in ("block_stack", "h2d_stage"):
                 # k (or fewer) batches of 256 f32 images and i32 labels
                 assert s["args"]["bytes"] % (256 * (784 * 4 + 4)) == 0
+
+        # -- the assembler thread's work on every staged batch, in no
+        # span of the driver: a track of its own (the inline assembler
+        # works inside batch_pull and has none)
+        if assembler is MTSampleToMiniBatch:
+            assert len(assembled) == 16
+            for s in assembled:
+                assert (s["name"], s["cat"]) == ("assemble",
+                                                 "batch_assemble")
+                assert s["cat"] in OFF_DRIVER_CATS
+                assert s["args"]["bytes"] == 256 * (784 * 4 + 4)
+                # the CPU backend may alias a placed array: no buffer
+                # ever comes back
+                assert s["args"]["recycled"] is False
+                assert s["t0"] < s["t1"]
+            counters = opt._telemetry.registry.snapshot()["counters"]
+            assert counters["input/buffers_allocated"] == 16
+            assert counters["input/buffers_recycled"] == 0
+        else:
+            assert assembled == []
 
         # -- the four top-level categories cover the driver's time
         tops = sorted((s for s in host if s["cat"] in TOP_LEVEL_CATS),
@@ -872,11 +895,13 @@ class TestTraceReport:
         share = report["phase_share"]
         # hand-built fixture, one block of the driver's loop: stage_next
         # .3 holding plan .01, the stage spans host_stack .12 (itself
-        # holding batch_pull .05 and block_stack .04) and h2d_stage .08,
+        # holding buffer_return .005, batch_pull .05 and block_stack .04)
+        # and h2d_stage .08,
         # and step_args .07; dispatch .1, wait .4, replay .1 with a
         # nested 40ms trigger span; other .1.  Self-time splits every
         # parent; the device-track pipeline span must NOT count
-        assert share == {"stage_next": 0.02, "plan": 0.01, "stage": 0.11,
+        assert share == {"stage_next": 0.02, "plan": 0.01, "stage": 0.105,
+                         "buffer_return": 0.005,
                          "batch_pull": 0.05, "block_stack": 0.04,
                          "step_args": 0.07, "dispatch": 0.1,
                          "device_wait": 0.4, "replay": 0.06,
@@ -884,7 +909,11 @@ class TestTraceReport:
         assert sum(share.values()) == pytest.approx(1.0)
         # every category of the fixture is one the tracer declares, and
         # the top-level four cover what "other" does not
-        assert set(share) - {"other"} == set(trace_report.PHASE_CATS)
+        # the assembler's batch (250 ms on a track of its own) overlaps
+        # the driver's time: reported beside the share, not in it
+        assert report["off_driver_share"] == {"batch_assemble": 0.25}
+        assert (set(share) - {"other"}) | set(report["off_driver_share"]) \
+            == set(trace_report.PHASE_CATS)
         assert report["driver_coverage"] == 0.9
         # the stall picture takes whole spans: the stager held the
         # driver for .2, whoever worked inside

@@ -14,6 +14,10 @@ driver-pipeline picture the raw timeline buries:
   TOP-LEVEL categories (stage_next / dispatch / device_wait / replay),
   which tile the driver's loop, over the wall clock: what is left is
   host time no span names;
+- **off the driver** — the whole time of the categories that lie in no
+  span of the driver (``batch_assemble``: the assembler thread's work
+  on each batch) over the wall clock.  It overlaps the driver's time,
+  so it is no part of the phase share;
 - **top spans** — by total duration, with call counts and mean;
 - **stall picture** — device-wait fraction (host blocked on device —
   healthy when the device is the bottleneck) vs host-stage fraction
@@ -49,11 +53,13 @@ import sys
 from collections import defaultdict
 from typing import Dict, List
 
-from bigdl_tpu.telemetry.tracer import PHASE_CATS, TOP_LEVEL_CATS
+from bigdl_tpu.telemetry.tracer import (OFF_DRIVER_CATS, PHASE_CATS,
+                                        TOP_LEVEL_CATS)
 
-# spans on virtual tracks (cat "pipeline") overlap the host timeline and
-# are excluded from the phase accounting
-_EXCLUDED_CATS = {"pipeline"}
+# spans on virtual tracks (cat "pipeline", and the assembler thread's
+# work) overlap the driver's timeline and are excluded from the phase
+# accounting
+_EXCLUDED_CATS = {"pipeline", *OFF_DRIVER_CATS}
 
 
 def load_trace(path: str) -> dict:
@@ -106,6 +112,11 @@ def summarize(trace: dict, top: int = 10) -> dict:
     t0 = min(s["ts"] for s in spans)
     t1 = max(s["ts"] + s.get("dur", 0.0) for s in spans)
     wall_us = max(t1 - t0, 1e-9)
+
+    off_us: Dict[str, float] = defaultdict(float)
+    for s in spans:
+        if s.get("cat") in OFF_DRIVER_CATS:
+            off_us[s["cat"]] += s.get("dur", 0.0)
 
     self_us = _self_times(host_spans)
     cat_us: Dict[str, float] = defaultdict(float)
@@ -169,6 +180,8 @@ def summarize(trace: dict, top: int = 10) -> dict:
         # some span names (0.0 for a trace without a driver)
         "driver_coverage": round(sum(whole.get(c, 0.0)
                                      for c in TOP_LEVEL_CATS), 4),
+        "off_driver_share": {c: round(v / wall_us, 4)
+                             for c, v in off_us.items()},
         "stall": {
             # whole time: ``stage`` spans hold batch_pull/block_stack
             # children, and the stall picture asks how long the stager
@@ -203,6 +216,9 @@ def _render(report: dict, events: bool = False) -> str:
         lines.append(f"  {cat + mark:<14} {frac * 100:6.2f}%")
     lines.append(f"driver coverage (top-level spans / wall): "
                  f"{report['driver_coverage']:.3f}")
+    for cat, frac in report["off_driver_share"].items():
+        lines.append(f"off the driver's thread (whole time / wall): "
+                     f"{cat} {frac:.3f}")
     st = report["stall"]
     lines.append(
         f"stall picture: device_wait {st['device_wait_fraction']:.3f} "
