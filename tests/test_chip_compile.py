@@ -18,7 +18,9 @@ compile cache is off around the compiles; no child process; ONE file,
 so one worker loads the TPU library.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -214,3 +216,57 @@ def test_ptb_medium_grad_step_has_fused_cell(one_chip, monkeypatch):
     # fwd + bwd kernel of layer 0: MultiRNNCell hoists (and so fuses)
     # only its first cell — upper layers take Cell.step, the XLA chain
     _assert_kernel(compiled, 2)
+
+
+# ------------------------------------------- the per-step output layer
+# "name = dtype[dims]{layout} opcode(" of one HLO instruction
+_HLO_RESULT_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+\[([\d,]*)\]\S*\s+([\w\-]+)\(")
+
+
+def _relayouts(hlo_text, n_elements):
+    """The ``copy`` / ``reshape`` / ``transpose`` instructions of the
+    module whose result holds ``n_elements``: each is a pass over that
+    many elements that computes nothing (a reshape the compiler could
+    make free is a ``bitcast`` by now)."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _HLO_RESULT_RE.match(line)
+        if not m or m.group(2) not in ("copy", "reshape", "transpose"):
+            continue
+        if math.prod(int(d) for d in m.group(1).split(",") if d) \
+                == n_elements:
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("head", [
+    (nn.LogSoftMax, nn.ClassNLLCriterion),
+    (nn.SoftMax, nn.CategoricalCrossEntropy),
+], ids=["LogSoftMax", "SoftMax"])
+def test_time_distributed_head_keeps_one_logits_layout(one_chip, head):
+    """PTB-medium's head at the benchmark's batch, forward and backward:
+    ``TimeDistributed(Linear)`` -> row-wise activation ->
+    ``TimeDistributedCriterion``.  The 924 MB of logits stay (N*T, V)
+    from the matmul to the loss and back; with the activation on the
+    (N, T, V) array the compiler re-laid them out four times a step
+    (35 rows pad to 40 in the (8, 128) tiling), 23 % of the device's
+    time on the chip (PERF.md, PR 29)."""
+    N, T, H, V = 660, 35, 650, 10000
+    activation, inner = head
+    model = (nn.Sequential().add(nn.TimeDistributed(nn.Linear(H, V)))
+             .add(activation()))
+    crit = nn.TimeDistributedCriterion(inner())
+
+    def loss_fn(params, mstate, x, y):
+        out, _ = model.apply(params, mstate, x, training=True)
+        return crit.apply(out, y)
+
+    params, mstate = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    as_spec = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    compiled = _compile(
+        jax.value_and_grad(loss_fn), as_spec(params), as_spec(mstate),
+        _spec(one_chip, (N, T, H), jnp.float32),
+        _spec(one_chip, (N, T), jnp.int32))
+    assert _relayouts(compiled.as_text(), N * T * V) == []

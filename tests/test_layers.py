@@ -137,6 +137,10 @@ class TestDropout:
         np.testing.assert_allclose(d.forward(x), x)
 
 
+_ROWWISE = [(nn.LogSoftMax, jax.nn.log_softmax), (nn.SoftMax, jax.nn.softmax)]
+_ROWWISE_IDS = ["LogSoftMax", "SoftMax"]
+
+
 class TestActivations:
     @pytest.mark.parametrize("layer,fn", [
         (nn.ReLU(), lambda x: np.maximum(x, 0)),
@@ -153,6 +157,46 @@ class TestActivations:
     def test_logsoftmax_rows_sum_to_one(self):
         y = nn.LogSoftMax().forward(jax.random.normal(rng(0), (4, 7)))
         np.testing.assert_allclose(jnp.sum(jnp.exp(y), -1), 1.0, rtol=1e-5)
+
+    @pytest.mark.parametrize("shape", [(7,), (4, 7), (3, 5, 7),
+                                       (2, 3, 5, 7)],
+                             ids=["rank1", "rank2", "rank3", "rank4"])
+    @pytest.mark.parametrize("layer,ref", _ROWWISE, ids=_ROWWISE_IDS)
+    def test_rowwise_matches_jax_nn(self, layer, ref, shape):
+        """Values and gradients over the last axis, whatever the rank:
+        rank > 2 is computed on the flat (-1, C) view."""
+        x = 3.0 * jax.random.normal(rng(1), shape)
+        g = jax.random.normal(rng(2), shape)
+        y = layer().forward(x)
+        assert y.shape == x.shape
+        np.testing.assert_allclose(y, ref(x, axis=-1), rtol=0, atol=1e-6)
+        got = jax.grad(lambda v: jnp.sum(
+            layer().apply({}, {}, v)[0] * g))(x)
+        want = jax.grad(lambda v: jnp.sum(ref(v, axis=-1) * g))(x)
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+    @pytest.mark.parametrize("layer,ref", _ROWWISE, ids=_ROWWISE_IDS)
+    def test_rowwise_rank2_program_unchanged(self, layer, ref):
+        """Rank <= 2 traces the plain jax.nn call, letter for letter:
+        the image models' programs and compile-cache entries stay."""
+        x = jnp.zeros((256, 1000), jnp.float32)
+        got = jax.make_jaxpr(lambda v: layer().apply({}, {}, v)[0])(x)
+        want = jax.make_jaxpr(lambda v: ref(v, axis=-1))(x)
+        assert str(got) == str(want)
+
+    @pytest.mark.parametrize("layer", [nn.LogSoftMax, nn.SoftMax],
+                             ids=_ROWWISE_IDS)
+    def test_rowwise_rank3_computes_on_flat_view(self, layer):
+        """What lets TimeDistributed's reshapes fold: between a reshape
+        to (N*T, C) and a reshape back, no value has the input's rank."""
+        x = jnp.zeros((6, 5, 7), jnp.float32)
+        eqns = jax.make_jaxpr(
+            lambda v: layer().apply({}, {}, v)[0])(x).jaxpr.eqns
+        assert len(eqns) >= 3
+        assert eqns[0].primitive.name == eqns[-1].primitive.name == "reshape"
+        assert eqns[0].outvars[0].aval.shape == (30, 7)
+        assert all(v.aval.ndim <= 2
+                   for e in eqns[:-1] for v in e.outvars)
 
     def test_prelu_learnable(self):
         p = nn.PReLU().initialize(0)
