@@ -201,8 +201,7 @@ class CheckpointManager:
 
     def stall_fraction(self) -> float:
         """Cumulative driver-side checkpoint time over run wall time —
-        the number the async path exists to keep near zero (bench rider
-        ``checkpoint_stall_fraction``)."""
+        the number the async path exists to keep near zero."""
         if self._t_run_start is None:
             return 0.0
         wall = time.perf_counter() - self._t_run_start

@@ -23,13 +23,14 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from bigdl_tpu.utils.config import get_config
+
 
 # the checkout root (the directory holding the ``bigdl_tpu`` package)
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _default_retry_times() -> int:
-    from bigdl_tpu.utils.config import get_config
     return get_config().failure_retry_times
 
 
@@ -43,18 +44,13 @@ class _EngineState:
     # (utils/config.Config.failure_retry_times, env BIGDL_TPU_*)
     failure_retry_times: int = field(default_factory=_default_retry_times)
     # K-step dispatch fusion for the training driver loop.  None =
-    # never explicitly set at the Engine level: steps_per_dispatch()
-    # then resolves through the default chain (configure()/env >
-    # tuned_configs.json for the workload > Config dataclass default);
+    # never set at the Engine level: steps_per_dispatch() then reads
+    # the Config field (the one rule: utils/config.py);
     # Engine.set_steps_per_dispatch pins an explicit process-wide value
     steps_per_dispatch: Optional[int] = None
     # custom-kernel selection (ops/pallas_*.py): "auto" | "pallas" |
-    # "xla"; None = unset, resolved through the same default chain
+    # "xla"; None = unset, the Config field answers
     kernel_impl: Optional[str] = None
-    # process-wide workload tag (Engine.set_workload): the key tuned
-    # defaults are looked up under when a call site doesn't carry its
-    # own tag (layer construction resolving kernel_impl, for example)
-    workload: Optional[str] = None
     # whether Engine.set_xla_async_collectives has armed the XLA
     # latency-hiding scheduler flags (None = never touched)
     xla_async_collectives: Optional[bool] = None
@@ -106,13 +102,6 @@ class Engine:
     @classmethod
     def reset(cls) -> None:
         cls._state = _EngineState()
-        # the tuned-config cache is process state the Engine owns the
-        # lifecycle of: a reset must also forget any loaded
-        # tuned_configs.json so tests and multi-run processes cannot
-        # leak a prior workload's tuned defaults (regression-gated in
-        # tests/test_autotune.py)
-        from bigdl_tpu.utils import tuned
-        tuned.reset_cache()
 
     # -- topology ----------------------------------------------------------
     @classmethod
@@ -154,40 +143,13 @@ class Engine:
         return cls._state.seed
 
     @classmethod
-    def set_workload(cls, tag: Optional[str]) -> None:
-        """Tag the process-wide workload (``"ptb_lstm"``,
-        ``"wide_deep"``, …) so tuned defaults from
-        ``tuned_configs.json`` apply at call sites that don't carry
-        their own tag — layer construction resolving ``kernel_impl``,
-        for example.  ``None`` clears the tag.  Per-run tags
-        (``Optimizer.set_workload``, ``InferenceService(workload=)``)
-        take precedence over this one at their own call sites."""
-        cls._state.workload = tag
-
-    @classmethod
-    def workload(cls) -> Optional[str]:
-        return cls._state.workload
-
-    @classmethod
-    def _resolve(cls, knob: str, workload: Optional[str]):
-        """Default chain below the Engine-level setters: configure()/
-        env > tuned_configs.json (``workload@backend``) > dataclass
-        default (utils/tuned.resolve_default)."""
-        from bigdl_tpu.utils.tuned import resolve_default
-        wl = workload if workload is not None else cls._state.workload
-        value, _src = resolve_default(knob, workload=wl)
-        return value
-
-    @classmethod
-    def steps_per_dispatch(cls, workload: Optional[str] = None) -> int:
-        """How many train steps the driver fuses into one jit dispatch.
-        Resolution: :meth:`set_steps_per_dispatch` (explicit,
-        process-wide) > ``configure()``/``BIGDL_TPU_STEPS_PER_DISPATCH``
-        > tuned_configs.json for ``workload`` (or the process-wide
-        :meth:`workload` tag) > ``Config.steps_per_dispatch``."""
+    def steps_per_dispatch(cls) -> int:
+        """How many train steps the driver fuses into one jit dispatch:
+        :meth:`set_steps_per_dispatch` where it was called, else
+        ``Config.steps_per_dispatch`` (the one rule: utils/config.py)."""
         if cls._state.steps_per_dispatch is not None:
             return max(1, int(cls._state.steps_per_dispatch))
-        return max(1, int(cls._resolve("steps_per_dispatch", workload)))
+        return max(1, int(get_config().steps_per_dispatch))
 
     @classmethod
     def set_steps_per_dispatch(cls, k: int) -> None:
@@ -196,15 +158,16 @@ class Engine:
         cls._state.steps_per_dispatch = int(k)
 
     @classmethod
-    def kernel_impl(cls, workload: Optional[str] = None) -> str:
+    def kernel_impl(cls) -> str:
         """Process-wide custom-kernel choice (``auto|pallas|xla``) the
         pallas-backed layers resolve when built without an explicit
         ``impl=``; see ``Config.kernel_impl`` for the semantics and
-        ``ops.resolve_kernel_impl`` for the auto rule.  Same default
-        chain as :meth:`steps_per_dispatch`."""
+        ``ops.resolve_kernel_impl`` for the auto rule.
+        :meth:`set_kernel_impl` where it was called, else the Config
+        field."""
         if cls._state.kernel_impl is not None:
             return cls._state.kernel_impl
-        impl = cls._resolve("kernel_impl", workload)
+        impl = get_config().kernel_impl
         if impl not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"kernel_impl must be auto|pallas|xla, got {impl!r}")
@@ -219,26 +182,21 @@ class Engine:
 
     # -- serving -----------------------------------------------------------
     @classmethod
-    def serving_defaults(cls, workload: Optional[str] = None) -> dict:
+    def serving_defaults(cls) -> dict:
         """Process-wide defaults for :class:`bigdl_tpu.serving.
         InferenceService` knobs (config ``serving_*`` fields /
-        ``BIGDL_TPU_SERVING_*`` env, each below a tuned_configs.json
-        entry for ``workload``); per-service constructor args
+        ``BIGDL_TPU_SERVING_*`` env); per-service constructor args
         override.  ``row_buckets`` is the parsed-ready bucket spec
         string (``serving_row_buckets``; "" = power-of-two auto)."""
+        cfg = get_config()
         return {
-            "max_batch_size": cls._resolve("serving_max_batch_size",
-                                           workload),
-            "batch_timeout_ms": cls._resolve("serving_batch_timeout_ms",
-                                             workload),
-            "queue_capacity": cls._resolve("serving_queue_capacity",
-                                           workload),
-            "row_buckets": cls._resolve("serving_row_buckets", workload),
+            "max_batch_size": cfg.serving_max_batch_size,
+            "batch_timeout_ms": cfg.serving_batch_timeout_ms,
+            "queue_capacity": cfg.serving_queue_capacity,
+            "row_buckets": cfg.serving_row_buckets,
             # resilience: the per-request deadline a ReplicaSet stamps
-            # on submissions (0 = none) — same resolution chain as the
-            # other serving knobs so the autotuner can tune it per
-            # workload
-            "deadline_ms": cls._resolve("serving_deadline_ms", workload),
+            # on submissions (0 = none)
+            "deadline_ms": cfg.serving_deadline_ms,
         }
 
     # -- XLA collective scheduling ----------------------------------------

@@ -34,8 +34,7 @@ thing every production autoscaler converges to:
 ``step()`` is the whole brain and takes an injectable ``now`` — unit
 tests drive spike/decay scenarios deterministically with a fake clock
 and never sleep.  ``start()`` wraps it in a daemon sampling thread for
-production (``bench.py --serving`` wire mode proves a live spike scales
-up within the cooldown budget and back down when load subsides).
+production.
 
 Scale actions run ON the controller thread and block it (a grow pays
 AOT bucket warmup) — by design: while capacity is changing, sampling

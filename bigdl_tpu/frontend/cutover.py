@@ -25,8 +25,7 @@ so that never happens:
 
 The zero-dropped-requests guarantee is gated in
 ``tests/test_frontend.py`` (N hot deploys under sustained wire load,
-every accepted request resolves correctly) and measured by
-``bench.py --serving``'s wire mode.
+every accepted request resolves correctly).
 """
 
 from __future__ import annotations

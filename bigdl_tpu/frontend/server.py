@@ -1019,8 +1019,8 @@ class FrontendServer:
             # buffered response writes + TCP_NODELAY: the stdlib
             # default (unbuffered wfile) emits every header line as
             # its own segment, and Nagle + delayed-ACK turns that
-            # into ~40 ms per exchange on loopback — measured by the
-            # bench's wire_overhead_ms before this pair of lines
+            # into ~40 ms per exchange on loopback (measured before
+            # this pair of lines)
             wbufsize = 64 * 1024
             disable_nagle_algorithm = True
             # idle keep-alive connections die after this many seconds
@@ -1229,8 +1229,8 @@ class FrontendServer:
         class _Httpd(ThreadingHTTPServer):
             daemon_threads = True
             # socketserver's default backlog of 5 SYN-drops any
-            # connect burst; keep the threaded baseline comparable in
-            # the bench connection sweep
+            # connect burst; keep the threaded core comparable with
+            # the event loop's
             request_queue_size = 1024
 
             def verify_request(self, request, client_address):

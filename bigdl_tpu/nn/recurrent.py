@@ -463,9 +463,11 @@ class Recurrent(Module):
     the loop when the cell supports it (see :meth:`Cell.hoist` — one
     large MXU matmul replaces T small ones), and ``unroll`` is passed to
     ``lax.scan`` — small-batch RNN steps are dispatch-bound on TPU, so
-    unrolling the loop body amortizes per-iteration overhead (measured
-    on the PTB bench; see bench.py).  Both are exact-math
-    transformations (hoisting reassociates one float reduction)."""
+    unrolling the loop body amortizes per-iteration overhead (a
+    round-5 chip capture at batch 20, before the ledger: PERF.md
+    section 7, "decisions from chip captures before the ledger").  Both
+    are exact-math transformations (hoisting reassociates one float
+    reduction)."""
 
     def __init__(self, cell: Cell, reverse: bool = False,
                  unroll: int = 1, name: Optional[str] = None):

@@ -15,24 +15,19 @@ and grows with it.
 from bigdl_tpu.ops.registry import OPS, register_op, get_op
 
 
-def resolve_kernel_impl(override=None, workload=None) -> str:
+def resolve_kernel_impl(override=None) -> str:
     """Resolve the effective custom-kernel backend: ``"pallas"`` or
     ``"xla"``.
 
     Per-layer ``impl=`` override wins; otherwise ``Engine.kernel_impl()``
-    (explicit ``Engine.set_kernel_impl`` > ``Config.kernel_impl`` /
-    ``BIGDL_TPU_KERNEL_IMPL`` > a ``tuned_configs.json`` entry for
-    ``workload`` — or the process-wide ``Engine.set_workload`` tag —
-    > the dataclass default).  ``"auto"`` means pallas-if-supported on
-    a TPU backend and xla elsewhere — interpret-mode kernels are
-    correctness emulation, not a speedup, so auto never engages them on
-    CPU hosts (force with ``"pallas"``, which tests and the bench
-    entries do).  Runs at trace time on the host — the choice is
-    static per compiled program, one more knob the autotuner sweeps
-    (tools/autotune.py)."""
+    (explicit ``Engine.set_kernel_impl`` > ``Config.kernel_impl``).
+    ``"auto"`` means pallas-if-supported on a TPU backend and xla
+    elsewhere — interpret-mode kernels are correctness emulation, not a
+    speedup, so auto never engages them on CPU hosts (force with
+    ``"pallas"``, which the tests do).  Runs at trace time on the host
+    — the choice is static per compiled program."""
     from bigdl_tpu.engine import Engine
-    impl = override if override is not None \
-        else Engine.kernel_impl(workload=workload)
+    impl = override if override is not None else Engine.kernel_impl()
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(
             f"kernel impl must be auto|pallas|xla, got {impl!r}")

@@ -25,7 +25,8 @@ kernel accepts exactly what ``coo_spmm`` accepts.
 Backward: ``jax.custom_vjp``.  The weight gradient deliberately stays
 on XLA's scatter-add — the r5 on-chip ablation measured XLA's scatter
 as the best known formulation for the random-update weight grad
-(sort+segsum measured worse; see bench.py Wide&Deep notes) — and
+(sort+segsum measured worse: PERF.md section 7, "decisions from chip
+captures before the ledger") — and
 ``d_values`` is a row-dot also left to XLA.  The forward is where the
 fused win lives.
 
@@ -197,7 +198,7 @@ def _bag_fn(n_rows: int, interpret: bool):
         gf = g.astype(jnp.float32)
         g_rows = jnp.take(gf, rows, axis=0)  # (nnz, D)
         # weight grad: XLA's scatter-add — measured best-known for the
-        # random-update pattern (module docstring / bench r5 notes)
+        # random-update pattern (module docstring)
         d_table = jnp.zeros(table.shape, jnp.float32).at[cols].add(
             values.astype(jnp.float32)[:, None] * g_rows)
         d_values = jnp.sum(

@@ -191,7 +191,7 @@ def _gemm_fn(K: int, O: int, mode: str, has_bias: bool,
         N = xin.shape[0]
         # batch rows pad to the INPUT dtype's sublane tile minimum —
         # (8,128) f32 / (16,128) bf16 / (32,128) int8 (PALLAS_NOTES.md);
-        # an explicit block_rows (autotune knob) is itself rounded to
+        # an explicit block_rows is itself rounded to
         # that multiple and the batch pads up to a whole block count
         n_pad, bn = _pad_plan(N, xin.dtype, block_rows)
         if n_pad != N:
@@ -222,8 +222,7 @@ def _gemm_fn(K: int, O: int, mode: str, has_bias: bool,
 
 
 def int8_matmul(x, wq, wscale, bias=None, *, mode: str = "weight_only",
-                impl=None, workload=None, block_rows=None,
-                interpret=None):
+                impl=None, block_rows=None, interpret=None):
     """Quantized ``x @ wq.T (+ bias)`` — the kernel-backed inference
     primitive behind ``nn/quantized.py``.
 
@@ -235,10 +234,9 @@ def int8_matmul(x, wq, wscale, bias=None, *, mode: str = "weight_only",
       mode: ``"weight_only"`` (f32-accumulated, no activation error) or
         ``"dynamic"`` (on-the-fly int8 activations, int32 accumulate).
       impl: per-call kernel_impl override; None defers to
-        ``resolve_kernel_impl`` (Engine/Config/tuned chain).
-      block_rows: row-block size (autotune knob); None defers to the
-        config chain (explicit ``configure()`` > env > tuned
-        ``int8_gemm@backend`` entry > 0 = auto (<=128 whole-batch)).
+        ``resolve_kernel_impl`` (Engine / Config).
+      block_rows: row-block size; None defers to
+        ``Config.int8_block_rows`` (0 = auto: <=128 whole-batch).
       interpret: pallas interpret override; None = auto (True off-TPU).
 
     Returns f32 (N, O).  Unsupported shapes/modes silently take the
@@ -248,11 +246,10 @@ def int8_matmul(x, wq, wscale, bias=None, *, mode: str = "weight_only",
         raise ValueError(
             f"int8 activation mode must be one of {MODES}, got {mode!r}")
     from bigdl_tpu.ops import resolve_kernel_impl
-    eff = resolve_kernel_impl(impl, workload)
+    eff = resolve_kernel_impl(impl)
     if block_rows is None:
-        from bigdl_tpu.utils.tuned import resolve_default
-        block_rows, _src = resolve_default(
-            "int8_block_rows", workload=workload or "int8_gemm")
+        from bigdl_tpu.utils.config import get_config
+        block_rows = get_config().int8_block_rows
     N, K = x.shape
     O = wq.shape[0]
     wscale_f = wscale.reshape(-1).astype(jnp.float32)

@@ -174,16 +174,14 @@ class DistriOptimizer(Optimizer):
                 "needs a structured clip spec — use "
                 "set_gradient_clipping_by_value/_by_l2_norm (or "
                 "grad_sync=False for a custom grad_clip callable)")
-        # constructor args win; otherwise the default chain
-        # (configure()/env > tuned_configs.json for this run's workload
-        # tag > dataclass default — utils/tuned.resolve_default)
-        from bigdl_tpu.utils.tuned import resolve_default
-        wl = self.workload or Engine.workload()
+        # constructor args win; otherwise the Config fields
+        from bigdl_tpu.utils.config import get_config
+        cfg = get_config()
         wire = self.grad_wire_dtype if self.grad_wire_dtype is not None \
-            else resolve_default("grad_wire_dtype", workload=wl)[0]
+            else cfg.grad_wire_dtype
         bucket = self.grad_bucket_bytes \
             if self.grad_bucket_bytes is not None \
-            else resolve_default("grad_bucket_bytes", workload=wl)[0]
+            else cfg.grad_bucket_bytes
         self._gs_wire = grad_sync.resolve_wire_dtype(wire)
         self._gs_plan = grad_sync.build_plan(
             params, mesh.shape["data"], int(bucket))

@@ -193,10 +193,6 @@ class Optimizer:
         self.seed = 1
         # K-step dispatch fusion; None = Engine/config default
         self.steps_per_dispatch: Optional[int] = None
-        # workload tag (set_workload): the tuned_configs.json key this
-        # run's knob defaults resolve under; None = only the
-        # process-wide Engine.set_workload tag (if any) applies
-        self.workload: Optional[str] = None
 
         # driver state (reference: the state Table inside OptimMethod —
         # epoch/neval survive checkpoint/resume)
@@ -225,8 +221,8 @@ class Optimizer:
         # activation-memory policy (set_activation_memory): "none" =
         # inert (bitwise-identical driver), else remat and/or bf16
         # activation storage for HBM-bound workloads.  None = setter
-        # never called — resolved through the default chain (env/tuned
-        # entry may apply; _resolved_activation_memory)
+        # never called — Config.activation_memory answers
+        # (_resolved_activation_memory)
         self.activation_memory: Optional[str] = None
         # numeric-failure policy (set_numeric_guard): "off" | "skip" |
         # "rollback" | "abort" — see bigdl_tpu/resilience/numeric.py.
@@ -413,9 +409,9 @@ class Optimizer:
                 f"activation memory policy must be one of "
                 f"{self._ACTIVATION_POLICIES} or None, got {policy!r}")
         # an explicit None IS the inert policy, not "unset": it must
-        # override an env/tuned default the same way "none" does
+        # override a configure()/env value the same way "none" does
         # (self.activation_memory stays None only when this setter was
-        # never called — the one state the default chain may fill)
+        # never called — the one state Config may fill)
         self.activation_memory = "none" if policy is None else policy
         return self
 
@@ -461,25 +457,6 @@ class Optimizer:
         self.steps_per_dispatch = int(k)
         return self
 
-    def set_workload(self, tag: Optional[str]) -> "Optimizer":
-        """Tag this run's workload (``"ptb_lstm"``, ``"wide_deep"``, …)
-        so autotuned defaults from ``tuned_configs.json`` apply to any
-        knob still at its dataclass default: ``steps_per_dispatch``,
-        ``activation_memory`` and (DistriOptimizer) the grad-sync
-        wire/bucket knobs resolve through
-
-            explicit setter > ``BIGDL_TPU_*`` env >
-            tuned_configs.json[``tag@backend``] > dataclass default
-
-        (``utils/tuned.resolve_default``).  With no tuned entry for the
-        tag — or no tuned file at all — tagging is provably inert
-        (bitwise loss sequence, equal dispatch count; gated in
-        tests/test_autotune.py).  ``kernel_impl`` is resolved at MODEL
-        construction, before an optimizer exists — use
-        ``Engine.set_workload`` for that knob."""
-        self.workload = tag
-        return self
-
     def set_telemetry(self, enabled: bool = True,
                       trace_path: Optional[str] = None) -> "Optimizer":
         """Enable/disable the telemetry subsystem for this run
@@ -517,21 +494,17 @@ class Optimizer:
 
     # ------------------------------------------------------------- shared
     def _resolved_activation_memory(self) -> str:
-        """Per-run ``set_activation_memory`` wins; otherwise the
-        default chain (``configure()``/``BIGDL_TPU_ACTIVATION_MEMORY``
-        > tuned entry for this run's workload tag > ``"none"``).  A
-        garbage value arriving through env or a tuned file fails
-        loudly here, same as the setter would."""
+        """Per-run ``set_activation_memory`` wins; otherwise
+        ``Config.activation_memory`` (a garbage env value fails loudly
+        here, same as the setter would)."""
         if self.activation_memory is not None:
             return self.activation_memory
-        from bigdl_tpu.utils.tuned import resolve_default
-        policy, src = resolve_default(
-            "activation_memory",
-            workload=self.workload or Engine.workload())
+        from bigdl_tpu.utils.config import get_config
+        policy = get_config().activation_memory
         if policy not in self._ACTIVATION_POLICIES:
             raise ValueError(
-                f"activation_memory {policy!r} (from {src}) must be "
-                f"one of {self._ACTIVATION_POLICIES}")
+                f"Config.activation_memory {policy!r} must be one of "
+                f"{self._ACTIVATION_POLICIES}")
         return policy
 
     def _resolved_numeric_guard(self) -> str:
@@ -901,8 +874,7 @@ class Optimizer:
         fusion/pipelining design).  Returns the final (params, mstate,
         ostate) bindings."""
         state = self.state
-        k_max = self.steps_per_dispatch \
-            or Engine.steps_per_dispatch(workload=self.workload)
+        k_max = self.steps_per_dispatch or Engine.steps_per_dispatch()
         k_max = max(1, int(k_max))
         scale = self._records_scale()
         # telemetry: resolve the enable knob (per-run override → config),

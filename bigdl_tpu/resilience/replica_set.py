@@ -13,8 +13,7 @@ Contract:
   replica with the shallowest queue (ties break on the lowest index —
   deterministic).  On an 8-chip host this is the 8× fan-out of one
   ``ModelRegistry`` entry; on a CPU host N replicas emulate the topology
-  on one device (how the tier-1 tests and ``bench.py --resilience``
-  exercise every path below).
+  on one device (how the tier-1 tests exercise every path below).
 - **Per-request deadlines, propagated.**  ``deadline_ms`` stamps each
   request with a monotonic deadline that travels WITH it through the
   replica's queue (``serving/batcher._Request.deadline``): the batcher
@@ -150,7 +149,7 @@ class ReplicaSet:
                  input_spec=None, max_batch_size: Optional[int] = None,
                  batch_timeout_ms: Optional[float] = None,
                  queue_capacity: Optional[int] = None, buckets=None,
-                 workload: Optional[str] = None, name: str = "model",
+                 name: str = "model",
                  deadline_ms: Optional[float] = None,
                  max_retries: int = 2,
                  health: Optional[HealthPolicy] = None,
@@ -179,10 +178,9 @@ class ReplicaSet:
             else _flight_mod.from_config()
         self.max_retries = max(0, int(max_retries))
         if deadline_ms is None:
-            # the same explicit > env > tuned[workload] > default chain
-            # the other serving knobs resolve through
+            # the Config field, as the other serving knobs
             from bigdl_tpu.engine import Engine
-            deadline_ms = Engine.serving_defaults(workload)["deadline_ms"]
+            deadline_ms = Engine.serving_defaults()["deadline_ms"]
         self.deadline_s = (float(deadline_ms) / 1e3
                            if deadline_ms and deadline_ms > 0 else None)
         if fault_injector is None:
@@ -212,7 +210,6 @@ class ReplicaSet:
         self._devices = list(devices)
         self._policy = policy = health or HealthPolicy()
         self._input_spec = input_spec
-        self._workload = workload
         self._started = bool(start)
         self._priority_fn = priority_fn
         self._service_kw = dict(
@@ -302,7 +299,7 @@ class ReplicaSet:
             lambda a: jax.device_put(a, dev), self._base_state)
         svc = InferenceService(
             self._model, p_i, s_i, input_spec=input_spec,
-            workload=self._workload, name=f"{self.name}/r{ix}",
+            name=f"{self.name}/r{ix}",
             start=self._started, fault_injector=self._faults,
             tracer=self.tracer,
             request_tracing=self._request_tracing,

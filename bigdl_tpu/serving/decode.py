@@ -39,7 +39,7 @@ surface (queue, lifecycle flags, active count) is guarded by ``_cond``'s
 lock.  Metrics land on a :class:`~bigdl_tpu.serving.ServingMetrics`
 (dispatch accounting reads as step occupancy: ``record_dispatch(active,
 slots)`` per step, so ``mean_batch_occupancy`` is the continuous-batching
-win the bench reports).
+win).
 """
 
 from __future__ import annotations

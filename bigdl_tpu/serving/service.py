@@ -65,8 +65,7 @@ def parse_row_buckets(spec: str, max_batch_size: int) -> Tuple[int, ...]:
     - ``""`` / ``"pow2"`` — :func:`row_buckets` power-of-two auto (the
       default);
     - ``"top"`` — one bucket at ``max_batch_size`` (maximum executable
-      sharing, maximum padding — the autotuner's coarse-granularity
-      grid point);
+      sharing, maximum padding);
     - ``"pow2@16"`` — power-of-two ladder FLOORED at 16: the
       sequence-length form of the grammar (decode prefill buckets in
       ``serving/decode.py``, where ``max_batch_size`` is the max
@@ -185,17 +184,11 @@ class InferenceService:
         back-compat ``PredictionService`` path).
     max_batch_size / batch_timeout_ms / queue_capacity / buckets:
         Coalescing and backpressure knobs; ``None`` resolves from
-        ``Engine.serving_defaults(workload)`` (config ``serving_*``
-        fields / ``BIGDL_TPU_SERVING_*`` env, each sitting above a
-        ``tuned_configs.json`` entry for ``workload`` and the
-        dataclass default — the documented resolution chain).
+        ``Engine.serving_defaults()`` (config ``serving_*`` fields /
+        ``BIGDL_TPU_SERVING_*`` env).
         ``buckets`` is either an explicit ascending int tuple or a
         :func:`parse_row_buckets` spec string ("pow2" / "top" /
         "8,16,32").
-    workload:
-        Tuned-config key this service's knob defaults resolve under
-        (e.g. the tag ``tools/autotune.py --workload`` tuned).  None =
-        config/env/dataclass defaults only.
     start:
         ``start=False`` builds the service with the batcher parked —
         requests queue (bounded) until :meth:`start`.  Used by tests to
@@ -204,9 +197,9 @@ class InferenceService:
     fault_injector:
         Optional :class:`~bigdl_tpu.resilience.faults.FaultInjector`
         consulted once per coalesced dispatch (keyed by this service's
-        own dispatch counter) — the chaos hook the resilience tests and
-        ``bench.py --resilience`` drive.  ``None`` (the default) is the
-        provably-inert state: the dispatch path never touches it.
+        own dispatch counter) — the chaos hook the resilience tests
+        drive.  ``None`` (the default) is the provably-inert state: the
+        dispatch path never touches it.
     priority_fn:
         Optional QoS preemption hook handed to the
         :class:`~bigdl_tpu.serving.batcher.RequestBatcher`: maps an
@@ -231,14 +224,12 @@ class InferenceService:
                  input_spec=None, max_batch_size: Optional[int] = None,
                  batch_timeout_ms: Optional[float] = None,
                  queue_capacity: Optional[int] = None,
-                 buckets=None, workload: Optional[str] = None,
-                 name: str = "model", start: bool = True,
+                 buckets=None, name: str = "model", start: bool = True,
                  fault_injector=None, tracer=None,
                  request_tracing: Optional[bool] = None,
                  priority_fn=None):
         from bigdl_tpu.engine import Engine
-        self.workload = workload
-        defaults = Engine.serving_defaults(workload)
+        defaults = Engine.serving_defaults()
         self.model = model
         if params is None:
             model._ensure_init()

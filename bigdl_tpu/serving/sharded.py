@@ -137,7 +137,7 @@ class ShardedReplicaSet(ReplicaSet):
             self._base_state)
         svc = InferenceService(
             self._model, p_i, s_i, input_spec=input_spec,
-            workload=self._workload, name=f"{self.name}/r{ix}",
+            name=f"{self.name}/r{ix}",
             start=self._started, fault_injector=self._faults,
             tracer=self.tracer,
             request_tracing=self._request_tracing,

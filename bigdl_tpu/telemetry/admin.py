@@ -35,8 +35,7 @@ Inertness contract: with ``admin_port == 0`` nothing here is ever
 constructed — no socket, no thread (the zero-extra-threads gate in
 ``tests/test_obs_plane.py``).  The serving/driver hot paths never call
 into this module; the scrape path only READS registry snapshots (each
-under its own lock) — rendering cost is paid by the scraper's thread,
-measured by ``bench.py --serving``'s ``admin_scrape_overhead`` point.
+under its own lock) — rendering cost is paid by the scraper's thread.
 """
 
 from __future__ import annotations

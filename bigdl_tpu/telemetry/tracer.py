@@ -140,8 +140,8 @@ class Tracer:
         self._events: List[Tuple] = []  # guarded-by: _lock
         self._dropped = 0               # write-guarded-by: _lock
         # the mirror's annotation class: imported when a tracer is made
-        # enabled (or first spans after ``enabled`` was switched on —
-        # bench.py does that), never on the off path
+        # enabled (or first spans after ``enabled`` was switched on),
+        # never on the off path
         self._annotation = _trace_annotation() if self.enabled else None
 
     # -- recording ---------------------------------------------------------
@@ -225,8 +225,8 @@ class Tracer:
 
     def phase_totals(self) -> Dict[str, float]:
         """Seconds per span category (instants excluded) — the cheap
-        aggregate ``bench._measure`` consumes; the full self-time
-        attribution lives in ``tools/trace_report.py``."""
+        aggregate; the full self-time attribution lives in
+        ``tools/trace_report.py``."""
         totals: Dict[str, float] = {}
         for ph, _name, cat, _t0, dur_ns, _tid, _args, _flow in self.events():
             if ph != "X":

@@ -7,14 +7,19 @@ Reference: the ``bigdl.*`` Java system properties scattered across
 + per-example scopt parsers.  SURVEY §5 flags the lack of one typed
 config object as a thing for the new build to centralize — this is it.
 
-Resolution order (later wins): dataclass defaults → per-workload
-``tuned_configs.json`` entries (autotuner output, consumed through
-``utils/tuned.resolve_default`` — only where a call site supplies a
-workload tag) → ``BIGDL_TPU_*`` environment variables → explicit
-``configure(**kw)`` calls.  The config records WHERE each field's value
-came from (``Config.source``: "default" | "env" | "explicit") so the
-tuned layer can slot in below env without guessing — a field that still
-carries its dataclass default is the only place a tuned value may apply.
+THE ONE RULE for what value a knob has (first that answers wins):
+
+    per-object setter or constructor argument
+      > ``Engine.set_*`` (process-wide)
+      > ``configure(**kw)``
+      > ``BIGDL_TPU_<FIELD>`` environment variable
+      > dataclass default
+
+The last three are this module: ``get_config()`` builds the one
+``Config`` from the defaults and the environment, and ``configure()``
+overwrites its fields.  The first two live where the object is: a call
+site holds ``None`` for "never set" and then reads ``get_config()`` (or
+the ``Engine`` accessor that does).  Nothing else decides a value.
 """
 
 from __future__ import annotations
@@ -102,9 +107,8 @@ class Config:
     # mode off-TPU); "auto" = pallas-if-supported on a TPU backend, xla
     # elsewhere (interpret-mode kernels are correctness-emulation, not
     # a speedup, so auto never engages them on CPU hosts).  Resolved
-    # through Engine.kernel_impl() so the autotuner (ROADMAP item 3)
-    # inherits kernel choice as one more measured knob.  Env:
-    # BIGDL_TPU_KERNEL_IMPL.  Per-layer ``impl=`` constructor args win.
+    # through Engine.kernel_impl().  Env: BIGDL_TPU_KERNEL_IMPL.
+    # Per-layer ``impl=`` constructor args win.
     kernel_impl: str = "auto"
     # int8 quantized inference (nn/quantized.py over
     # ops/pallas_int8_gemm.py).  int8_activation_mode is the default
@@ -114,16 +118,15 @@ class Config:
     # default) or "dynamic" (BigQuant-style on-the-fly int8
     # activations, int32 accumulate).  int8_block_rows is the GEMM
     # row-block size, 0 = auto (<=128 whole-batch, else 128-row
-    # blocks) — an autotuner knob like kernel_impl.  Env:
-    # BIGDL_TPU_INT8_ACTIVATION_MODE / BIGDL_TPU_INT8_BLOCK_ROWS.
+    # blocks).  Env: BIGDL_TPU_INT8_ACTIVATION_MODE /
+    # BIGDL_TPU_INT8_BLOCK_ROWS.
     int8_activation_mode: str = "weight_only"
     int8_block_rows: int = 0
     # activation-memory policy default (Optimizer.set_activation_memory
     # overrides per run): "none" | "dots" | "full" | "bf16" |
     # "bf16+dots" | "bf16+full" — remat / bf16 activation storage for
     # HBM-bound workloads (see optim/optimizer.py for the semantics).
-    # One more autotuner knob: tuned_configs.json can set it per
-    # workload.  Env: BIGDL_TPU_ACTIVATION_MEMORY.
+    # Env: BIGDL_TPU_ACTIVATION_MEMORY.
     activation_memory: str = "none"
     # serving row-bucket set: "" or "pow2" = power-of-two buckets up to
     # serving_max_batch_size (serving.row_buckets — the default);
@@ -249,17 +252,6 @@ class Config:
     mesh_model: int = 1
     mesh_seq: int = 1
     mesh_pipe: int = 1
-    # provenance: field name -> "env" | "explicit" for every field that
-    # was overridden; absent = still the dataclass default (the one
-    # state where a tuned_configs.json value may apply — see
-    # utils/tuned.resolve_default).  Private: not an env-settable knob.
-    _sources: dict = dataclasses.field(default_factory=dict, repr=False,
-                                       compare=False)
-
-    def source(self, name: str) -> str:
-        """Where ``name``'s current value came from: ``"default"`` |
-        ``"env"`` | ``"explicit"``."""
-        return self._sources.get(name, "default")
 
     @staticmethod
     def _coerce(value: str, typ):
@@ -271,21 +263,17 @@ class Config:
     def from_env(cls) -> "Config":
         cfg = cls()
         for f in dataclasses.fields(cls):
-            if f.name.startswith("_"):
-                continue  # bookkeeping, not a knob
             env = _ENV_PREFIX + f.name.upper()
             if env in os.environ:
                 setattr(cfg, f.name,
                         cls._coerce(os.environ[env], type(getattr(cfg,
                                                                   f.name))))
-                cfg._sources[f.name] = "env"
         # short alias: BIGDL_TPU_TELEMETRY=1 ⇔ BIGDL_TPU_TELEMETRY_ENABLED=1
         # (the explicit long form wins when both are set)
         alias = _ENV_PREFIX + "TELEMETRY"
         if alias in os.environ and \
                 _ENV_PREFIX + "TELEMETRY_ENABLED" not in os.environ:
             cfg.telemetry_enabled = cls._coerce(os.environ[alias], bool)
-            cfg._sources["telemetry_enabled"] = "env"
         return cfg
 
 
@@ -304,16 +292,15 @@ def get_config() -> Config:
 
 
 def configure(**kw) -> Config:
-    """Override config fields programmatically (highest precedence)."""
+    """Override config fields programmatically (above the environment,
+    below ``Engine.set_*`` and per-object setters: the module docstring)."""
     cfg = get_config()
     for k, v in kw.items():
         if k.startswith("_") or not hasattr(cfg, k):
-            names = [f.name for f in dataclasses.fields(Config)
-                     if not f.name.startswith("_")]
+            names = [f.name for f in dataclasses.fields(Config)]
             raise AttributeError(
                 f"unknown config field {k!r}; fields: {names}")
         setattr(cfg, k, v)
-        cfg._sources[k] = "explicit"
     if "debug_nans" in kw:
         apply_debug_config(cfg)
     return cfg

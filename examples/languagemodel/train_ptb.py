@@ -34,7 +34,7 @@ def main():
     p.add_argument("--layers", type=int, default=2)
     p.add_argument("--scan-unroll", type=int, default=1,
                    help="unroll the time loop (exact math; speeds up "
-                        "small-batch RNNs on TPU, see bench.py)")
+                        "small-batch RNNs on TPU, see PERF.md)")
     p.add_argument("--cpu", action="store_true")
     args = p.parse_args()
     if args.cpu:
@@ -76,9 +76,9 @@ def main():
         nn.CrossEntropyCriterion(), size_average=True)
     optimizer = (optim.LocalOptimizer(model, ds, criterion)
                  .set_optim_method(optim.Adam(learning_rate=0.01))
-                 # LSTM steps are 3-5 ms — host dispatch is the measured
-                 # bottleneck; K=8 is the production default for this
-                 # workload class (bench.PRODUCTION_K, round-6 ablation)
+                 # LSTM steps at this size are a few ms — host dispatch
+                 # is the bottleneck; K=8 is what the benchmark's PTB
+                 # cell runs too (PERF.md section 4)
                  .set_steps_per_dispatch(8)
                  .set_end_when(optim.max_epoch(args.max_epoch)))
     optimizer.optimize()

@@ -32,7 +32,7 @@ def main():
     p.add_argument("--learning-rate", type=float, default=0.005)
     p.add_argument("--scan-unroll", type=int, default=1,
                    help="unroll the time loop (exact math; speeds up "
-                        "small-batch RNNs on TPU, see bench.py)")
+                        "small-batch RNNs on TPU, see PERF.md)")
     p.add_argument("--cpu", action="store_true")
     args = p.parse_args()
 

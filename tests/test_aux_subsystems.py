@@ -119,6 +119,232 @@ class TestConfig:
             C.get_config().failure_retry_times
 
 
+def _tiny_optimizer(k=None):
+    from bigdl_tpu.dataset import DataSet, Sample, SampleToMiniBatch
+    rng = np.random.default_rng(0)
+    samples = [Sample(rng.normal(0, 1, (16,)).astype(np.float32),
+                      np.int32(rng.integers(0, 4)))
+               for _ in range(64)]
+    model = nn.Sequential(nn.Linear(16, 16), nn.ReLU(),
+                          nn.Linear(16, 4), nn.LogSoftMax())
+    opt = (optim.LocalOptimizer(model,
+                                DataSet.array(samples)
+                                >> SampleToMiniBatch(8),
+                                nn.ClassNLLCriterion())
+           .set_optim_method(optim.SGD(learning_rate=0.1))
+           .set_end_when(optim.max_iteration(8)))
+    if k is not None:
+        opt.set_steps_per_dispatch(k)
+    return opt
+
+
+def _grad_sync_of(**ctor):
+    """(wire dtype, bucket count) DistriOptimizer's own
+    ``_resolve_grad_sync`` settles on for a model of two Linear layers:
+    272 + 68 f32 elements, the larger bias 16."""
+    from bigdl_tpu.engine import Engine
+    from bigdl_tpu.optim.distri_optimizer import DistriOptimizer
+    model = nn.Sequential(nn.Linear(16, 16), nn.ReLU(),
+                          nn.Linear(16, 4), nn.LogSoftMax()).initialize(0)
+    opt = DistriOptimizer(model, None, nn.ClassNLLCriterion(),
+                          parameter_sharding=True, **ctor)
+    opt._resolve_grad_sync(Engine.get_mesh(), model._params)
+    return opt._gs_wire, len(opt._gs_plan.buckets)
+
+
+def _int8_block_rows_of(monkeypatch, **call):
+    """The ``block_rows`` ``int8_matmul`` hands its row plan."""
+    from bigdl_tpu.ops import pallas_int8_gemm as g
+    seen = []
+    real = g._pad_plan
+
+    def spy(N, dtype, block_rows):
+        seen.append(block_rows)
+        return real(N, dtype, block_rows)
+
+    monkeypatch.setattr(g, "_pad_plan", spy)
+    x = jnp.ones((256, 128), jnp.float32)
+    wq = jnp.ones((128, 128), jnp.int8)
+    g.int8_matmul(x, wq, jnp.ones((128,), jnp.float32), **call)
+    assert seen
+    return seen[0]
+
+
+class TestKnobResolution:
+    """The one rule (``utils/config.py``): per-object setter or
+    constructor argument > ``Engine.set_*`` > ``configure()`` >
+    ``BIGDL_TPU_*`` > dataclass default — walked from the bottom up at
+    the call site the product itself resolves each knob at."""
+
+    @pytest.fixture(autouse=True)
+    def _fresh(self):
+        from bigdl_tpu.engine import Engine
+        from bigdl_tpu.utils.config import reset_config
+        reset_config()
+        Engine.reset()
+        yield
+        reset_config()
+        Engine.reset()
+
+    # knob, env string, the value the env gives, a configure() value
+    CASES = [
+        ("steps_per_dispatch", "5", 5, 7),
+        ("grad_wire_dtype", "f16", "f16", "bf16"),
+        ("kernel_impl", "pallas", "pallas", "xla"),
+        ("activation_memory", "dots", "dots", "full"),
+        ("grad_bucket_bytes", "1048576", 1 << 20, 2 << 20),
+        ("int8_block_rows", "64", 64, 128),
+        ("serving_max_batch_size", "16", 16, 8),
+        ("serving_batch_timeout_ms", "1.5", 1.5, 0.5),
+        ("serving_queue_capacity", "64", 64, 128),
+        ("serving_row_buckets", "top", "top", "8,16,32"),
+        ("serving_deadline_ms", "250", 250.0, 100.0),
+    ]
+
+    @pytest.mark.parametrize("knob,envs,envv,expl", CASES)
+    def test_chain(self, monkeypatch, knob, envs, envv, expl):
+        from bigdl_tpu.utils.config import (Config, configure, get_config,
+                                            reset_config)
+        default = getattr(Config(), knob)
+        assert default not in (envv, expl)
+        assert getattr(get_config(), knob) == default
+        monkeypatch.setenv("BIGDL_TPU_" + knob.upper(), envs)
+        reset_config()
+        got = getattr(get_config(), knob)
+        assert got == envv and type(got) is type(default)
+        configure(**{knob: expl})
+        assert getattr(get_config(), knob) == expl
+        # a reset forgets configure(), not the environment
+        reset_config()
+        assert getattr(get_config(), knob) == envv
+
+    def test_engine_steps_per_dispatch_chain(self, monkeypatch):
+        """All five levels, by the dispatches the driver issues for 8
+        iterations (one epoch of 8 batches)."""
+        from bigdl_tpu.engine import Engine
+        from bigdl_tpu.utils.config import configure, reset_config
+
+        def dispatches(k=None):
+            opt = _tiny_optimizer(k)
+            opt.optimize()
+            return opt._dispatch_count
+
+        assert Engine.steps_per_dispatch() == 1
+        assert dispatches() == 8
+        monkeypatch.setenv("BIGDL_TPU_STEPS_PER_DISPATCH", "2")
+        reset_config()
+        assert Engine.steps_per_dispatch() == 2
+        assert dispatches() == 4
+        configure(steps_per_dispatch=4)
+        assert Engine.steps_per_dispatch() == 4
+        assert dispatches() == 2
+        Engine.set_steps_per_dispatch(8)
+        assert Engine.steps_per_dispatch() == 8
+        assert dispatches() == 1
+        # the optimizer's own setter tops everything
+        assert dispatches(k=1) == 8
+
+    def test_engine_kernel_impl_chain(self, monkeypatch):
+        from bigdl_tpu.engine import Engine
+        from bigdl_tpu.ops import resolve_kernel_impl
+        from bigdl_tpu.utils.config import configure, reset_config
+        assert Engine.kernel_impl() == "auto"
+        assert resolve_kernel_impl() == "xla"  # auto, off the TPU
+        monkeypatch.setenv("BIGDL_TPU_KERNEL_IMPL", "pallas")
+        reset_config()
+        assert Engine.kernel_impl() == "pallas"
+        configure(kernel_impl="xla")
+        assert Engine.kernel_impl() == "xla"
+        Engine.set_kernel_impl("pallas")
+        assert resolve_kernel_impl() == "pallas"
+        # a layer's own impl= tops everything
+        assert resolve_kernel_impl("xla") == "xla"
+        monkeypatch.setenv("BIGDL_TPU_KERNEL_IMPL", "mosaic")
+        reset_config()
+        Engine.reset()
+        with pytest.raises(ValueError):
+            Engine.kernel_impl()
+
+    def test_activation_memory_explicit_none_beats_env(self, monkeypatch):
+        """set_activation_memory(None) is the documented INERT policy,
+        not 'unset': it must override a configure()/env value exactly
+        like 'none' does (only a never-called setter lets Config fill
+        the knob)."""
+        from bigdl_tpu.utils.config import configure, reset_config
+
+        def opt():
+            model = nn.Sequential(nn.Linear(4, 2), nn.LogSoftMax())
+            return optim.LocalOptimizer(model, None,
+                                        nn.ClassNLLCriterion())
+
+        assert opt()._resolved_activation_memory() == "none"
+        monkeypatch.setenv("BIGDL_TPU_ACTIVATION_MEMORY", "full")
+        reset_config()
+        assert opt()._resolved_activation_memory() == "full"
+        configure(activation_memory="dots")
+        assert opt()._resolved_activation_memory() == "dots"
+        assert opt().set_activation_memory(
+            "bf16")._resolved_activation_memory() == "bf16"
+        assert opt().set_activation_memory(
+            None)._resolved_activation_memory() == "none"
+        # garbage from the environment fails where it is resolved
+        monkeypatch.setenv("BIGDL_TPU_ACTIVATION_MEMORY", "most")
+        reset_config()
+        with pytest.raises(ValueError):
+            opt()._resolved_activation_memory()
+
+    def test_grad_sync_knobs_chain(self, monkeypatch):
+        from bigdl_tpu.utils.config import configure, reset_config
+        assert _grad_sync_of() == (jnp.float32, 1)
+        monkeypatch.setenv("BIGDL_TPU_GRAD_WIRE_DTYPE", "f16")
+        monkeypatch.setenv("BIGDL_TPU_GRAD_BUCKET_BYTES", "64")
+        reset_config()
+        assert _grad_sync_of() == (jnp.float16, 4)  # a leaf a bucket
+        configure(grad_wire_dtype="bf16", grad_bucket_bytes=1100)
+        assert _grad_sync_of() == (jnp.bfloat16, 2)  # a layer a bucket
+        # constructor arguments top everything
+        assert _grad_sync_of(grad_wire_dtype="f32",
+                             grad_bucket_bytes=1 << 20) == (jnp.float32, 1)
+
+    def test_serving_defaults_from_the_environment(self, monkeypatch):
+        from bigdl_tpu.engine import Engine
+        from bigdl_tpu.serving import InferenceService
+        from bigdl_tpu.utils.config import configure, reset_config
+        d0 = Engine.serving_defaults()
+        assert d0["max_batch_size"] == 32 and d0["row_buckets"] == ""
+        monkeypatch.setenv("BIGDL_TPU_SERVING_MAX_BATCH_SIZE", "16")
+        monkeypatch.setenv("BIGDL_TPU_SERVING_BATCH_TIMEOUT_MS", "1.5")
+        monkeypatch.setenv("BIGDL_TPU_SERVING_ROW_BUCKETS", "top")
+        reset_config()
+        d = Engine.serving_defaults()
+        assert d["max_batch_size"] == 16
+        assert d["batch_timeout_ms"] == 1.5
+        assert d["row_buckets"] == "top"
+        configure(serving_batch_timeout_ms=0.5)
+        model = nn.Sequential(nn.Linear(16, 4)).initialize(0)
+        svc = InferenceService(model, start=False)
+        assert (svc.max_batch_size, svc.batch_timeout_ms, svc.buckets) \
+            == (16, 0.5, (16,))
+        svc.stop()
+        # constructor arguments top everything
+        svc = InferenceService(model, max_batch_size=8, buckets="pow2",
+                               start=False)
+        assert (svc.max_batch_size, svc.buckets) == (8, (1, 2, 4, 8))
+        svc.stop()
+
+    def test_configured_block_rows_picked_up_by_kernel(self, monkeypatch):
+        """int8_matmul's block_rows=None defers to
+        ``Config.int8_block_rows``; an explicit argument beats it."""
+        from bigdl_tpu.utils.config import configure, reset_config
+        assert _int8_block_rows_of(monkeypatch) == 0
+        monkeypatch.setenv("BIGDL_TPU_INT8_BLOCK_ROWS", "64")
+        reset_config()
+        assert _int8_block_rows_of(monkeypatch) == 64
+        configure(int8_block_rows=128)
+        assert _int8_block_rows_of(monkeypatch) == 128
+        assert _int8_block_rows_of(monkeypatch, block_rows=32) == 32
+
+
 class TestControlFlowImport:
     def _cond_graph(self, tmp_path):
         from bigdl_tpu.utils import protowire as pw

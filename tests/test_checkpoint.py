@@ -790,9 +790,9 @@ class TestAsyncInertness:
     def test_async_driver_stall_much_smaller_than_write(self, tmp_path):
         """The point of async: the driver-side stall per snapshot is a
         fraction of the full serialize+CRC+fsync the writer thread
-        pays.  (The bench rider records the production-sized numbers;
-        this just pins the ordering so a regression that moves the
-        write back inline fails loudly.)"""
+        pays.  (No benchmark cell checkpoints yet; this just pins the
+        ordering so a regression that moves the write back inline
+        fails loudly.)"""
         opt = build_opt(str(tmp_path / "ck"), iters=12, k=4, every=2)
         opt.optimize()
         reg = opt.metrics.registry

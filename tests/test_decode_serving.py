@@ -27,8 +27,7 @@ The load-bearing gates:
   ``ChunkedDecoder``; malformed framing answers 400, the body cap
   413, TE+CL smuggling 400, unknown codings 501.
 
-Tiny models throughout; the serving-scale numbers live in
-``bench.py --serving``, not tier-1.
+Tiny models throughout; no benchmark cell serves yet (PERF.md 7b).
 """
 
 import http.client

@@ -20,8 +20,7 @@ The load-bearing gates:
   serve every request; gracefully skipped where the platform lacks
   ``SO_REUSEPORT``.
 
-Everything here runs tiny models and sub-second timeouts — the 10k
-connection number lives in ``bench.py --serving``, not tier-1.
+Everything here runs tiny models and sub-second timeouts.
 """
 
 import http.client
@@ -423,8 +422,7 @@ class TestReaperAndCap:
         try:
             wait_until(lambda: fe.open_connections == 3,
                        what="3 idle conns admitted")
-            # active traffic flows with the idle flood parked (the
-            # 10k-scale version of this is bench.py --serving)
+            # active traffic flows with the idle flood parked
             x = rows(np.random.default_rng(8), 2)
             for _ in range(3):
                 # the previous post's server-side conn releases

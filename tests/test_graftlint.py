@@ -915,7 +915,7 @@ class TestThreadLifecycle:
         assert vs[0].line > 10
 
     def test_negative_listcomp_bound_threads_joined_via_loop(self):
-        # the bench/autotune shape: a pool of workers joined through
+        # a sweep's shape: a pool of workers joined through
         # iteration over the container binding
         assert rule_ids("""
             import threading
@@ -2393,20 +2393,21 @@ class TestStatsCLI:
         doc = json.loads(r.stdout)
         assert {v["rule"] for v in doc["violations"]} == {"GL204"}
 
-    def test_default_paths_cover_tools_and_bench(self, tmp_path):
+    def test_default_paths_cover_tools(self, tmp_path):
         # ISSUE-15 satellite: the bare CLI gate extends past bigdl_tpu
-        # to tools/ and bench.py (threaded helper code is product
-        # too).  Exercised against a stub tree so the default-path
-        # resolution is gated end-to-end without a full-repo scan
+        # to tools/ (threaded helper code is product too) and to
+        # nothing else at the root.  Exercised against a stub tree so
+        # the default-path resolution is gated end-to-end without a
+        # full-repo scan
         # (the real tree's cleanliness is TestRealTree's job).
         (tmp_path / "bigdl_tpu").mkdir()
         (tmp_path / "bigdl_tpu" / "m.py").write_text("x = 1\n")
         (tmp_path / "tools").mkdir()
         (tmp_path / "tools" / "t.py").write_text("y = 2\n")
-        (tmp_path / "bench.py").write_text("z = 3\n")
+        (tmp_path / "script.py").write_text("z = 3\n")
         r = run_cli("--json", cwd=str(tmp_path))
         doc = json.loads(r.stdout)
-        assert doc["files_scanned"] == 3
+        assert doc["files_scanned"] == 2
 
 
 # ===========================================================================
@@ -2421,8 +2422,7 @@ def full_tree_scan():
     old = os.getcwd()
     os.chdir(REPO)  # baseline keys and violation paths are repo-relative
     try:
-        return core.lint_paths_with_stats(["bigdl_tpu", "tools",
-                                           "bench.py"])
+        return core.lint_paths_with_stats(["bigdl_tpu", "tools"])
     finally:
         os.chdir(old)
 
@@ -2607,9 +2607,9 @@ class TestRealTree:
             "suppression with a justification:\n" + msgs)
 
     def test_tools_lint_clean_too(self, full_tree_scan):
-        # ISSUE-15 satellite: the gate covers the tools/ tree AND
-        # bench.py (threaded helper code is product code) — same bar
-        # as the library: zero findings, not just zero errors
+        # ISSUE-15 satellite: the gate covers the tools/ tree
+        # (threaded helper code is product code) — same bar as the
+        # library: zero findings, not just zero errors
         result, _ = full_tree_scan
         rest = [v for v in result.violations
                 if not v.path.startswith("bigdl_tpu")]
@@ -2654,21 +2654,6 @@ class TestRealTree:
             os.path.join(REPO, "bigdl_tpu", "ops",
                          "pallas_int8_gemm.py"),
             os.path.join(REPO, "bigdl_tpu", "nn", "quantized.py")])
-        assert result.files_scanned == 2
-        msgs = "\n".join(v.render() for v in result.violations)
-        assert result.violations == [], msgs
-
-    def test_autotuner_lints_clean(self):
-        """Standalone gate for the autotuner (round-11, ISSUE-9):
-        tools/autotune.py is pure host-side search/driver code — every
-        measurement rides bench._measure or the serving engine, so any
-        traced-scope hazard surfacing here means search code leaked
-        into a jit.  utils/tuned.py (the consumption side) rides the
-        bigdl_tpu gate above but is host-side-only by the same
-        contract, so it gets the explicit gate too."""
-        result = lint_paths([os.path.join(REPO, "tools", "autotune.py"),
-                             os.path.join(REPO, "bigdl_tpu", "utils",
-                                          "tuned.py")])
         assert result.files_scanned == 2
         msgs = "\n".join(v.render() for v in result.violations)
         assert result.violations == [], msgs

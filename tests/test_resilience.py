@@ -505,8 +505,8 @@ class TestReplicaSet:
         rs.stop()
 
     def test_deadline_default_resolves_through_engine_chain(self):
-        # serving_deadline_ms rides the same explicit > env > tuned >
-        # default chain as the other serving knobs
+        # serving_deadline_ms resolves as the other serving knobs do:
+        # constructor argument > configure() > env > default
         configure(serving_deadline_ms=75.0)
         try:
             rs = self._set(start=False)

@@ -38,8 +38,8 @@ class TestPhaseMaxPool:
 
 class TestPallasPoolBwd:
     """First-match parity vs XLA select-and-scatter, via pallas
-    interpret mode (runs on CPU; the compiled path is exercised on the
-    real chip by bench.py)."""
+    interpret mode (runs on CPU; the kernel is compiled for a described
+    v5e by tests/test_chip_compile.py)."""
 
     CASES = [
         ((2, 16, 16, 64), (3, 3), (2, 2), ((0, 1), (0, 1))),

@@ -66,6 +66,30 @@ class TestBuckets:
         svc.stop()
 
 
+class TestServingKnobs:
+    """``parse_row_buckets``: the ``Config.serving_row_buckets`` spec
+    grammar."""
+
+    def test_spec_grammar(self):
+        from bigdl_tpu.serving.service import parse_row_buckets
+        assert parse_row_buckets("", 32) == (1, 2, 4, 8, 16, 32)
+        assert parse_row_buckets("pow2", 32) == (1, 2, 4, 8, 16, 32)
+        assert parse_row_buckets("top", 32) == (32,)
+        assert parse_row_buckets("8,16,32", 32) == (8, 16, 32)
+
+    @pytest.mark.parametrize("spec", ["8,x", "16,8", "8,8,16", "0,8",
+                                      "4,8"])
+    def test_bad_specs_rejected(self, spec):
+        from bigdl_tpu.serving.service import parse_row_buckets
+        with pytest.raises(ValueError):
+            parse_row_buckets(spec, 32)
+
+    def test_explicit_tuple_validated_through_same_grammar(self):
+        from bigdl_tpu.serving.service import parse_row_buckets
+        with pytest.raises(ValueError):
+            parse_row_buckets("16,8", 8)
+
+
 class TestCoalescing:
     """The acceptance gate: 16-thread single-row load."""
 

@@ -832,8 +832,8 @@ class TestProfilerMirror:
         off = Tracer(enabled=False)
         assert off.span("a", cat="stage", block=1) is NULL_SPAN
         assert off._annotation is None  # jax.profiler left alone
-        # a tracer switched on after it was made (bench.py's warm-up
-        # windows) mirrors from its first span on
+        # a tracer switched on after it was made mirrors from its
+        # first span on
         off.enabled = True
         with off.span("a", cat="stage"):
             pass

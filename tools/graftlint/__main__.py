@@ -73,9 +73,9 @@ def main(argv=None) -> int:
               f"--format {args.format}", file=sys.stderr)
         return 2
 
-    # default gate paths: the library AND the tools/ tree (bench.py
-    # helpers and tools/*.py threaded code are part of the product)
-    paths = args.paths or [p for p in ("bigdl_tpu", "tools", "bench.py")
+    # default gate paths: the library AND the tools/ tree (tools/*.py
+    # threaded code is part of the product)
+    paths = args.paths or [p for p in ("bigdl_tpu", "tools")
                            if os.path.exists(p)] or ["bigdl_tpu"]
     for p in paths:
         if not os.path.exists(p):

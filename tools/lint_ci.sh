@@ -3,7 +3,7 @@
 # consumes — the SARIF report (inline PR annotations) and the
 # suppression-debt dashboard (--stats, printed to the job log).
 #
-# Usage:  tools/lint_ci.sh [paths...]        (default: bigdl_tpu tools bench.py)
+# Usage:  tools/lint_ci.sh [paths...]        (default: bigdl_tpu tools)
 #   GRAFTLINT_SARIF_OUT=path  where to write the SARIF file
 #                             (default: graftlint.sarif in the repo root)
 #   PYTHON=interpreter        defaults to `python`
