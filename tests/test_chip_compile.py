@@ -219,9 +219,23 @@ def test_ptb_medium_grad_step_has_fused_cell(one_chip, monkeypatch):
 
 
 # ------------------------------------------- the per-step output layer
-# "name = dtype[dims]{layout} opcode(" of one HLO instruction
-_HLO_RESULT_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+\[([\d,]*)\]\S*\s+([\w\-]+)\(")
+# "name = type opcode(" of one HLO instruction; the type may be a tuple
+_HLO_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\(")
+_HLO_SHAPE_RE = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _results_of(hlo_text, n_elements):
+    """``(opcode, line)`` of every instruction in ``hlo_text``, once for
+    each array of ``n_elements`` in its result (a tuple's members
+    counted)."""
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR_RE.match(line)
+        if not m:
+            continue
+        for dims in _HLO_SHAPE_RE.findall(m.group(1)):
+            if math.prod(int(d) for d in dims.split(",") if d) == n_elements:
+                yield m.group(2), line.strip()[:120]
 
 
 def _relayouts(hlo_text, n_elements):
@@ -229,33 +243,31 @@ def _relayouts(hlo_text, n_elements):
     module whose result holds ``n_elements``: each is a pass over that
     many elements that computes nothing (a reshape the compiler could
     make free is a ``bitcast`` by now)."""
-    found = []
-    for line in hlo_text.splitlines():
-        m = _HLO_RESULT_RE.match(line)
-        if not m or m.group(2) not in ("copy", "reshape", "transpose"):
-            continue
-        if math.prod(int(d) for d in m.group(1).split(",") if d) \
-                == n_elements:
-            found.append(line.strip()[:120])
-    return found
+    return [line for op, line in _results_of(hlo_text, n_elements)
+            if op in ("copy", "reshape", "transpose")]
 
 
-@pytest.mark.parametrize("head", [
-    (nn.LogSoftMax, nn.ClassNLLCriterion),
-    (nn.SoftMax, nn.CategoricalCrossEntropy),
-], ids=["LogSoftMax", "SoftMax"])
-def test_time_distributed_head_keeps_one_logits_layout(one_chip, head):
+def _writers(hlo_text, n_elements):
+    """The instructions of the entry computation that WRITE an array of
+    ``n_elements``: what names a buffer and writes none is left out."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    return [line for op, line in _results_of(entry, n_elements)
+            if op not in ("get-tuple-element", "bitcast", "tuple",
+                          "parameter")]
+
+
+_PTB_HEAD = (660, 35, 650, 10000)  # N, T, H, V of the benchmark's cell
+
+
+def _compile_ptb_head(one_chip, activation, inner):
     """PTB-medium's head at the benchmark's batch, forward and backward:
-    ``TimeDistributed(Linear)`` -> row-wise activation ->
-    ``TimeDistributedCriterion``.  The 924 MB of logits stay (N*T, V)
-    from the matmul to the loss and back; with the activation on the
-    (N, T, V) array the compiler re-laid them out four times a step
-    (35 rows pad to 40 in the (8, 128) tiling), 23 % of the device's
-    time on the chip (PERF.md, PR 29)."""
-    N, T, H, V = 660, 35, 650, 10000
-    activation, inner = head
-    model = (nn.Sequential().add(nn.TimeDistributed(nn.Linear(H, V)))
-             .add(activation()))
+    ``TimeDistributed(Linear)`` [-> row-wise activation] ->
+    ``TimeDistributedCriterion(inner)``."""
+    N, T, H, V = _PTB_HEAD
+    model = nn.Sequential().add(nn.TimeDistributed(nn.Linear(H, V)))
+    if activation is not None:
+        model.add(activation())
     crit = nn.TimeDistributedCriterion(inner())
 
     def loss_fn(params, mstate, x, y):
@@ -265,8 +277,39 @@ def test_time_distributed_head_keeps_one_logits_layout(one_chip, head):
     params, mstate = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     as_spec = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda a: _spec(one_chip, a.shape, a.dtype), t)
-    compiled = _compile(
+    return _compile(
         jax.value_and_grad(loss_fn), as_spec(params), as_spec(mstate),
         _spec(one_chip, (N, T, H), jnp.float32),
         _spec(one_chip, (N, T), jnp.int32))
+
+
+@pytest.mark.parametrize("head", [
+    (nn.LogSoftMax, nn.ClassNLLCriterion),
+    (nn.SoftMax, nn.CategoricalCrossEntropy),
+], ids=["LogSoftMax", "SoftMax"])
+def test_time_distributed_head_keeps_one_logits_layout(one_chip, head):
+    """The 924 MB of logits stay (N*T, V) from the matmul to the loss
+    and back; with the activation on the (N, T, V) array the compiler
+    re-laid them out four times a step (35 rows pad to 40 in the
+    (8, 128) tiling), 23 % of the device's time on the chip (PERF.md,
+    PR 29)."""
+    N, T, _, V = _PTB_HEAD
+    compiled = _compile_ptb_head(one_chip, *head)
     assert _relayouts(compiled.as_text(), N * T * V) == []
+
+
+@pytest.mark.parametrize("head", [
+    (nn.LogSoftMax, nn.ClassNLLCriterion),
+    (None, nn.CrossEntropyCriterion),
+], ids=["LogSoftMax+ClassNLL", "CrossEntropy"])
+def test_head_writes_the_logits_and_nothing_else_that_large(one_chip, head):
+    """The matmul's result is the ONE logits-sized array of the head:
+    the log-probabilities are an operand of the class pick's row sum
+    (``nn.criterion._pick_class``), written nowhere.  Picked by a
+    gather they were materialised for it, 924 MB a step and 9 % of the
+    device's time on the chip (PERF.md, PR 31), under either spelling:
+    ``CrossEntropyCriterion`` compiled to the same program."""
+    N, T, _, V = _PTB_HEAD
+    compiled = _compile_ptb_head(one_chip, *head)
+    assert len(_writers(compiled.as_text(), N * T * V)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9

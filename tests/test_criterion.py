@@ -3,8 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from bigdl_tpu import nn
+from bigdl_tpu.nn.criterion import _pick_class
 
 
 def test_class_nll():
@@ -111,6 +113,102 @@ def test_multilabel_margin_class_zero_with_padding():
     t = jnp.array([[0, -1, -1]])
     loss = nn.MultiLabelMarginCriterion().forward(x, t)
     np.testing.assert_allclose(loss, 0.0)
+
+
+# ------------------------------------------- the class pick, held to a gather
+
+
+def _nll_by_gather(x, t, weights=None, size_average=True, logits=False,
+                   ignore_index=-100):
+    """``ClassNLLCriterion.apply`` with the pick as the gather it was."""
+    logp = jax.nn.log_softmax(x, axis=-1) if logits else x
+    valid = t != ignore_index
+    t_safe = jnp.where(valid, t, 0)
+    picked = jnp.take_along_axis(logp, t_safe[..., None], axis=-1)[..., 0]
+    w = jnp.ones_like(picked) if weights is None \
+        else jnp.take(weights, t_safe)
+    w = jnp.where(valid, w, 0.0)
+    total = -jnp.sum(w * picked)
+    return total / jnp.maximum(jnp.sum(w), 1e-8) if size_average else total
+
+
+def _logits(shape, seed):
+    return 3.0 * jax.random.normal(jax.random.PRNGKey(seed), shape)
+
+
+def _logp(shape, seed=0, dtype=jnp.float32):
+    return jax.nn.log_softmax(_logits(shape, seed), axis=-1).astype(dtype)
+
+
+_T5 = [0, 6, 3, 3, 1]
+_PICK_CASES = {
+    # name: () -> (input, target, ClassNLLCriterion arguments); built in
+    # the test, so that importing this file computes nothing
+    "rank1": lambda: (_logp((7,)), 4, {}),
+    "rank2": lambda: (_logp((5, 7)), _T5, {}),
+    "rank3": lambda: (_logp((2, 3, 7)), [[0, 6, 2], [5, 5, 1]], {}),
+    "class_weights": lambda: (_logp((5, 7)), _T5,
+                              {"weights": jnp.linspace(0.25, 2.0, 7)}),
+    "ignore_index": lambda: (_logp((5, 7)), [0, -100, 3, -100, 1], {}),
+    "ignore_index_weights_sum": lambda: (
+        _logp((5, 7)), [2, -1, -1, 6, 0],
+        {"weights": jnp.linspace(0.25, 2.0, 7), "size_average": False,
+         "ignore_index": -1}),
+    "sum": lambda: (_logp((5, 7)), _T5, {"size_average": False}),
+    "logits": lambda: (_logits((5, 7), 3), _T5, {"logits": True}),
+    "logits_rank3": lambda: (_logits((2, 3, 7), 4),
+                             [[0, -100, 2], [5, 5, 1]], {"logits": True}),
+    "bf16": lambda: (_logp((5, 7), dtype=jnp.bfloat16), _T5, {}),
+    "bf16_logits": lambda: (_logits((5, 7), 5).astype(jnp.bfloat16), _T5,
+                            {"logits": True}),
+    "neg_inf_elsewhere": lambda: (
+        _logp((5, 7)).at[1, 2].set(-jnp.inf), _T5, {}),
+    "neg_inf_logit_elsewhere": lambda: (
+        _logits((5, 7), 6).at[1, 2].set(-jnp.inf), _T5, {"logits": True}),
+}
+
+
+@pytest.mark.parametrize("case", list(_PICK_CASES))
+def test_class_pick_equals_the_gather_it_replaced(case):
+    """``_pick_class`` is a masked row sum where a ``take_along_axis``
+    was: the loss and its gradient keep every bit, in the input's
+    dtype, and what an unpicked column holds reaches neither."""
+    x, t, kw = _PICK_CASES[case]()
+    t = jnp.array(t)
+    crit = nn.ClassNLLCriterion(**kw)
+    got, got_g = jax.value_and_grad(crit.apply)(x, t)
+    want, want_g = jax.value_and_grad(_nll_by_gather)(x, t, **kw)
+    assert got.dtype == want.dtype and got_g.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(got_g, np.float32),
+                                  np.asarray(want_g, np.float32))
+    assert np.isfinite(np.asarray(got, np.float32))
+    if "neg_inf" in case:
+        assert np.asarray(got_g)[1, 2] == 0.0
+
+
+@pytest.mark.parametrize("axis,shape", [(1, (2, 5, 3, 4)), (0, (5, 6)),
+                                        (-1, (6, 5)), (-2, (2, 5, 3))])
+def test_class_pick_along_any_axis(axis, shape):
+    logp = _logp(shape, seed=7)
+    t_shape = tuple(np.delete(shape, axis))
+    t = jax.random.randint(jax.random.PRNGKey(8), t_shape, 0, shape[axis])
+    gather = lambda a: jnp.squeeze(  # noqa: E731
+        jnp.take_along_axis(a, jnp.expand_dims(t, axis), axis=axis), axis)
+    np.testing.assert_array_equal(_pick_class(logp, t, axis), gather(logp))
+    np.testing.assert_array_equal(
+        jax.grad(lambda a: jnp.sum(_pick_class(a, t, axis) ** 2))(logp),
+        jax.grad(lambda a: jnp.sum(gather(a) ** 2))(logp))
+
+
+def test_class_pick_outside_the_classes_picks_nothing():
+    # the one place where the row sum and the gather differ: the gather
+    # wrapped a negative target and read NaN past the end
+    logp = _logp((3, 7))
+    np.testing.assert_array_equal(
+        _pick_class(logp, jnp.array([-1, 7, 2])),
+        jnp.array([0.0, 0.0, logp[2, 2]]))
 
 
 # ----------------------------------------------------------- round-2 breadth
