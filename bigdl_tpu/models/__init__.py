@@ -10,3 +10,6 @@ from bigdl_tpu.models.transformer import (
     transformer_lm, transformer_block, LearnedPositionalEmbedding,
 )
 from bigdl_tpu.models.recommender import NeuralCF, WideAndDeep
+from bigdl_tpu.models.granite_moe_hybrid import (
+    GraniteMoeHybrid, GraniteMoeHybridLayer, granite_moe_hybrid,
+)

@@ -66,8 +66,13 @@ from bigdl_tpu.nn.quantized import (
     quantize, QuantizedLinear, QuantizedSpatialConvolution,
 )
 from bigdl_tpu.nn.attention import (
-    LayerNorm, MultiHeadAttention, dot_product_attention,
+    GroupedQueryAttention, LayerNorm, MultiHeadAttention, RMSNorm,
+    dot_product_attention, rms_norm,
 )
+from bigdl_tpu.nn.mamba import (
+    Mamba2Mixer, causal_depthwise_conv1d, gated_rms_norm, ssd_chunked_scan,
+)
+from bigdl_tpu.nn.moe import ExpertParallelMoE, GatedMLP, expert_rows
 from bigdl_tpu.nn.regularizers import (
     L1L2Regularizer, L1Regularizer, L2Regularizer, regularization_loss,
 )

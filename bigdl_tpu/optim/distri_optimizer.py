@@ -659,6 +659,10 @@ class DistriOptimizer(Optimizer):
 
         params, mstate, ostate = self._train_driver(params, mstate, ostate,
                                                     grad_fn, rng)
+        # as LocalOptimizer: a model's ``state_warnings``, after the run
+        for said in getattr(self.model, "state_warnings",
+                            lambda state: [])(mstate):
+            logger.warning("%s", said)
 
         self.model._params = params
         self.model._state = mstate

@@ -1568,6 +1568,12 @@ class LocalOptimizer(Optimizer):
                     self.dataset.size(), jax.devices()[0])
         params, mstate, ostate = self._train_driver(params, mstate, ostate,
                                                     grad_fn, rng)
+        # what a model's own counters say of the run (a model may define
+        # ``state_warnings(state) -> list of str``: an expert layer's
+        # dropped assignments), read once, after the last step
+        for said in getattr(self.model, "state_warnings",
+                            lambda state: [])(mstate):
+            logger.warning("%s", said)
 
         # write trained weights back into the user's model object
         # (reference: final getModel copy, DistriOptimizer.scala:1063)

@@ -12,6 +12,9 @@ measurement-driven tuning both stand on):
   as Chrome-trace JSON (summarize with ``python -m tools.trace_report
   trace.json``) and mirrored as ``jax.profiler.TraceAnnotation``s, so
   any profiler capture holds them beside the device's timeline;
+- :func:`device_scope` — named DEVICE scopes (``bigdl.moe.experts``)
+  for work inside a compiled block, which no host span can see: the
+  name lands in the instructions' ``op_name`` metadata;
 - :class:`MetricRegistry` — counters, gauges, reservoir histograms with
   p50/p95/p99; ``utils/metrics.Metrics`` and
   ``serving/metrics.ServingMetrics`` are veneers over it;
@@ -47,6 +50,7 @@ from bigdl_tpu.telemetry.flight import FlightRecorder
 from bigdl_tpu.telemetry.hooks import DriverTelemetry
 from bigdl_tpu.telemetry.registry import (Counter, Gauge, Histogram,
                                           MetricRegistry, Reservoir)
+from bigdl_tpu.telemetry.scopes import SCOPE_PREFIX, device_scope
 from bigdl_tpu.telemetry.tracer import (NULL_SPAN, OFF_DRIVER_CATS,
                                         PHASE_CATS, TOP_LEVEL_CATS, Tracer)
 from bigdl_tpu.telemetry.watchdog import (MemoryWatermark,
@@ -57,7 +61,8 @@ __all__ = [
     "AdminServer", "Counter", "DriverTelemetry", "FlightRecorder", "Gauge",
     "Histogram", "MemoryWatermark", "MetricRegistry", "NULL_SPAN",
     "OFF_DRIVER_CATS", "PHASE_CATS", "RecompileWatchdog", "RequestContext",
-    "Reservoir", "StallDetector", "TOP_LEVEL_CATS", "Tracer",
+    "Reservoir", "SCOPE_PREFIX", "StallDetector", "TOP_LEVEL_CATS",
+    "Tracer", "device_scope",
     "jit_cache_size",
     "new_trace_id",
     "render_prometheus",
