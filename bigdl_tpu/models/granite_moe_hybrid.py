@@ -30,12 +30,38 @@ layer).  Token ids and targets are rows of the held slice,
 
 Departures from HF: no packed sequences and no attention mask (every
 record is one document); no dropout; no ``output_router_logits``
-auxiliary loss; one ``jax.checkpoint`` a layer, so a training step
-keeps a layer's input and recomputes its inside.
+auxiliary loss; one ``jax.checkpoint`` a layer.
+
+**What a layer's checkpoint keeps.**  A training step keeps a layer's
+input and, by name (``SAVED_IN_LAYER``), the outputs of the expert
+block's two up-projections: the grouped ``rows W_in`` of the routed
+experts (``moe.h``; what lays its rows out goes under the same name:
+the experts chosen and each row's token, use and gate, 0.5 MB a layer)
+and the shared expert's ``x W_in`` (``mlp.h``).  Everything else inside
+the layer is made a second time by the backward.  These two because
+each is a large product whose result is small beside the work of making
+it again (at granite-4.0-h-small's widths and 8,192 tokens on one chip
+of eight: 47 and 50 MB a layer for 2.0 and 1.2 ms), and the backward
+reads each as it stands.  The cost is their bytes in every layer until
+its backward has run: 1.0 GB at 8,192 tokens and ten layers, linear in
+both (the compiled step counts 1.67 GB more: PERF.md, PR 33), so a
+share that just fits one chip at 16k tokens without them would not with
+them.  Not kept, on purpose: Mamba's ``u in_proj`` (38 MB a layer for
+0.7 ms; kept, it put ``mixer.out_proj``'s gradient within 1 % of the
+limit the benchmark holds it to: a backward that mixes kept arrays with
+a second making of the rest rounds otherwise, layer by layer); the
+gathered rows (126 MB a layer for a 0.8 ms gather; with them the step
+counted 13.07e9 bytes where 13.0e9 was the line drawn); the experts'
+``ys`` (made from the kept ``moe.h`` by one product); anything f32 of
+the scan (residuals several times the size of what they save); the
+attention layer's projections (one layer in ten).  The policy is fixed:
+it is one algorithm with one value in use, and the configuration that
+needs another brings the argument and its cell.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -43,7 +69,21 @@ import jax.numpy as jnp
 
 from bigdl_tpu import nn
 from bigdl_tpu.nn.attention import rms_norm
+from bigdl_tpu.nn.moe import MLP_H, MOE_H
 from bigdl_tpu.telemetry.scopes import device_scope
+
+# what a layer's checkpoint keeps for its backward beside the layer's
+# input (module docstring)
+SAVED_IN_LAYER = (MOE_H, MLP_H)
+
+
+def checkpointed(layer, training: bool = False):
+    """``layer.apply(params, state, input)`` under the layer's
+    checkpoint (module docstring)."""
+    return jax.checkpoint(
+        functools.partial(layer.apply, training=training),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *SAVED_IN_LAYER))
 
 
 def _slice_of(total: int, index: int, of: int, what: str):
@@ -163,11 +203,8 @@ class GraniteMoeHybrid(nn.Module):
             * jnp.asarray(c["embedding_multiplier"], embed.dtype)
         new_state = {}
         for j, layer in enumerate(self.layers):
-            @jax.checkpoint
-            def run(p, s, x, _layer=layer):
-                return _layer.apply(p, s, x, training=training)
-            h, new_state[str(j)] = run(params["layers"][str(j)],
-                                       state["layers"][str(j)], h)
+            h, new_state[str(j)] = checkpointed(layer, training)(
+                params["layers"][str(j)], state["layers"][str(j)], h)
         with device_scope("head"):
             x = rms_norm(h, params["final_norm"], c["rms_norm_eps"])
             logits = jnp.einsum("ntd,vd->ntv", x, embed,

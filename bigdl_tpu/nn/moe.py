@@ -49,10 +49,22 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.telemetry.scopes import device_scope
+
+# Names a caller's ``jax.checkpoint`` policy may save
+# (``save_only_these_names``); under no such policy a tag is the identity.
+# MOE_H names ``h`` AND what lays its rows out: the experts chosen, each
+# row's token, which rows are used, each row's gate (a few hundred KB).
+# A backward that makes the choice a second time gets another one on
+# some tokens (``ExpertParallelMoE.route``) -- with 8,192 tokens on the
+# chip the experts' gradients then came out as noise (PERF.md, PR 33).
+# One name, so ``h`` is never kept without them
+MLP_H = "mlp.h"    # GatedMLP: x W_in
+MOE_H = "moe.h"    # ExpertParallelMoE: rows W_in, grouped, and their layout
 
 
 def gated_act(h):
@@ -78,7 +90,8 @@ class GatedMLP(Module):
                 "w_out": xav.init(k_out, (F, D), F, D)}, {}
 
     def apply(self, params, state, input, *, training=False, rng=None):
-        return gated_act(input @ params["w_in"]) @ params["w_out"], state
+        h = checkpoint_name(input @ params["w_in"], MLP_H)
+        return gated_act(h) @ params["w_out"], state
 
 
 ROW_TILE = 256
@@ -151,7 +164,16 @@ class ExpertParallelMoE(Module):
         precision (a tie broken the other way is another expert)."""
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        values, experts = jax.lax.top_k(logits, self.top_k)
+        _, experts = jax.lax.top_k(logits, self.top_k)
+        # The logits are read AT the experts chosen (a masked sum: the
+        # values top_k gives, bit for bit), so that a backward which
+        # keeps the choice by name and makes ``x`` a second time
+        # differentiates the forward's choice.  Its own top-k differs on
+        # some tokens (the second ``x`` rounds otherwise in bf16); rows of
+        # ``h`` kept from the forward would then belong to other tokens
+        experts = checkpoint_name(experts, MOE_H)
+        chosen = experts[..., None] == jnp.arange(self.n_experts)
+        values = jnp.sum(jnp.where(chosen, logits[:, None, :], 0), axis=-1)
         return jax.nn.softmax(values, axis=-1), experts
 
     def plan(self, experts, rows: int):
@@ -195,14 +217,18 @@ class ExpertParallelMoE(Module):
                 jnp.repeat(jnp.arange(N * T, dtype=jnp.int32), self.top_k),
                 mode="drop")
             used = jnp.zeros((R,), bool).at[flat].set(True, mode="drop")
+            # kept with ``h``: a scatter of T*k scalars is 0.4-0.5 ms on
+            # the v5e, half of what the row gather below takes
+            token, used = (checkpoint_name(a, MOE_H) for a in (token, used))
             xs = jnp.where(used[:, None], x[token], 0)
         with device_scope("moe.experts"):
-            h = jax.lax.ragged_dot(xs, params["w_in"], sizes)
+            h = checkpoint_name(
+                jax.lax.ragged_dot(xs, params["w_in"], sizes), MOE_H)
             ys = jax.lax.ragged_dot(gated_act(h), params["w_out"], sizes)
         with device_scope("moe.combine"):
             # a token's rows weighted by their gates and added up, in f32
-            gate = jnp.zeros((R,), jnp.float32).at[flat].set(
-                gates.reshape(-1), mode="drop")
+            gate = checkpoint_name(jnp.zeros((R,), jnp.float32).at[flat].set(
+                gates.reshape(-1), mode="drop"), MOE_H)     # as token, used
             out = jnp.zeros((N * T, D), jnp.float32).at[token].add(
                 ys.astype(jnp.float32) * gate[:, None])
         new_state = {"rows_held": count_add(state["rows_held"], n_fit),

@@ -385,3 +385,59 @@ def test_granite_attention_never_holds_all_scores(one_chip):
     # f32; a block of 1,024 queries holds an eighth of that
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
     assert "f32[1,4,8192,8192]" not in compiled.as_text()
+
+
+class _CheckpointedLayers(nn.Module):
+    """Granite layers stacked as ``GraniteMoeHybrid.apply`` stacks them:
+    each under the model's own checkpoint."""
+
+    def __init__(self, layers):
+        super().__init__("CheckpointedLayers")
+        self.layers = layers
+
+    def init(self, rng):
+        made = [m.init(rng) for m in self.layers]
+        return [p for p, _ in made], [s for _, s in made]
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        from bigdl_tpu.models.granite_moe_hybrid import checkpointed
+        h = input
+        for m, p, s in zip(self.layers, params, state):
+            h, _ = checkpointed(m)(p, s, h)
+        return h, state
+
+
+def test_granite_layers_checkpoint_keeps_the_up_projections(one_chip):
+    """A Mamba and an attention layer of the cell, stacked and
+    checkpointed as the model does it: the backward makes no named
+    up-projection a second time.  These counts are what the gain of
+    PR 33 rests on (PERF.md 6); under a bare ``jax.checkpoint`` the same
+    compile reads 16 grouped kernels, four of them ``rows W_in``, and
+    four shared-expert up-projections."""
+    from benchmarks import hlo_scopes, lib
+    from bigdl_tpu.models.granite_moe_hybrid import GraniteMoeHybridLayer
+    builder = lib.load_module("builders", "granite_moe_hybrid")
+    cfg = lib.load_json("configs", "granite-4.0-h-small-share8")
+    whole, share = builder.whole_config(cfg), builder.share(cfg)
+    compiled = _granite_grad(_CheckpointedLayers(
+        [GraniteMoeHybridLayer(whole, kind, share)
+         for kind in ("mamba", "attention")]), one_chip)
+    text = compiled.as_text()
+
+    # the compiler names its grouped kernels itself: count them by shape.
+    # Seven a layer: forward 2, backward 4, and ``ys`` made a second
+    # time from the kept ``moe.h``; ``rows W_in`` in the forward alone
+    grouped = re.findall(r"= (\S+) custom-call\([^\n]*ragged_dot_tiling",
+                         text)
+    assert len(grouped) == 14
+    assert sum(r.startswith("bf16[15360,1536]") for r in grouped) == 2
+    # the shared expert's up-projection, once a layer
+    assert len(re.findall(r"= bf16\[8192,3072\]\S* convolution\(",
+                          text)) == 2
+    # (the gathered rows are not kept, models/granite_moe_hybrid.py says
+    # why: a layer still gathers them twice, and no count holds that)
+    placed = hlo_scopes.instruction_scopes(text, builder.COMPILER_OPS)
+    assert hlo_scopes.unscoped_kernels(text, placed) == []
+    # read 1,282,366,464 bytes (v5e:2x2 compile, PR 33): the kept arrays
+    # of two layers are 0.20 GB of it
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

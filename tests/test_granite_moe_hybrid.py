@@ -23,6 +23,8 @@ import pytest
 
 from bigdl_tpu import nn, optim
 from bigdl_tpu.models import granite_moe_hybrid
+from bigdl_tpu.models.granite_moe_hybrid import (GraniteMoeHybridLayer,
+                                                 checkpointed)
 from bigdl_tpu.nn.attention import dot_product_attention
 from bigdl_tpu.nn.moe import COUNT_WORD, count_add, count_value as count
 
@@ -297,6 +299,95 @@ def test_model_against_reference(share):
     close(jax.jit(jax.grad(loss))(p),
           jax.jit(jax.grad(
               lambda p: ref.loss_fn(CFG, share, p, ids, targets)))(p))
+
+
+# ------------------------------------- what a layer's checkpoint keeps
+# what a layer tags, by the function that tags it: the last dimension of
+# each array at CFG's sizes.  The k experts chosen; rows W_in and each
+# row's token, use and gate (R = 256 rows for these 32 tokens: one
+# ROW_TILE); x W_in of the shared expert.  Neither mixer tags anything
+_TAGGED = {"ExpertParallelMoE.route": [CFG["num_experts_per_tok"]],
+           "ExpertParallelMoE.apply": [2 * CFG["intermediate_size"],
+                                       256, 256, 256],
+           "GatedMLP.apply": [2 * CFG["shared_intermediate_size"]]}
+
+
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_a_layers_checkpoint_keeps_the_named_arrays_and_no_other(
+        kind, capsys):
+    """Beside its arguments a checkpointed layer of either kind saves
+    the outputs of the expert block's two up-projections, what lays the
+    experts' rows out, and nothing else."""
+    layer = GraniteMoeHybridLayer(CFG, kind, q_block=8)
+    p, s = layer.init(key(0))
+    x = jax.random.normal(key(1), (2, 16, 32))
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p, v: checkpointed(layer)(p, s, v)[0].sum(), p, x)
+    made = [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line]
+    assert len(made) == sum(map(len, _TAGGED.values())), made
+    for who, widths in _TAGGED.items():
+        got = sorted(int(line.split("]")[0].split("[")[1].split(",")[-1])
+                     for line in made if f"({who}" in line)
+        assert got == sorted(widths), (who, made)
+
+
+def test_gates_are_read_at_the_experts_chosen():
+    """``route`` reads the logits at the chosen experts by a masked sum:
+    the values ``top_k`` gives, to the last bit."""
+    m, p, _ = _moe()
+    x = jax.random.normal(key(1), (48, 32))
+    gates, experts = m.route(p["router"], x)
+    logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
+    values, chosen = jax.lax.top_k(logits, m.top_k)
+    np.testing.assert_array_equal(experts, chosen)
+    np.testing.assert_array_equal(gates, jax.nn.softmax(values, axis=-1))
+
+
+@pytest.mark.parametrize("share", [(0, 1), (0, SHARES)],
+                         ids=["whole", "share0of8"])
+def test_gradients_with_the_policy_equal_a_bare_checkpoints(share,
+                                                            monkeypatch):
+    """The kept arrays are the ones the forward made: every parameter's
+    gradient (the embedding's carries the first layer's input's) is the
+    one a bare ``jax.checkpoint`` a layer gives, to f32 rounding."""
+    m = granite_moe_hybrid(CFG, share, q_block=8)
+    p, s = m.init(key(0))
+    rows = CFG["vocab_size"] // share[1]
+    ids = jax.random.randint(key(1), (2, 24), 0, rows)
+    targets = jax.random.randint(key(2), (2, 24), 0, rows)
+
+    def grads():
+        return jax.jit(jax.grad(lambda p: ref.cross_entropy(
+            m.apply(p, s, ids, training=True)[0], targets)))(p)
+
+    kept = grads()
+    # policy=None is jax.checkpoint's default: nothing kept by name
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    # the CPU compiles two programs that fuse, and so round, apart: 27
+    # of 43 leaves came out equal to the last bit, the worst (a
+    # two-element ``D``) 1.2e-6 of its larger element
+    close(kept, grads(), rtol=5e-6)
+
+
+@pytest.mark.parametrize("module", [
+    nn.GatedMLP(32, 24), nn.ExpertParallelMoE(32, 16, 16, 4, held=(4, 6)),
+], ids=["GatedMLP", "ExpertParallelMoE"])
+def test_a_tag_outside_a_checkpoint_changes_nothing(module, monkeypatch):
+    p, s = module.init(key(0))
+    x = jax.random.normal(key(1), (2, 16, 32))
+
+    def out_and_grads():
+        return jax.jit(jax.value_and_grad(
+            lambda p, v: module.apply(p, s, v)[0].sum(),
+            argnums=(0, 1)))(p, x)
+
+    tagged = out_and_grads()
+    monkeypatch.setattr(nn.moe, "checkpoint_name", lambda a, name: a)
+    for got, want in zip(jax.tree_util.tree_leaves(tagged),
+                         jax.tree_util.tree_leaves(out_and_grads())):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_counts_that_do_not_split_are_refused():
