@@ -6,7 +6,8 @@
 
 loads, warms up, measures for ``--seconds`` and prints as its LAST line
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, in a traced run, ``breakdown``.  ``--trace 0`` gives the
+``device``, in a traced run ``breakdown``, and last ``checks`` where the
+runner gives the numbers it compared.  ``--trace 0`` gives the
 cell's end-to-end metrics, ``--trace 1`` its per-layer metrics.  What
 else it has to say goes on earlier lines and into
 ``<--out>/<workload>/``.
@@ -51,7 +52,8 @@ def main(argv=None) -> int:
                    help="leave the xplane file in the output directory")
     args = p.parse_args(argv)
 
-    bench = lib.load_benchmark()
+    # held cells too (benchmarks/held/): the driver asks for none of them
+    bench = lib.load_benchmark(held=True)
     cells = {w["name"]: w for w in bench["workloads"]}
     if args.workload not in cells:
         raise lib.BenchFailure(f"no workload {args.workload!r} in "
@@ -127,6 +129,9 @@ def main(argv=None) -> int:
         "metrics": metrics, "device": device}
     if args.trace and result.get("breakdown"):
         line["breakdown"] = result["breakdown"]
+    if result.get("checks"):
+        # each number compared beside its limit, last on the line
+        line["checks"] = result["checks"]
     if args.tiny:
         print(f"   rehearsal: the run's own checks said correct="
               f"{result['correct']}", flush=True)

@@ -49,4 +49,6 @@ def test_declared_in_benchmark_json():
     assert m == {"name": NAME, "unit": "share", "better": "lower",
                  "source": "program_span", "layer": "input pipeline",
                  "moves": "train_throughput",
-                 "workloads": ["resnet50-train-4chip"]}
+                 # the cells whose batches MTSampleToMiniBatch assembles
+                 "workloads": ["resnet50-train-4chip",
+                               "resnet50-train-1chip"]}
