@@ -13,3 +13,4 @@ from bigdl_tpu.models.recommender import NeuralCF, WideAndDeep
 from bigdl_tpu.models.granite_moe_hybrid import (
     GraniteMoeHybrid, GraniteMoeHybridLayer, granite_moe_hybrid,
 )
+from bigdl_tpu.models.zaya import Zaya, ZayaLayer, zaya

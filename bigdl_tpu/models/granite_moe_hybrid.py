@@ -32,7 +32,8 @@ Departures from HF: no packed sequences and no attention mask (every
 record is one document); no dropout; no ``output_router_logits``
 auxiliary loss; one ``jax.checkpoint`` a layer.
 
-**What a layer's checkpoint keeps.**  A training step keeps a layer's
+**What a layer's checkpoint keeps** (``models/share.py``
+``checkpointed``, which ``zaya`` uses too).  A training step keeps a layer's
 input and, by name (``SAVED_IN_LAYER``), the outputs of the expert
 block's two up-projections: the grouped ``rows W_in`` of the routed
 experts (``moe.h``; what lays its rows out goes under the same name:
@@ -61,36 +62,16 @@ needs another brings the argument and its cell.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from bigdl_tpu import nn
+from bigdl_tpu.models.share import (checkpointed, expert_counts, slice_of,
+                                    state_warnings)
 from bigdl_tpu.nn.attention import rms_norm
-from bigdl_tpu.nn.moe import MLP_H, MOE_H
 from bigdl_tpu.telemetry.scopes import device_scope
-
-# what a layer's checkpoint keeps for its backward beside the layer's
-# input (module docstring)
-SAVED_IN_LAYER = (MOE_H, MLP_H)
-
-
-def checkpointed(layer, training: bool = False):
-    """``layer.apply(params, state, input)`` under the layer's
-    checkpoint (module docstring)."""
-    return jax.checkpoint(
-        functools.partial(layer.apply, training=training),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *SAVED_IN_LAYER))
-
-
-def _slice_of(total: int, index: int, of: int, what: str):
-    if total % of:
-        raise ValueError(f"{total} {what} do not split {of} ways")
-    step = total // of
-    return index * step, (index + 1) * step
 
 
 class GraniteMoeHybridLayer(nn.Module):
@@ -111,13 +92,13 @@ class GraniteMoeHybridLayer(nn.Module):
                 D, c["mamba_n_heads"], c["mamba_d_head"],
                 c["mamba_d_state"], n_groups=c["mamba_n_groups"],
                 d_conv=c["mamba_d_conv"], chunk_size=c["mamba_chunk_size"],
-                held=_slice_of(c["mamba_n_heads"], i, n, "Mamba heads"),
+                held=slice_of(c["mamba_n_heads"], i, n, "Mamba heads"),
                 conv_bias=c["mamba_conv_bias"], eps=self.eps)
         elif kind == "attention":
             self.mixer = nn.GroupedQueryAttention(
                 D, c["num_attention_heads"], c["num_key_value_heads"],
                 D // c["num_attention_heads"],
-                held=_slice_of(c["num_key_value_heads"], i, n,
+                held=slice_of(c["num_key_value_heads"], i, n,
                                "key/value heads"),
                 scale=c["attention_multiplier"], q_block=q_block)
         else:
@@ -125,7 +106,7 @@ class GraniteMoeHybridLayer(nn.Module):
         self.experts = nn.ExpertParallelMoE(
             D, c["intermediate_size"], c["num_local_experts"],
             c["num_experts_per_tok"],
-            held=_slice_of(c["num_local_experts"], i, n, "experts"),
+            held=slice_of(c["num_local_experts"], i, n, "experts"),
             row_factor=row_factor)
         self.shared = nn.GatedMLP(D, c["shared_intermediate_size"])
 
@@ -171,7 +152,7 @@ class GraniteMoeHybrid(nn.Module):
         if not 0 <= i < n:
             raise ValueError(f"share {share}: index outside [0, {n})")
         self.config, self.share = dict(config), (i, n)
-        self.vocab_rows = _slice_of(config["vocab_size"], i, n,
+        self.vocab_rows = slice_of(config["vocab_size"], i, n,
                                     "vocabulary rows")
         kinds = config["layer_types"][:config["num_hidden_layers"]]
         if len(kinds) != config["num_hidden_layers"]:
@@ -213,19 +194,13 @@ class GraniteMoeHybrid(nn.Module):
         return logits, {"layers": new_state}
 
     def expert_counts(self, state) -> list:
-        """The host's reading of every layer's expert counters:
-        ``[{"rows_held": n, "rows_overflow": n}, ...]``, running totals
-        since ``init``."""
-        return [{name: nn.moe.count_value(total)
-                 for name, total in state["layers"][str(j)]["experts"].items()}
-                for j in range(len(self.layers))]
+        """The host's reading of every layer's expert counters
+        (``share.expert_counts``)."""
+        return expert_counts(self, state)
 
     def state_warnings(self, state) -> list:
         """Read by the optimizers when a run ends, and logged."""
-        return [f"layer {j}: {said}"
-                for j, layer in enumerate(self.layers)
-                for said in layer.experts.state_warnings(
-                    state["layers"][str(j)]["experts"])]
+        return state_warnings(self, state)
 
 
 def granite_moe_hybrid(config: dict, share=(0, 1), **kw) -> GraniteMoeHybrid:

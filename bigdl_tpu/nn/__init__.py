@@ -66,13 +66,15 @@ from bigdl_tpu.nn.quantized import (
     quantize, QuantizedLinear, QuantizedSpatialConvolution,
 )
 from bigdl_tpu.nn.attention import (
-    GroupedQueryAttention, LayerNorm, MultiHeadAttention, RMSNorm,
-    dot_product_attention, rms_norm,
+    CompressedConvAttention, GroupedQueryAttention, LayerNorm,
+    MultiHeadAttention, RMSNorm, dot_product_attention, rms_norm, rope,
 )
 from bigdl_tpu.nn.mamba import (
     Mamba2Mixer, causal_depthwise_conv1d, gated_rms_norm, ssd_chunked_scan,
 )
-from bigdl_tpu.nn.moe import ExpertParallelMoE, GatedMLP, expert_rows
+from bigdl_tpu.nn.moe import (
+    ExpertParallelMoE, GatedMLP, LinearTopKRouter, MLPRouter, expert_rows,
+)
 from bigdl_tpu.nn.regularizers import (
     L1L2Regularizer, L1Regularizer, L2Regularizer, regularization_loss,
 )

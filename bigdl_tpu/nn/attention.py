@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.initialization import Xavier
+from bigdl_tpu.nn.mamba import causal_depthwise_conv1d
 from bigdl_tpu.telemetry.scopes import device_scope
 
 
@@ -283,5 +284,155 @@ class GroupedQueryAttention(Module):
             o = dot_product_attention(q, k, v, causal=True,
                                       scale=self.scale,
                                       q_block=self.q_block)
+            o = o.transpose(0, 2, 1, 3).reshape(N, T, -1)
+            return o @ params["wo"], state
+
+
+def rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
+    """Rotary positions (Su et al., arXiv:2104.09864) on the first
+    ``rotary_dim`` channels of each head (default: all), HF's
+    rotate-half pairing: channel ``i`` turns with channel ``i +
+    rotary_dim / 2`` by the angle ``position * theta ** (-2 i /
+    rotary_dim)``; the channels past ``rotary_dim`` are left as they
+    are.  ``x``: (..., T, Dh); ``positions``: (T,).  Angles in f32,
+    result in ``x``'s dtype."""
+    rd = x.shape[-1] if rotary_dim is None else rotary_dim
+    half = rd // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rd)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq   # (T, half)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    a, b, rest = x32[..., :half], x32[..., half:rd], x32[..., rd:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
+                           axis=-1).astype(x.dtype)
+
+
+class CompressedConvAttention(Module):
+    """Compressed convolutional attention (Zyphra, arXiv:2510.04476; the
+    attention of ZAYA1, arXiv:2511.17127): causal grouped-query
+    attention carried out in a latent of ``heads * head_dim`` (queries)
+    and ``kv_heads * head_dim`` (keys, values) channels, narrower than
+    ``hidden``, whose queries and keys are mixed over time and
+    normalised before they meet::
+
+        q~ = a W_q;  k~ = a W_k;  v = heads([a_t W_v1 ; a_{t-1} W_v2])
+        u  = conv_grouped(conv_depthwise([q~ ; k~]))     causal, with bias
+        m_q = (heads(q~) + repeat(heads(k~))) / 2;  m_k = mean over a group
+        q  = rms(heads(u_q) + m_q);  k = exp(t) * rms(heads(u_k) + m_k)
+        q, k = rope(q), rope(k)  on the first ``rotary`` channels a head
+        out = (causal softmax(q k^T / sqrt(head_dim)) v) W_o
+
+    ``conv=(k0, k1)``: the taps of the depthwise convolution (a channel
+    alone) and of the grouped one (the ``head_dim`` channels of one head
+    mix among themselves); ``[q~ ; k~]`` is padded ONCE, with ``k0 - 1 +
+    k1 - 1`` zeros, and both convolutions run without padding of their
+    own, so position ``t`` reads ``t - (k0 + k1 - 2) .. t``.  ``rms`` is
+    ``sqrt(head_dim) x / |x|_2`` with no weight, in f32; ``t`` one
+    learned scalar a key/value head, 0 at the start.  Half of the value
+    channels of the WHOLE layer (the last ``kv_heads * head_dim / 2``)
+    come from the previous token (zeros before the first).  ``rotary``:
+    ``(theta, channels)``.
+
+    ``held=(lo, hi)``: the key/value groups THIS chip holds (as
+    :class:`GroupedQueryAttention`): their columns of ``W_q``, ``W_k``
+    and ``[W_v1 ; W_v2]``, the convolutions' channels and groups, their
+    ``t``, their rows of ``W_o``; the output is the share's partial sum.
+
+    Input and output: (N, T, D).  Weights are stored (in, out)."""
+
+    def __init__(self, hidden: int, heads: int, kv_heads: int,
+                 head_dim: int, *, conv=(2, 2), rotary=(10000.0, None),
+                 held=None, q_block: Optional[int] = 1024,
+                 eps: float = 1e-5, name: Optional[str] = None):
+        super().__init__(name)
+        if heads % kv_heads:
+            raise ValueError(f"{heads} query heads on {kv_heads} "
+                             "key/value heads: not a multiple")
+        lo, hi = held if held is not None else (0, kv_heads)
+        if not 0 <= lo < hi <= kv_heads:
+            raise ValueError(f"held key/value heads {held} outside "
+                             f"[0, {kv_heads}]")
+        self.hidden, self.head_dim = hidden, head_dim
+        self.group = heads // kv_heads
+        self.held = (lo, hi)
+        self.kv_heads = hi - lo
+        self.q_heads = self.kv_heads * self.group
+        self.conv, self.eps, self.q_block = tuple(conv), eps, q_block
+        self.theta, self.rotary_dim = rotary
+        # the held value channels that read the token itself: those in
+        # the first half of the whole layer's kv_heads * head_dim
+        first, half = lo * head_dim, kv_heads * head_dim // 2
+        self.v_now = min(max(half - first, 0), self.kv_heads * head_dim)
+
+    def init(self, rng):
+        D, dh = self.hidden, self.head_dim
+        nq, nkv = self.q_heads * dh, self.kv_heads * dh
+        k0, k1 = self.conv
+        ks = jax.random.split(rng, 8)
+        xav = Xavier()
+        # the convolutions as torch's Conv1d draws them: uniform within
+        # 1 / sqrt(taps x channels read)
+        b0, b1 = 1.0 / math.sqrt(k0), 1.0 / math.sqrt(k1 * dh)
+        uniform = functools.partial(jax.random.uniform, dtype=jnp.float32)
+        return {"wq": xav.init(ks[0], (D, nq), D, nq),
+                "wk": xav.init(ks[1], (D, nkv), D, nkv),
+                "wv": xav.init(ks[2], (D, nkv), D, nkv),
+                "conv0_w": uniform(ks[3], (k0, nq + nkv), minval=-b0,
+                                   maxval=b0),
+                "conv0_b": uniform(ks[4], (nq + nkv,), minval=-b0,
+                                   maxval=b0),
+                "conv1_w": uniform(ks[5], (k1, self.q_heads + self.kv_heads,
+                                           dh, dh), minval=-b1, maxval=b1),
+                "conv1_b": uniform(ks[6], (nq + nkv,), minval=-b1,
+                                   maxval=b1),
+                "temp": jnp.zeros((self.kv_heads,), jnp.float32),
+                "wo": xav.init(ks[7], (nq, D), nq, D)}, {}
+
+    def mix(self, params, q0, k0):
+        """``(q, k)`` as (N, T, heads, Dh), ready to attend, of the
+        projections ``q0`` (N, T, q_heads * Dh) and ``k0``."""
+        N, T, _ = q0.shape
+        dh, G, hq, hkv = self.head_dim, self.group, self.q_heads, \
+            self.kv_heads
+        taps = self.conv[1]
+        u = jnp.pad(jnp.concatenate([q0, k0], axis=-1),
+                    ((0, 0), (taps - 1, 0), (0, 0)))
+        u = causal_depthwise_conv1d(u, params["conv0_w"], params["conv0_b"])
+        u = u.reshape(N, T + taps - 1, hq + hkv, dh)
+        u = sum(jnp.einsum("nthd,hde->nthe", u[:, j:j + T],
+                           params["conv1_w"][j]) for j in range(taps)) \
+            + params["conv1_b"].reshape(hq + hkv, dh)
+        qh = q0.reshape(N, T, hkv, G, dh)
+        kh = k0.reshape(N, T, hkv, dh)
+        m_q = (qh + kh[:, :, :, None]) / 2
+        q = u[:, :, :hq] + m_q.reshape(N, T, hq, dh)
+        k = u[:, :, hq:] + jnp.mean(m_q, axis=3)
+
+        def unit(x):        # sqrt(Dh) x / |x| = an RMS norm of weight 1
+            return rms_norm(x.astype(jnp.float32),
+                            jnp.ones((), jnp.float32), self.eps)
+
+        k = unit(k) * jnp.exp(params["temp"].astype(jnp.float32))[:, None]
+        pos = jnp.arange(T)
+        turn = lambda x: rope(x.transpose(0, 2, 1, 3), pos, self.theta,
+                              self.rotary_dim).astype(q0.dtype)
+        return turn(unit(q)), turn(k)
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        N, T, _ = input.shape
+        dh = self.head_dim
+        with device_scope("cca.project"):
+            q0, k0 = input @ params["wq"], input @ params["wk"]
+            v = input @ params["wv"]
+            # (a_{t-1}) W = (a W)_{t-1}: the shift after the product
+            before = jnp.pad(v[:, :-1, self.v_now:], ((0, 0), (1, 0), (0, 0)))
+            v = jnp.concatenate([v[..., :self.v_now], before], axis=-1) \
+                .reshape(N, T, self.kv_heads, dh).transpose(0, 2, 1, 3)
+        with device_scope("cca.mix"):
+            q, k = self.mix(params, q0, k0)
+        with device_scope("cca.attend"):
+            o = dot_product_attention(q, k, v, causal=True,
+                                      q_block=self.q_block)
+        with device_scope("cca.out"):
             o = o.transpose(0, 2, 1, 3).reshape(N, T, -1)
             return o @ params["wo"], state

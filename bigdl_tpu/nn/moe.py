@@ -8,6 +8,24 @@ No reference analog (BigDL predates sparse experts).  Equations as HF
     v, e   = top_k(logits);  g = softmax(v)      over the k selected
     out_t  = sum_k g[t, k] * gated(x_t; expert e[t, k])
 
+**The router is a part the expert layer is given** (``router=``), and
+there are two.  :class:`LinearTopKRouter` is the three lines above: one
+matrix, and the gates a softmax over the k CHOSEN logits.  With k = 1
+that gate is 1 for every token whatever the logits say, so no gradient
+reaches the router: a top-1 model cannot train behind it.
+:class:`MLPRouter` is ZAYA1's (Zyphra, arXiv:2511.17127): the logits
+come from a small MLP on a narrow state that also takes the previous
+layer's (the layer hands the state on: input ``(x, r_prev)``, output
+``(out, r)``), and the gate is the chosen expert's probability over ALL
+experts, which does carry a gradient at k = 1::
+
+    r      = x W_d + b_d  (+ g * r_prev after the first layer)
+    z      = W_3 gelu(W_2 gelu(W_1 rms(r) + b_1) + b_2)        all f32
+    p      = softmax(z);  e = top_k(p + bal);  g = p[e]
+
+Everything after the choice (``plan``, the row buffer, both grouped
+products, combine, the counters) is ONE path for both.
+
 :class:`ExpertParallelMoE` is told which experts it HOLDS
 (``held=(lo, hi)``) and computes their part of ``out``: what the
 experts on other chips would add is left out (the all-to-all and the
@@ -30,7 +48,8 @@ row below ``R`` reads 0: that token loses that expert's part, silently
 as far as the numbers go, so it is COUNTED.  Two counters leave the step
 as model state (as BatchNorm's running statistics do), both running
 totals: ``rows_held``, the assignments computed, and ``rows_overflow``,
-those dropped — 0 in a run that may be believed.  A total is two int32
+those dropped — 0 in a run that may be believed; ``rows_by_expert``
+splits the first by held expert (how uneven the load is).  A total is two int32
 words (``count_value``; x64 is off, and one word would wrap after 2e5
 steps of 10,240 rows).  ``state_warnings`` says in words what
 ``rows_overflow`` holds, and the optimizers log it when a run ends.
@@ -44,6 +63,7 @@ loss balances it here: watch the counter, raise the factor.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -51,6 +71,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from bigdl_tpu.nn.attention import rms_norm
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.telemetry.scopes import device_scope
@@ -120,16 +141,114 @@ def expert_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
                math.ceil(row_factor * balanced / ROW_TILE) * ROW_TILE)
 
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _read_at(values, experts):
+    """``values`` (T, E) read AT ``experts`` (T, k) by a masked sum: the
+    numbers a gather gives, bit for bit.  A backward that keeps the
+    choice by name and makes ``x`` a second time then differentiates the
+    forward's choice.  Its own top-k differs on some tokens (the second
+    ``x`` rounds otherwise in bf16); rows of ``h`` kept from the forward
+    would then belong to other tokens."""
+    chosen = experts[..., None] == jnp.arange(values.shape[-1])
+    return jnp.sum(jnp.where(chosen, values[:, None, :], 0), axis=-1)
+
+
+class LinearTopKRouter(Module):
+    """``logits = x W_r`` over all experts, the ``top_k`` largest chosen,
+    gates a softmax over the CHOSEN logits (granite's; module docstring).
+    Its parameters are the bare (hidden, n_experts) matrix."""
+
+    def __init__(self, hidden_size: int, n_experts: int, top_k: int,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.hidden_size, self.n_experts = hidden_size, n_experts
+        self.top_k = top_k
+
+    def init(self, rng):
+        D, E = self.hidden_size, self.n_experts
+        return Xavier().init(rng, (D, E), D, E), {}
+
+    def route(self, router, state, x):
+        """``(gates (T, k) f32, experts (T, k) int32)`` of tokens
+        ``x`` (T, D): logits over all experts in f32 at the highest
+        precision (a tie broken the other way is another expert)."""
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=_HI)
+        _, experts = jax.lax.top_k(logits, self.top_k)
+        experts = checkpoint_name(experts, MOE_H)
+        return jax.nn.softmax(_read_at(logits, experts), axis=-1), experts
+
+
+class MLPRouter(Module):
+    """ZAYA1's router (module docstring): a down-projection to
+    ``width``, the previous layer's state added through a learned gain
+    (``first=True``: the model's first layer, which has none to add), an
+    RMS norm, two GELU layers of ``width`` and a bias-free read-out over
+    all experts; the gate is the chosen expert's probability over ALL of
+    them.  All of it f32 at the highest precision.  ``bal`` (state, zeros)
+    is added to the probabilities for the CHOICE only: the balancing
+    controller that would move it is not built."""
+
+    def __init__(self, hidden_size: int, n_experts: int, top_k: int,
+                 width: int, *, first: bool = False, eps: float = 1e-5,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.hidden_size, self.n_experts = hidden_size, n_experts
+        self.top_k, self.width = top_k, width
+        self.first, self.eps = first, eps
+
+    def init(self, rng):
+        D, R, E = self.hidden_size, self.width, self.n_experts
+        ks = jax.random.split(rng, 4)
+        xav = Xavier()
+        params = {"wd": xav.init(ks[0], (D, R), D, R),
+                  "bd": jnp.zeros((R,), jnp.float32),
+                  "norm": jnp.ones((R,), jnp.float32),
+                  "w1": xav.init(ks[1], (R, R), R, R),
+                  "b1": jnp.zeros((R,), jnp.float32),
+                  "w2": xav.init(ks[2], (R, R), R, R),
+                  "b2": jnp.zeros((R,), jnp.float32),
+                  "w3": xav.init(ks[3], (R, E), R, E)}
+        if not self.first:
+            params["g"] = jnp.ones((R,), jnp.float32)
+        return params, {"bal": jnp.zeros((E,), jnp.float32)}
+
+    def route(self, router, state, x, r_prev):
+        """``(gates (T, k) f32, experts (T, k) int32, r (T, width)
+        f32)`` of tokens ``x`` (T, D) and the previous layer's state
+        ``r_prev`` (T, width; unread by a ``first`` layer)."""
+        f32 = jnp.float32
+        p = jax.tree_util.tree_map(lambda a: a.astype(f32), router)
+        dot = functools.partial(jnp.dot, precision=_HI)
+        r = dot(x.astype(f32), p["wd"]) + p["bd"]
+        if not self.first:
+            r = r + p["g"] * r_prev.astype(f32)
+        z = rms_norm(r, p["norm"], self.eps)
+        z = jax.nn.gelu(dot(z, p["w1"]) + p["b1"], approximate=False)
+        z = jax.nn.gelu(dot(z, p["w2"]) + p["b2"], approximate=False)
+        prob = jax.nn.softmax(dot(z, p["w3"]), axis=-1)
+        _, experts = jax.lax.top_k(
+            prob + jax.lax.stop_gradient(state["bal"]), self.top_k)
+        experts = checkpoint_name(experts, MOE_H)
+        return _read_at(prob, experts), experts, r
+
+
 class ExpertParallelMoE(Module):
     """The routed experts one chip holds (module docstring).
 
     ``n_experts`` experts of width ``expert_width`` in the layer,
     ``top_k`` per token, ``held=(lo, hi)`` the experts here (default:
     all); the buffer has ``expert_rows(...)`` rows for the tokens seen at
-    trace time.  Input and output: (N, T, D)."""
+    trace time.  ``router``: the module that chooses (default: a
+    :class:`LinearTopKRouter`).  Input and output: (N, T, D); with a
+    router that carries a state from layer to layer (:class:`MLPRouter`)
+    the input is ``(x, r_prev)`` and the output ``(out, r)``."""
 
     def __init__(self, hidden_size: int, expert_width: int, n_experts: int,
                  top_k: int, *, held=None, row_factor: float = 1.5,
+                 router: Optional[Module] = None,
                  name: Optional[str] = None):
         super().__init__(name)
         lo, hi = held if held is not None else (0, n_experts)
@@ -141,40 +260,31 @@ class ExpertParallelMoE(Module):
         self.held = (lo, hi)
         self.n_held = hi - lo
         self.row_factor = row_factor
+        self.router = router if router is not None else LinearTopKRouter(
+            hidden_size, n_experts, top_k)
 
     def init(self, rng):
         k_r, k_in, k_out = jax.random.split(rng, 3)
-        D, F, E, n = (self.hidden_size, self.expert_width, self.n_experts,
-                      self.n_held)
+        D, F, n = self.hidden_size, self.expert_width, self.n_held
         xav = Xavier()
-        params = {"router": xav.init(k_r, (D, E), D, E),
+        router, router_state = self.router.init(k_r)
+        params = {"router": router,
                   "w_in": xav.init(k_in, (n, D, 2 * F), D, 2 * F),
                   "w_out": xav.init(k_out, (n, F, D), F, D)}
-        # two buffers, not one twice: the optimizer donates its state
+        # a buffer each, not one twice: the optimizer donates its state
         return params, {"rows_held": jnp.zeros((2,), jnp.int32),
-                        "rows_overflow": jnp.zeros((2,), jnp.int32)}
+                        "rows_overflow": jnp.zeros((2,), jnp.int32),
+                        "rows_by_expert": jnp.zeros((n, 2), jnp.int32),
+                        "router": router_state}
 
     def n_rows(self, tokens: int) -> int:
         return expert_rows(tokens, self.top_k, self.n_held, self.n_experts,
                            self.row_factor)
 
     def route(self, router, x):
-        """``(gates (T, k) f32, experts (T, k) int32)`` of tokens
-        ``x`` (T, D): logits over all experts in f32 at the highest
-        precision (a tie broken the other way is another expert)."""
-        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        _, experts = jax.lax.top_k(logits, self.top_k)
-        # The logits are read AT the experts chosen (a masked sum: the
-        # values top_k gives, bit for bit), so that a backward which
-        # keeps the choice by name and makes ``x`` a second time
-        # differentiates the forward's choice.  Its own top-k differs on
-        # some tokens (the second ``x`` rounds otherwise in bf16); rows of
-        # ``h`` kept from the forward would then belong to other tokens
-        experts = checkpoint_name(experts, MOE_H)
-        chosen = experts[..., None] == jnp.arange(self.n_experts)
-        values = jnp.sum(jnp.where(chosen, logits[:, None, :], 0), axis=-1)
-        return jax.nn.softmax(values, axis=-1), experts
+        """``(gates, experts)`` of tokens ``x`` (T, D) by a router that
+        carries nothing from layer to layer."""
+        return self.router.route(router, {}, x)
 
     def plan(self, experts, rows: int):
         """Where each assignment goes.  ``experts``: (T, k).  Returns
@@ -202,11 +312,14 @@ class ExpertParallelMoE(Module):
                 jnp.sum(held.astype(jnp.int32)) - n_fit)
 
     def apply(self, params, state, input, *, training=False, rng=None):
+        input, *carried = input if isinstance(input, tuple) else (input,)
         N, T, D = input.shape
         x = input.reshape(N * T, D)
         R = self.n_rows(N * T)
         with device_scope("moe.route"):
-            gates, experts = self.route(params["router"], x)
+            gates, experts, *carried = self.router.route(
+                params["router"], state["router"], x,
+                *(c.reshape(N * T, -1) for c in carried))
             row, sizes, n_fit, n_over = self.plan(experts, R)
         with device_scope("moe.dispatch"):
             # row -> its token and gate (an index scatter, T*k scalars);
@@ -231,10 +344,18 @@ class ExpertParallelMoE(Module):
                 gates.reshape(-1), mode="drop"), MOE_H)     # as token, used
             out = jnp.zeros((N * T, D), jnp.float32).at[token].add(
                 ys.astype(jnp.float32) * gate[:, None])
+        # the rows each held expert computed: its group, less the padding
+        # that ``plan`` counts to the last one
+        by_expert = sizes.at[-1].add(n_fit - R)
         new_state = {"rows_held": count_add(state["rows_held"], n_fit),
                      "rows_overflow": count_add(state["rows_overflow"],
-                                                n_over)}
-        return out.astype(input.dtype).reshape(N, T, D), new_state
+                                                n_over),
+                     "rows_by_expert": jax.vmap(count_add)(
+                         state["rows_by_expert"], by_expert),
+                     "router": state["router"]}
+        outs = (out.astype(input.dtype).reshape(N, T, D),
+                *(c.reshape(N, T, -1) for c in carried))
+        return (outs if len(outs) > 1 else outs[0]), new_state
 
     def state_warnings(self, state) -> list:
         """What the counters say that a user has to hear (host side,

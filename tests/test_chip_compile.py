@@ -400,7 +400,7 @@ class _CheckpointedLayers(nn.Module):
         return [p for p, _ in made], [s for _, s in made]
 
     def apply(self, params, state, input, *, training=False, rng=None):
-        from bigdl_tpu.models.granite_moe_hybrid import checkpointed
+        from bigdl_tpu.models.share import checkpointed
         h = input
         for m, p, s in zip(self.layers, params, state):
             h, _ = checkpointed(m)(p, s, h)
@@ -441,3 +441,54 @@ def test_granite_layers_checkpoint_keeps_the_up_projections(one_chip):
     # read 1,282,366,464 bytes (v5e:2x2 compile, PR 33): the kept arrays
     # of two layers are 0.20 GB of it
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# ------------------------------------ ZAYA1-8B: one chip's share (PR 35)
+def test_zaya_layers_keep_the_grouped_kernels_and_no_full_scores(one_chip):
+    """Two layers of ``benchmarks/configs/zaya1-8b-share2.json`` at the
+    published widths and the cell's two records of 8,192 tokens, stacked
+    and checkpointed as the model does it, forward and backward in bf16
+    with the router's state crossing the checkpoints in f32: the expert
+    layer behind the MLP router still lowers to the compiler's grouped
+    kernels, seven a layer with ``rows W_in`` made once (granite's
+    counts: the router is a part, the path is one), every kernel lies
+    under a named scope, and attention in the latent never holds the
+    scores of all 8,192 queries."""
+    from benchmarks import hlo_scopes, lib
+    from bigdl_tpu.models.share import checkpointed
+    from bigdl_tpu.models.zaya import ZayaLayer
+    builder = lib.load_module("builders", "zaya")
+    cfg = lib.load_json("configs", "zaya1-8b-share2")
+    whole, share = builder.whole_config(cfg), builder.share(cfg)
+    layers = [ZayaLayer(whole, share, first=j == 0,
+                        row_factor=cfg["train"]["row_factor"])
+              for j in range(2)]
+    assert layers[0].experts.n_rows(2 * 8192) == 16384
+    shapes = [jax.eval_shape(m.init, jax.random.PRNGKey(0)) for m in layers]
+    params = [jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, jnp.bfloat16), p)
+        for p, _ in shapes]
+
+    def loss(ps, h, r):
+        for m, p, (_, s) in zip(layers, ps, shapes):
+            state = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype), s)
+            (h, r), _ = checkpointed(m)(p, state, (h, r))
+        return jnp.sum(h.astype(jnp.float32) ** 2) + jnp.sum(r ** 2)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), params,
+                        _spec(one_chip, (2, 8192, 2048), jnp.bfloat16),
+                        _spec(one_chip, (2, 8192, 256), jnp.float32))
+    text = compiled.as_text()
+    grouped = re.findall(r"= (\S+) custom-call\([^\n]*ragged_dot_tiling",
+                         text)
+    assert len(grouped) == 14
+    assert sum(r.startswith("bf16[16384,4096]") for r in grouped) == 2
+    placed = hlo_scopes.instruction_scopes(text, builder.COMPILER_OPS)
+    assert hlo_scopes.unscoped_kernels(text, placed) == []
+    assert {"bigdl.cca.project", "bigdl.cca.mix", "bigdl.cca.attend",
+            "bigdl.cca.out", "bigdl.moe.route"} <= set(placed.values())
+    # all scores of the 4 heads held of 2 records: 2 x 4 x 8192 x 8192
+    # x 4 = 2.1 GB in f32; a block of 1,024 queries holds an eighth
+    assert not re.search(r"f32\[[0-9,]*8192,8192\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9

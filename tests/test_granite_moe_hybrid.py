@@ -23,8 +23,8 @@ import pytest
 
 from bigdl_tpu import nn, optim
 from bigdl_tpu.models import granite_moe_hybrid
-from bigdl_tpu.models.granite_moe_hybrid import (GraniteMoeHybridLayer,
-                                                 checkpointed)
+from bigdl_tpu.models.granite_moe_hybrid import GraniteMoeHybridLayer
+from bigdl_tpu.models.share import checkpointed
 from bigdl_tpu.nn.attention import dot_product_attention
 from bigdl_tpu.nn.moe import COUNT_WORD, count_add, count_value as count
 
@@ -306,7 +306,7 @@ def test_model_against_reference(share):
 # each array at CFG's sizes.  The k experts chosen; rows W_in and each
 # row's token, use and gate (R = 256 rows for these 32 tokens: one
 # ROW_TILE); x W_in of the shared expert.  Neither mixer tags anything
-_TAGGED = {"ExpertParallelMoE.route": [CFG["num_experts_per_tok"]],
+_TAGGED = {"LinearTopKRouter.route": [CFG["num_experts_per_tok"]],
            "ExpertParallelMoE.apply": [2 * CFG["intermediate_size"],
                                        256, 256, 256],
            "GatedMLP.apply": [2 * CFG["shared_intermediate_size"]]}
@@ -468,10 +468,10 @@ def test_eight_shares_of_an_expert_block_add_up():
     x = jax.random.normal(key(1), (2, 24, 32))
     total, rows = shared.apply(ps, {}, x)[0], 0
     for i in range(SHARES):
-        part, _, _ = _moe((2 * i, 2 * i + 2))
+        part, _, s_part = _moe((2 * i, 2 * i + 2))
         out, new = part.apply(
             {"router": p["router"], "w_in": p["w_in"][2 * i:2 * i + 2],
-             "w_out": p["w_out"][2 * i:2 * i + 2]}, s, x)
+             "w_out": p["w_out"][2 * i:2 * i + 2]}, s_part, x)
         total, rows = total + out, rows + count(new["rows_held"])
         assert count(new["rows_overflow"]) == 0
     assert rows == 48 * 4               # every assignment, once
