@@ -40,6 +40,32 @@ lock.  Metrics land on a :class:`~bigdl_tpu.serving.ServingMetrics`
 (dispatch accounting reads as step occupancy: ``record_dispatch(active,
 slots)`` per step, so ``mean_batch_occupancy`` is the continuous-batching
 win).
+
+Measured from inside (PR 37).  With a :class:`~bigdl_tpu.telemetry.Tracer`
+(``tracer=``, or one of the service's own under
+``Config.telemetry_enabled`` / ``BIGDL_TPU_TELEMETRY=1``) four top-level
+spans tile every pass of the scheduler's loop — ``idle``, ``schedule``,
+one ``admit`` a prefill, one ``step`` — and their children name what the
+host does inside (``prefill_launch``, ``splice_launch``, ``first_fetch``,
+``emit``; ``step_h2d``, ``dispatch``, ``device_wait``, ``step_fetch``,
+``emit``); each request leaves a ``queue_wait`` and a ``sequence`` span
+on tracks of their own.  Which span lies in which category:
+:data:`SPAN_CATS`; which categories are top-level:
+``telemetry.tracer.DECODE_PHASE_CATS``.  Every span on the scheduler's
+thread is mirrored into a running profiler capture
+(``benchmarks/host_spans.py`` finds the thread by its ``dispatch`` span),
+and as it closes its time goes into a histogram of the service's
+registry (``decode/span_ms/<name>``), which is what ``stats()`` reads:
+exact sums and counts since the start and a median over the newest 4,096
+spans, however long the service has run.  The tracer's own buffer
+(``/trace``, ``Tracer.dump``) holds the FIRST ``capacity`` events and
+then drops and counts (``trace_dropped_events`` in ``stats()``;
+``tracer.clear()`` arms it again); the mirror and the histograms go on.
+With no tracer the loop makes no span: a call site then costs one call
+that returns the shared no-op.  The counters and histograms of the
+work itself (queue wait, step time, key/value positions used against
+reserved, prefill padding, the bytes an admission fetches, expiries by
+place) are always on.
 """
 
 from __future__ import annotations
@@ -57,8 +83,44 @@ from bigdl_tpu.serving.batcher import (DeadlineExceeded, RequestSpecError,
                                        _Request, settle_future)
 from bigdl_tpu.serving.metrics import ServingMetrics
 from bigdl_tpu.serving.service import parse_row_buckets
+from bigdl_tpu.telemetry.tracer import DECODE_PHASE_CATS, NULL_SPAN
 
 logger = logging.getLogger("bigdl_tpu.serving")
+
+# every span the scheduler's thread makes, and its category
+SPAN_CATS = {
+    "idle": "decode_idle", "schedule": "decode_schedule",
+    "admit": "decode_admit", "step": "decode_step",
+    "prefill_launch": "decode_launch", "splice_launch": "decode_launch",
+    "dispatch": "decode_launch",
+    "first_fetch": "decode_fetch", "step_fetch": "decode_fetch",
+    "step_h2d": "decode_h2d", "device_wait": "decode_device_wait",
+    "emit": "decode_emit",
+}
+
+
+class _TimedSpan:
+    """A tracer's span whose time, as it closes, also goes into the
+    service's histogram of that span name (the running state ``stats()``
+    reads).  ``with`` hands back the tracer's own span (``set``,
+    ``dur_ns``)."""
+
+    __slots__ = ("_sp", "_hist", "_svc")
+
+    def __init__(self, sp, hist, svc):
+        self._sp = sp
+        self._hist = hist
+        self._svc = svc     # of a top-level span; ``None`` of a child
+
+    def __enter__(self):
+        return self._sp.__enter__()
+
+    def __exit__(self, *exc):
+        self._sp.__exit__(*exc)
+        self._hist.observe(self._sp.dur_ns / 1e6)
+        if self._svc is not None:
+            self._svc._loop_t1_ns = time.perf_counter_ns()
+        return False
 
 
 class DecodeResult:
@@ -95,30 +157,36 @@ class DecodeResult:
 class _Pending:
     """A queued decode request: the generic :class:`_Request` (future /
     deadline / ctx / t_enqueue — the existing request path) plus the
-    decode-only fields that don't fit its __slots__."""
+    decode-only fields that don't fit its __slots__.  ``rid`` names the
+    request in every span it leaves (the caller's ``ctx.trace_id``, else
+    the service's count of submissions); ``t_submit_ns`` is stamped only
+    when the service holds a tracer."""
 
-    __slots__ = ("req", "max_new", "on_token")
+    __slots__ = ("req", "max_new", "on_token", "rid", "t_submit_ns")
 
     def __init__(self, req: _Request, max_new: int, on_token):
         self.req = req
         self.max_new = max_new
         self.on_token = on_token
+        self.rid = None
+        self.t_submit_ns = 0
 
 
 class _Sequence:
     """One active slot: scheduler-thread-owned bookkeeping."""
 
     __slots__ = ("pend", "prompt_len", "bucket", "generated",
-                 "admit_step", "slot")
+                 "admit_step", "slot", "t_admit_ns")
 
     def __init__(self, pend: _Pending, prompt_len: int, bucket: int,
-                 admit_step: int, slot: int):
+                 admit_step: int, slot: int, t_admit_ns: int = 0):
         self.pend = pend
         self.prompt_len = prompt_len
         self.bucket = bucket
         self.generated: List[int] = []
         self.admit_step = admit_step
         self.slot = slot
+        self.t_admit_ns = t_admit_ns  # start of its ``sequence`` span
 
 
 class DecodeService:
@@ -148,6 +216,13 @@ class DecodeService:
     - ``priority_fn``: the batcher's QoS contract — maps a queued
       ``_Request`` to an int rank (lower admits first), engaged only
       under pressure (more queued than free slots).
+    - ``tracer``: a :class:`~bigdl_tpu.telemetry.Tracer` for the
+      scheduler's spans (module docstring); ``None`` makes one when
+      ``Config.telemetry_enabled`` is set and otherwise holds none, and
+      the loop then makes no span.  ``stats()["decode"]`` then holds
+      ``spans`` (per span name: category, seconds, count, median),
+      ``host_step_ms`` (a step less its ``device_wait``) and
+      ``loop_unspanned_share``, all from running sums.
 
     Greedy (argmax) decoding — deterministic, so serving output equals
     the full-context reference run token-for-token (the acceptance
@@ -168,7 +243,8 @@ class DecodeService:
                  queue_capacity: int = 64,
                  deadline_ms: Optional[float] = None,
                  name: str = "decode", mesh=None,
-                 registry=None, priority_fn=None, start: bool = True):
+                 registry=None, priority_fn=None, tracer=None,
+                 start: bool = True):
         import jax
         import jax.numpy as jnp
 
@@ -237,6 +313,46 @@ class DecodeService:
         self._c_admissions = reg.counter("decode/admissions")
         self._c_reclaims = reg.counter("decode/slots_reclaimed")
         self._c_active_steps = reg.counter("decode/active_slot_steps")
+        self._h_queue_wait = reg.histogram("decode/queue_wait_ms")
+        self._h_step = reg.histogram("decode/step_ms")
+        self._c_kv_used = reg.counter("decode/kv_positions_used")
+        self._c_kv_reserved = reg.counter("decode/kv_positions_reserved")
+        self._c_prefill_tokens = reg.counter("decode/prefill_tokens")
+        self._c_prefill_padded = reg.counter("decode/prefill_tokens_padded")
+        self._c_first_fetch_bytes = reg.counter("decode/first_fetch_bytes")
+        self._c_expired_queued = reg.counter("decode/expired_before_admit")
+        self._c_expired_active = reg.counter("decode/expired_mid_decode")
+
+        # the tracer: resolved ONCE here; ``None`` is the off state and
+        # every span of the loop goes through ``_span``, which tests it
+        if tracer is None:
+            from bigdl_tpu.utils.config import get_config
+            cfg = get_config()
+            if cfg.telemetry_enabled:
+                from bigdl_tpu.telemetry.tracer import Tracer
+                tracer = Tracer(capacity=cfg.telemetry_trace_capacity)
+        self.tracer = tracer if tracer is not None and tracer.enabled \
+            else None
+        # the spans' running state, one histogram a span name, fed as
+        # each span closes (scheduler's thread) and read by ``stats()``
+        self._span_hist = self._h_host_step = None
+        if self.tracer is not None:
+            self._span_hist = {n: reg.histogram(f"decode/span_ms/{n}")
+                               for n in SPAN_CATS}
+            self._h_host_step = reg.histogram("decode/host_step_ms")
+        # the loop's start and the end of its last top-level span;
+        # written by the scheduler only, read racily by ``stats()``
+        self._loop_t0_ns = self._loop_t1_ns = 0
+        # admin plane, as the predict engine registers: the registry,
+        # and the tracer when one is held, under a name of its own
+        from bigdl_tpu.telemetry import admin as _admin
+        self._admin_name: Optional[str] = None
+        _srv = _admin.maybe_start()
+        if _srv is not None:
+            self._admin_name = _srv.unique_source_name(self.name)
+            _srv.add_registry(self._admin_name, reg)
+            if self.tracer is not None:
+                _srv.add_tracer(self._admin_name, self.tracer)
 
         self._priority_fn = priority_fn
         self._priority_aging_s = 0.5  # same starvation bound as batcher
@@ -247,7 +363,9 @@ class DecodeService:
         self._n_active = 0       # guarded-by: _cond
         self._stopping = False   # guarded-by: _cond
         self._drain = True       # guarded-by: _cond
-        self._steps_done = 0     # guarded-by: _cond
+        self._n_submitted = 0    # guarded-by: _cond
+        # written by the scheduler only; it reads its own count lock-free
+        self._steps_done = 0     # write-guarded-by: _cond
         # step-seconds EWMA; written by the scheduler only, read racily
         # for overload retry hints (a stale hint is still a hint)
         self._step_ewma: Optional[float] = None
@@ -258,6 +376,7 @@ class DecodeService:
         self._seqs: List[Optional[_Sequence]] = [None] * slots
         self._lengths = np.zeros((slots,), np.int32)  # cached positions
         self._last_tok = np.zeros((slots,), np.int32)
+        self._h2d_bytes = self._last_tok.nbytes + self._lengths.nbytes
         full, fdtype = kv_cache_spec(model, slots, self.max_seq_len)
         self._k = jnp.zeros(full, fdtype)
         self._v = jnp.zeros(full, fdtype)
@@ -314,6 +433,8 @@ class DecodeService:
             rep_sh = kv_sh = None
             lkv_out = kv_out = {}
         kspec = sds(full, fdtype, sharding=kv_sh)
+        # leaves handed to the step's executable a launch
+        self._step_leaves = len(jax.tree_util.tree_leaves(self._params)) + 4
         self._step_exec = _aot(
             jax.jit(_step_fn, **lkv_out), self._params,
             sds((slots,), i32, sharding=rep_sh),
@@ -383,6 +504,11 @@ class DecodeService:
             self._cond.notify_all()
         if t is not None:
             t.join(timeout)
+        if self._admin_name is not None:
+            from bigdl_tpu.telemetry import admin as _admin
+            _srv = _admin.current()
+            if _srv is not None:
+                _srv.remove_source(self._admin_name)
 
     def __enter__(self):
         return self
@@ -421,6 +547,8 @@ class DecodeService:
             deadline = time.monotonic() + self.deadline_s
         req = _Request(x.astype(np.int32), 1, deadline=deadline, ctx=ctx)
         pend = _Pending(req, max_new, on_token)
+        if self.tracer is not None:
+            pend.t_submit_ns = time.perf_counter_ns()
         with self._cond:
             if self._stopping:
                 raise ServiceClosed(f"decode service {self.name!r} is "
@@ -430,6 +558,9 @@ class DecodeService:
                 raise ServiceOverloaded(
                     len(self._queue), self.queue_capacity, self.name,
                     retry_after_ms=self._retry_hint_locked())
+            self._n_submitted += 1
+            pend.rid = (getattr(ctx, "trace_id", None)
+                        or self._n_submitted)
             self._queue.append(pend)
             self._cond.notify_all()
         self.metrics.record_submit(1)
@@ -485,6 +616,18 @@ class DecodeService:
                 picked.append(self._queue.popleft())
         return picked
 
+    def _span(self, name: str, **args):
+        """A span of the scheduler's thread (``SPAN_CATS``); the shared
+        no-op without a tracer (the driver's ``_tel_span`` discipline).
+        Call sites pass only values they hold already."""
+        tr = self.tracer
+        if tr is None:
+            return NULL_SPAN
+        cat = SPAN_CATS[name]
+        return _TimedSpan(tr.span(name, cat=cat, **args),
+                          self._span_hist[name],
+                          self if DECODE_PHASE_CATS[cat] else None)
+
     def _emit(self, seq: _Sequence, index: int, token: int) -> None:
         cb = seq.pend.on_token
         if cb is None:
@@ -505,38 +648,71 @@ class DecodeService:
         """Prefill one sequence into ``slot`` (scheduler thread)."""
         import jax.numpy as jnp
         req = pend.req
-        now = time.monotonic()
-        if req.deadline is not None and now >= req.deadline:
-            if settle_future(req.future, exc=DeadlineExceeded(
-                    f"deadline expired before admission "
-                    f"(model={self.name})")):
-                self.metrics.record_failure(1)
-            return
         prompt = req.x
         n = int(prompt.shape[0])
         tb = self._bucket_for(n)
-        padded = np.zeros((1, tb), np.int32)
-        padded[0, :n] = prompt
-        lp, kp, vp = self._prefill_exec[tb](self._params,
-                                            jnp.asarray(padded))
-        self._k, self._v = self._splice_exec[tb](
-            self._k, self._v, kp, vp, np.int32(slot))
-        self.metrics.record_dispatch(1, 1)  # prefill dispatch
-        first = int(np.asarray(lp)[0, n - 1].argmax())
-        with self._cond:
-            admit_step = self._steps_done
-            self._n_active += 1
-        seq = _Sequence(pend, n, tb, admit_step, slot)
-        self._seqs[slot] = seq
-        self._lengths[slot] = n
-        self._last_tok[slot] = first
-        self._c_admissions.inc()
-        seq.generated.append(first)
-        self._c_tokens.inc()
-        self._emit(seq, 0, first)
-        # a 1-token request (or instant EOS) finishes without ever
-        # joining the step batch
-        self._maybe_finish(seq, first)
+        now = time.monotonic()
+        wait_ms = (now - req.t_enqueue) * 1e3
+        tr = self.tracer
+        # one stamp ends the request's wait and starts its sequence
+        t_admit_ns = time.perf_counter_ns() if tr is not None else 0
+        with self._span("admit", req=pend.rid, slot=slot, prompt_len=n,
+                        bucket=tb):
+            if tr is not None:
+                tr.record("queue_wait", pend.t_submit_ns, t_admit_ns,
+                          cat="decode_queue", track="queue", req=pend.rid)
+            if req.deadline is not None and now >= req.deadline:
+                self._c_expired_queued.inc()
+                if settle_future(req.future, exc=DeadlineExceeded(
+                        f"deadline expired before admission "
+                        f"(model={self.name})")):
+                    self.metrics.record_failure(1)
+                return
+            self._h_queue_wait.observe(wait_ms)
+            padded = np.zeros((1, tb), np.int32)
+            padded[0, :n] = prompt
+            with self._span("prefill_launch", bucket=tb):
+                lp, kp, vp = self._prefill_exec[tb](self._params,
+                                                    jnp.asarray(padded))
+            with self._span("splice_launch", bucket=tb):
+                self._k, self._v = self._splice_exec[tb](
+                    self._k, self._v, kp, vp, np.int32(slot))
+            self.metrics.record_dispatch(1, 1)  # prefill dispatch
+            nbytes = int(lp.nbytes)
+            with self._span("first_fetch", bytes=nbytes):
+                lp_host = np.asarray(lp)  # waits for the prefill
+            self._c_first_fetch_bytes.inc(nbytes)
+            self._c_prefill_tokens.inc(n)
+            self._c_prefill_padded.inc(tb)
+            with self._span("emit"):
+                first = int(lp_host[0, n - 1].argmax())
+                with self._cond:
+                    admit_step = self._steps_done
+                    self._n_active += 1
+                seq = _Sequence(pend, n, tb, admit_step, slot, t_admit_ns)
+                self._seqs[slot] = seq
+                self._lengths[slot] = n
+                self._last_tok[slot] = first
+                self._c_admissions.inc()
+                seq.generated.append(first)
+                self._c_tokens.inc()
+                self._emit(seq, 0, first)
+                # a 1-token request (or instant EOS) finishes without
+                # ever joining the step batch
+                self._maybe_finish(seq, first)
+
+    def _close_sequence(self, seq: _Sequence, reason: str,
+                        finish_step: int) -> None:
+        """The request's ``sequence`` span, admission to finish, on its
+        slot's track."""
+        tr = self.tracer
+        if tr is None:
+            return
+        tr.record(
+            "sequence", seq.t_admit_ns, time.perf_counter_ns(),
+            cat="decode_sequence", track=f"slot-{seq.slot}",
+            req=seq.pend.rid, tokens=len(seq.generated), reason=reason,
+            admit_step=seq.admit_step, finish_step=finish_step)
 
     def _finish(self, seq: _Sequence, reason: str) -> None:
         with self._cond:
@@ -547,6 +723,7 @@ class DecodeService:
         self._lengths[seq.slot] = 0
         self._last_tok[seq.slot] = 0
         self._c_reclaims.inc()
+        self._close_sequence(seq, reason, finish_step)
         res = DecodeResult(np.asarray(seq.generated, np.int32), reason,
                            seq.admit_step, finish_step, seq.slot,
                            seq.prompt_len, seq.bucket)
@@ -557,12 +734,14 @@ class DecodeService:
 
     def _fail(self, seq: _Sequence, exc: BaseException) -> None:
         with self._cond:
+            finish_step = self._steps_done
             self._n_active -= 1
             self._cond.notify_all()
         self._seqs[seq.slot] = None
         self._lengths[seq.slot] = 0
         self._last_tok[seq.slot] = 0
         self._c_reclaims.inc()
+        self._close_sequence(seq, type(exc).__name__, finish_step)
         if settle_future(seq.pend.req.future, exc=exc):
             self.metrics.record_failure(1)
 
@@ -585,36 +764,69 @@ class DecodeService:
         many sequences are active (the inactive lanes compute discarded
         garbage; occupancy is the metric that prices this)."""
         import jax.numpy as jnp
+        tr = self.tracer
         t0 = time.monotonic()
         active = [s for s in self._seqs if s is not None]
-        lp, self._k, self._v = self._step_exec(
-            self._params, jnp.asarray(self._last_tok),
-            jnp.asarray(self._lengths), self._k, self._v)
-        lp_host = np.asarray(lp)  # device sync point
-        dt = time.monotonic() - t0
-        self._step_ewma = (dt if self._step_ewma is None
-                           else 0.8 * self._step_ewma + 0.2 * dt)
-        with self._cond:
-            self._steps_done += 1
-        self._c_steps.inc()
-        self._c_active_steps.inc(len(active))
-        self.metrics.record_dispatch(len(active), self.slots)
-        now = time.monotonic()
-        for seq in active:
-            # cache grew by one position (the step wrote last_tok's K/V)
-            self._lengths[seq.slot] += 1
-            if (seq.pend.req.deadline is not None
-                    and now >= seq.pend.req.deadline):
-                self._fail(seq, DeadlineExceeded(
-                    f"deadline expired mid-decode after "
-                    f"{len(seq.generated)} tokens (model={self.name})"))
-                continue
-            tok = int(lp_host[seq.slot].argmax())
-            self._last_tok[seq.slot] = tok
-            seq.generated.append(tok)
-            self._c_tokens.inc()
-            self._emit(seq, len(seq.generated) - 1, tok)
-            self._maybe_finish(seq, tok)
+        with self._span("step", step=self._steps_done,
+                        active=len(active)) as st:
+            # positions the step attends over (a free slot's length is
+            # 0), against what the strips reserve
+            self._c_kv_used.inc(int(self._lengths.sum()))
+            self._c_kv_reserved.inc(self.slots * self.max_seq_len)
+            with self._span("step_h2d", bytes=self._h2d_bytes):
+                tokens = jnp.asarray(self._last_tok)
+                lengths = jnp.asarray(self._lengths)
+            with self._span("dispatch", args=self._step_leaves):
+                lp, self._k, self._v = self._step_exec(
+                    self._params, tokens, lengths, self._k, self._v)
+            if tr is None:
+                lp_host = np.asarray(lp)  # device sync point
+            else:
+                # the same fetch, split into the wait and the copy
+                with self._span("device_wait") as wait:
+                    lp.block_until_ready()
+                with self._span("step_fetch", bytes=int(lp.nbytes)):
+                    lp_host = np.asarray(lp)
+            dt = time.monotonic() - t0
+            self._step_ewma = (dt if self._step_ewma is None
+                               else 0.8 * self._step_ewma + 0.2 * dt)
+            self._h_step.observe(dt * 1e3)
+            with self._cond:
+                self._steps_done += 1
+            self._c_steps.inc()
+            self._c_active_steps.inc(len(active))
+            self.metrics.record_dispatch(len(active), self.slots)
+            with self._span("emit") as sp:
+                now = time.monotonic()
+                emitted = 0
+                for seq in active:
+                    # cache grew by one position (the step wrote
+                    # last_tok's K/V)
+                    self._lengths[seq.slot] += 1
+                    if (seq.pend.req.deadline is not None
+                            and now >= seq.pend.req.deadline):
+                        self._c_expired_active.inc()
+                        self._fail(seq, DeadlineExceeded(
+                            f"deadline expired mid-decode after "
+                            f"{len(seq.generated)} tokens "
+                            f"(model={self.name})"))
+                        continue
+                    tok = int(lp_host[seq.slot].argmax())
+                    self._last_tok[seq.slot] = tok
+                    seq.generated.append(tok)
+                    self._c_tokens.inc()
+                    emitted += 1
+                    self._emit(seq, len(seq.generated) - 1, tok)
+                    self._maybe_finish(seq, tok)
+                sp.set(tokens=emitted)
+        if tr is not None:
+            # what the host adds to this step: the step less its wait
+            self._h_host_step.observe((st.dur_ns - wait.dur_ns) / 1e6)
+
+    def _idle_locked(self) -> bool:  # guarded-by: _cond
+        """Nothing queued, nothing active, no stop asked for."""
+        return (not self._stopping and not self._queue
+                and self._n_active == 0)
 
     def _cancel_backlog_locked(self) -> List[_Pending]:  # guarded-by: _cond
         out = list(self._queue)
@@ -624,25 +836,34 @@ class DecodeService:
     def _run(self) -> None:
         """The decode loop.  Each pass: admit queued sequences into free
         slots (prefill off the lock), then run one step if anything is
-        active.  Blocks on the condition when idle.  An unexpected
+        active.  Blocks on the condition when idle.  Four top-level
+        spans tile a pass: ``idle``, ``schedule``, ``admit``, ``step``
+        (``DECODE_PHASE_CATS``).  An unexpected
         exception anywhere in the loop fails every in-flight future
         with it instead of dying silently — a crashed scheduler with
         live futures would park every ``generate()`` caller forever."""
         cancelled: List[_Pending] = []
         crash: Optional[BaseException] = None
+        if self.tracer is not None:
+            self._loop_t0_ns = self._loop_t1_ns = time.perf_counter_ns()
         try:
             while True:
                 with self._cond:
-                    while (not self._stopping and not self._queue
-                           and self._n_active == 0):
-                        self._cond.wait()
-                    if self._stopping and (
-                            not self._drain
-                            or (not self._queue and self._n_active == 0)):
-                        cancelled = self._cancel_backlog_locked()
-                        break
+                    if self._idle_locked():
+                        with self._span("idle"):
+                            while self._idle_locked():
+                                self._cond.wait()
                     free = self.slots - self._n_active
-                    to_admit = self._pick_admissions_locked(free)
+                    with self._span("schedule", queued=len(self._queue),
+                                    free=free) as sp:
+                        if self._stopping and (
+                                not self._drain
+                                or (not self._queue
+                                    and self._n_active == 0)):
+                            cancelled = self._cancel_backlog_locked()
+                            break
+                        to_admit = self._pick_admissions_locked(free)
+                        sp.set(picked=len(to_admit))
                 for slot in range(self.slots):
                     if not to_admit:
                         break
@@ -673,11 +894,44 @@ class DecodeService:
                 self._fail(seq, exc)
 
     # -------------------------------------------------------------- stats
+    def _span_stats(self) -> dict:
+        """The spans' running state: per span name its category, exact
+        seconds and count since the start and the median of the newest
+        4,096; a step less its ``device_wait``, paired as the step
+        closed; and the share of the scheduler's time, from its loop's
+        start to the end of its last top-level span, that no top-level
+        span covered."""
+        # the window's end before the sums: a span that closes between
+        # the two reads can only add to what is covered
+        loop_ms = (self._loop_t1_ns - self._loop_t0_ns) / 1e6
+        spans = {}
+        covered_ms = 0.0
+        for name, h in self._span_hist.items():
+            if not h.count:
+                continue
+            cat = SPAN_CATS[name]
+            spans[name] = {"cat": cat, "seconds": h.sum / 1e3,
+                           "spans": h.count,
+                           "median_ms": h.percentiles((50,))["p50"]}
+            if DECODE_PHASE_CATS[cat]:
+                covered_ms += h.sum
+        host = self._h_host_step.percentiles((50,))
+        return {
+            "spans": spans,
+            "host_step_ms": host["p50"] if host else None,
+            "loop_unspanned_share": (max(0.0, 1.0 - covered_ms / loop_ms)
+                                     if loop_ms > 0 else None),
+            "trace_dropped_events": self.tracer.dropped_events,
+        }
+
     def stats(self) -> dict:
         """The ``service.stats()`` schema plus a ``decode`` section:
-        step/token/admission accounting and step-level occupancy
+        step/token/admission accounting, step-level occupancy
         (active-slot-steps over total slot-steps — the continuous-
-        batching utilization figure)."""
+        batching utilization figure), the counters and histograms of
+        the module docstring, and with a tracer the spans' running
+        sums (:meth:`_span_stats`).  Counts run since the service
+        started."""
         with self._cond:
             qd = len(self._queue)
             steps = self._steps_done
@@ -695,9 +949,21 @@ class DecodeService:
             "step_occupancy": (
                 round(self._c_active_steps.value / (steps * self.slots), 4)
                 if steps else None),
+            # for overload retry hints only; read ``step_ms``
             "step_ms_ewma": round(ew * 1e3, 3) if ew is not None else None,
+            "step_ms": self._h_step.snapshot(),
+            "queue_wait_ms": self._h_queue_wait.snapshot(),
+            "kv_positions_used": self._c_kv_used.value,
+            "kv_positions_reserved": self._c_kv_reserved.value,
+            "prefill_tokens": self._c_prefill_tokens.value,
+            "prefill_tokens_padded": self._c_prefill_padded.value,
+            "first_fetch_bytes": self._c_first_fetch_bytes.value,
+            "expired_before_admit": self._c_expired_queued.value,
+            "expired_mid_decode": self._c_expired_active.value,
             "prefill_buckets": list(self.buckets),
             "max_seq_len": self.max_seq_len,
             "kv_bytes": self.kv_bytes,
         }
+        if self.tracer is not None:
+            snap["decode"].update(self._span_stats())
         return snap

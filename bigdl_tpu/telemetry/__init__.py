@@ -11,7 +11,12 @@ measurement-driven tuning both stand on):
   lies beside them on a track of its own — ``PHASE_CATS``), exported
   as Chrome-trace JSON (summarize with ``python -m tools.trace_report
   trace.json``) and mirrored as ``jax.profiler.TraceAnnotation``s, so
-  any profiler capture holds them beside the device's timeline;
+  any profiler capture holds them beside the device's timeline; the
+  decode service's scheduler thread is tiled the same way
+  (``serving/decode.py``: idle, schedule, admit, step on top;
+  prefill_launch, splice_launch, first_fetch, step_h2d, dispatch,
+  device_wait, step_fetch and emit inside; queue_wait and sequence a
+  request, on tracks of their own — ``DECODE_PHASE_CATS``);
 - :func:`device_scope` — named DEVICE scopes (``bigdl.moe.experts``)
   for work inside a compiled block, which no host span can see: the
   name lands in the instructions' ``op_name`` metadata;
@@ -51,14 +56,17 @@ from bigdl_tpu.telemetry.hooks import DriverTelemetry
 from bigdl_tpu.telemetry.registry import (Counter, Gauge, Histogram,
                                           MetricRegistry, Reservoir)
 from bigdl_tpu.telemetry.scopes import SCOPE_PREFIX, device_scope
-from bigdl_tpu.telemetry.tracer import (NULL_SPAN, OFF_DRIVER_CATS,
-                                        PHASE_CATS, TOP_LEVEL_CATS, Tracer)
+from bigdl_tpu.telemetry.tracer import (DECODE_PHASE_CATS,
+                                        DECODE_TOP_LEVEL_CATS, NULL_SPAN,
+                                        OFF_DRIVER_CATS, PHASE_CATS,
+                                        TOP_LEVEL_CATS, Tracer)
 from bigdl_tpu.telemetry.watchdog import (MemoryWatermark,
                                           RecompileWatchdog, StallDetector,
                                           jit_cache_size)
 
 __all__ = [
-    "AdminServer", "Counter", "DriverTelemetry", "FlightRecorder", "Gauge",
+    "AdminServer", "Counter", "DECODE_PHASE_CATS", "DECODE_TOP_LEVEL_CATS",
+    "DriverTelemetry", "FlightRecorder", "Gauge",
     "Histogram", "MemoryWatermark", "MetricRegistry", "NULL_SPAN",
     "OFF_DRIVER_CATS", "PHASE_CATS", "RecompileWatchdog", "RequestContext",
     "Reservoir", "SCOPE_PREFIX", "StallDetector", "TOP_LEVEL_CATS",

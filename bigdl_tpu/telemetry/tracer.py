@@ -58,6 +58,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -83,6 +86,27 @@ PHASE_CATS = {
 TOP_LEVEL_CATS = tuple(c for c, top in PHASE_CATS.items() if top)
 OFF_DRIVER_CATS = tuple(c for c, top in PHASE_CATS.items() if top is None)
 
+# The same table for the decode service's scheduler thread
+# (``serving/decode.py``): four top-level categories tile every pass of
+# its loop (``idle``, ``schedule``, one ``admit`` a prefill, one ``step``
+# carrying ``step=<index>``); the others lie inside an ``admit``
+# (``prefill_launch`` and ``splice_launch`` in ``decode_launch``,
+# ``first_fetch`` in ``decode_fetch``, ``emit``) or a ``step``
+# (``step_h2d``, ``dispatch`` in ``decode_launch``, ``device_wait``,
+# ``step_fetch`` in ``decode_fetch``, ``emit``).  ``None``: a request's
+# own spans, recorded on virtual tracks (``queue_wait`` on ``queue``,
+# ``sequence`` on ``slot-<i>``), which overlap the scheduler's time.
+DECODE_PHASE_CATS = {
+    "decode_idle": True, "decode_schedule": True, "decode_admit": True,
+    "decode_step": True,
+    "decode_h2d": False, "decode_launch": False,
+    "decode_device_wait": False, "decode_fetch": False,
+    "decode_emit": False,
+    "decode_queue": None, "decode_sequence": None,
+}
+DECODE_TOP_LEVEL_CATS = tuple(c for c, top in DECODE_PHASE_CATS.items()
+                              if top)
+
 _SCALARS = (bool, int, float, str)
 
 
@@ -92,7 +116,7 @@ def _trace_annotation():
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann", "dur_ns")
 
     def __init__(self, tracer: "Tracer", name: str, cat: Optional[str],
                  args: Optional[dict]):
@@ -115,9 +139,18 @@ class _Span:
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
+        # kept for a caller that sums its own spans as they close
+        # (``serving/decode.py``), whatever the buffer still holds
+        self.dur_ns = t1 - self._t0
         self._tr._record("X", self.name, self.cat, self._t0,
-                         t1 - self._t0, self.args)
+                         self.dur_ns, self.args)
         return False
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the span (a count of what it
+        did).  They reach the recorded event; the annotation took its
+        keywords at entry."""
+        self.args = {**self.args, **args} if self.args else args
 
 
 class Tracer:
@@ -222,18 +255,6 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._dropped = 0
-
-    def phase_totals(self) -> Dict[str, float]:
-        """Seconds per span category (instants excluded) — the cheap
-        aggregate; the full self-time attribution lives in
-        ``tools/trace_report.py``."""
-        totals: Dict[str, float] = {}
-        for ph, _name, cat, _t0, dur_ns, _tid, _args, _flow in self.events():
-            if ph != "X":
-                continue
-            key = cat or "uncategorized"
-            totals[key] = totals.get(key, 0.0) + dur_ns / 1e9
-        return totals
 
     # -- export ------------------------------------------------------------
     def to_chrome_trace(self, process_name: str = "bigdl_tpu") -> dict:

@@ -135,15 +135,6 @@ class TestTracer:
         assert _time.perf_counter() - t0 < 0.5
         assert len(t.events()) == 10_000
 
-    def test_phase_totals(self):
-        t = Tracer()
-        t.record("a", 0, 10_000_000, cat="stage")
-        t.record("b", 0, 30_000_000, cat="stage")
-        t.record("c", 0, 5_000_000, cat="dispatch")
-        totals = t.phase_totals()
-        assert totals["stage"] == pytest.approx(0.04)
-        assert totals["dispatch"] == pytest.approx(0.005)
-
 
 # ==========================================================================
 # registry
@@ -396,7 +387,10 @@ class TestStallDetector:
                .set_telemetry(True))
         opt.optimize()
         tel = opt._telemetry
-        totals = tel.tracer.phase_totals()
+        totals = {}
+        for ph, _n, cat, _t0, dur_ns, *_rest in tel.tracer.events():
+            if ph == "X":
+                totals[cat] = totals.get(cat, 0.0) + dur_ns / 1e9
         # the premise: step_args dwarfs the take
         assert totals["step_args"] > 2 * totals["stage"]
         assert tel.stalls.blocks_observed == 6

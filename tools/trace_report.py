@@ -4,24 +4,31 @@ Reads the Chrome-trace JSON the telemetry tracer emits
 (``Tracer.dump`` / ``Config.telemetry_trace_path``) and prints the
 driver-pipeline picture the raw timeline buries:
 
-- **per-phase time share** — self-time per span category (the list is
-  ``telemetry.tracer.PHASE_CATS``) over the trace wall clock, plus
+- **per-phase time share** — self-time per span category (the lists are
+  ``telemetry.tracer.PHASE_CATS`` for a training driver's trace and
+  ``DECODE_PHASE_CATS`` for a decode service's) over the trace wall
+  clock, plus
   ``other`` for unaccounted time, summing to ~1.  Self-time: nested
   spans (a validation span inside a replay span, ``batch_pull`` inside
   ``host_stack`` inside ``stage_next``) are charged to the child, never
   double-counted;
-- **driver coverage** — the whole (not self) time of the four
-  TOP-LEVEL categories (stage_next / dispatch / device_wait / replay),
-  which tile the driver's loop, over the wall clock: what is left is
-  host time no span names;
-- **off the driver** — the whole time of the categories that lie in no
-  span of the driver (``batch_assemble``: the assembler thread's work
-  on each batch) over the wall clock.  It overlaps the driver's time,
-  so it is no part of the phase share;
+- **loop coverage** — the whole (not self) time of the four TOP-LEVEL
+  categories of a loop, which tile it, over the wall clock: what is
+  left is host time no span names.  One figure a loop, never summed:
+  ``driver_coverage`` (stage_next / dispatch / device_wait / replay)
+  and ``decode_coverage`` (decode_idle / decode_schedule /
+  decode_admit / decode_step; two services on one tracer add up);
+- **off the loop's thread** — the whole time of the categories that lie
+  in no span of the loop (``batch_assemble``: the assembler thread's
+  work on each batch; ``decode_queue`` / ``decode_sequence``: a
+  request's wait and its life in a slot) over the wall clock.  It
+  overlaps the loop's time, so it is no part of the phase share;
 - **top spans** — by total duration, with call counts and mean;
 - **stall picture** — device-wait fraction (host blocked on device —
-  healthy when the device is the bottleneck) vs host-stage fraction
-  (device starved by the input pipeline), plus the DISRUPTION count:
+  healthy when the device is the bottleneck; the driver's
+  ``device_wait``, and the decode scheduler's ``decode_device_wait``
+  apart) vs host-stage fraction (device starved by the input pipeline;
+  a decode trace has none and reads 0), plus the DISRUPTION count:
   resilience instants (failover, quarantine, replica death, shed,
   breaker trip, rollback) folded in, because a stall picture that
   ignores the failovers that caused the stalls is half a picture;
@@ -53,13 +60,18 @@ import sys
 from collections import defaultdict
 from typing import Dict, List
 
-from bigdl_tpu.telemetry.tracer import (OFF_DRIVER_CATS, PHASE_CATS,
+from bigdl_tpu.telemetry.tracer import (DECODE_PHASE_CATS,
+                                        DECODE_TOP_LEVEL_CATS, PHASE_CATS,
                                         TOP_LEVEL_CATS)
 
-# spans on virtual tracks (cat "pipeline", and the assembler thread's
-# work) overlap the driver's timeline and are excluded from the phase
-# accounting
-_EXCLUDED_CATS = {"pipeline", *OFF_DRIVER_CATS}
+# both loops' categories: a trace holds the training driver's, the
+# decode scheduler's, or (one tracer shared) both
+_ALL_CATS = {**PHASE_CATS, **DECODE_PHASE_CATS}
+_OFF_LOOP_CATS = tuple(c for c, top in _ALL_CATS.items() if top is None)
+# spans on virtual tracks (cat "pipeline", the assembler thread's work,
+# a decode request's wait and sequence) overlap the loop's timeline and
+# are excluded from the phase accounting
+_EXCLUDED_CATS = {"pipeline", *_OFF_LOOP_CATS}
 
 
 def load_trace(path: str) -> dict:
@@ -115,7 +127,7 @@ def summarize(trace: dict, top: int = 10) -> dict:
 
     off_us: Dict[str, float] = defaultdict(float)
     for s in spans:
-        if s.get("cat") in OFF_DRIVER_CATS:
+        if s.get("cat") in _OFF_LOOP_CATS:
             off_us[s["cat"]] += s.get("dur", 0.0)
 
     self_us = _self_times(host_spans)
@@ -175,11 +187,13 @@ def summarize(trace: dict, top: int = 10) -> dict:
         "phase_share": share,
         "phase_seconds": {c: round(v / 1e6, 6)
                           for c, v in sorted(cat_us.items())},
-        # the top-level categories tile the driver's loop, so their
-        # whole time over the wall clock is the share of the run that
-        # some span names (0.0 for a trace without a driver)
+        # the top-level categories tile their loop, so their whole
+        # time over the wall clock is the share of the run that some
+        # span of that loop names (0.0 for a trace without the loop)
         "driver_coverage": round(sum(whole.get(c, 0.0)
                                      for c in TOP_LEVEL_CATS), 4),
+        "decode_coverage": round(sum(whole.get(c, 0.0)
+                                     for c in DECODE_TOP_LEVEL_CATS), 4),
         "off_driver_share": {c: round(v / wall_us, 4)
                              for c, v in off_us.items()},
         "stall": {
@@ -187,6 +201,8 @@ def summarize(trace: dict, top: int = 10) -> dict:
             # children, and the stall picture asks how long the stager
             # held the driver, whoever did the work inside
             "device_wait_fraction": whole.get("device_wait", 0.0),
+            "decode_device_wait_fraction": whole.get("decode_device_wait",
+                                                     0.0),
             "host_stage_fraction": whole.get("stage", 0.0),
             "dispatch_fraction": whole.get("dispatch", 0.0),
             # the disruption fold (satellite of the admin-plane PR): a
@@ -212,16 +228,23 @@ def _render(report: dict, events: bool = False) -> str:
     lines.append("phase share (self-time / wall; * = top-level):")
     for cat, frac in sorted(report["phase_share"].items(),
                             key=lambda kv: -kv[1]):
-        mark = "*" if PHASE_CATS.get(cat) else ""
-        lines.append(f"  {cat + mark:<14} {frac * 100:6.2f}%")
+        mark = "*" if _ALL_CATS.get(cat) else ""
+        lines.append(f"  {cat + mark:<19} {frac * 100:6.2f}%")
     lines.append(f"driver coverage (top-level spans / wall): "
                  f"{report['driver_coverage']:.3f}")
+    if report["decode_coverage"]:
+        lines.append(f"decode coverage (top-level spans / wall): "
+                     f"{report['decode_coverage']:.3f}")
     for cat, frac in report["off_driver_share"].items():
-        lines.append(f"off the driver's thread (whole time / wall): "
+        lines.append(f"off the loop's thread (whole time / wall): "
                      f"{cat} {frac:.3f}")
     st = report["stall"]
+    decode_wait = (f", decode_device_wait "
+                   f"{st['decode_device_wait_fraction']:.3f}"
+                   if st["decode_device_wait_fraction"] else "")
     lines.append(
-        f"stall picture: device_wait {st['device_wait_fraction']:.3f} "
+        f"stall picture: device_wait {st['device_wait_fraction']:.3f}"
+        f"{decode_wait} "
         f"(host blocked on device), host_stage "
         f"{st['host_stage_fraction']:.3f} (device starved by input), "
         f"{st['disruption_events']} disruption event(s)")
