@@ -37,7 +37,7 @@ auxiliary loss; one ``jax.checkpoint`` a layer.
 input and, by name (``SAVED_IN_LAYER``), the outputs of the expert
 block's two up-projections: the grouped ``rows W_in`` of the routed
 experts (``moe.h``; what lays its rows out goes under the same name:
-the experts chosen and each row's token, use and gate, 0.5 MB a layer)
+the experts chosen and each row's assignment and gate, 0.45 MB a layer)
 and the shared expert's ``x W_in`` (``mlp.h``).  Everything else inside
 the layer is made a second time by the backward.  These two because
 each is a large product whose result is small beside the work of making

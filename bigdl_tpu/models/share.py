@@ -7,7 +7,7 @@ layers' counters are read and put into words.
 input and, by name (``SAVED_IN_LAYER``), the outputs of the expert
 block's up-projections: the grouped ``rows W_in`` of the routed experts
 (``moe.h``; what lays its rows out goes under the same name: the
-experts chosen and each row's token, use and gate) and a shared
+experts chosen and each row's assignment and gate) and a shared
 expert's ``x W_in`` (``mlp.h``).  Everything else inside the layer is
 made a second time by the backward.  ``models/granite_moe_hybrid.py``
 says why these and no others; the policy is one algorithm with one

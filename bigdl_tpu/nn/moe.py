@@ -38,6 +38,10 @@ held experts get the rows ``offset[expert] + rank`` of ONE buffer of
 ``R`` rows, and a grouped product (``jax.lax.ragged_dot``, which the TPU
 compiler lowers to its own grouped-matmul kernel) runs over ALL ``R``
 rows: the rows past the load are zeros and belong to the last group.
+The plan is inverted ONCE: one scatter writes each assignment's index
+into its row, and each row's token, use and gate are read from that
+index (one scalar scatter a layer; a scatter a quantity, with JAX's JVP
+of the gates' scatter, took five: PERF.md, PR 38).
 ``R`` is static — ``row_factor`` times the balanced load, rounded up to
 ``ROW_TILE`` — so the device's work depends on the shapes alone and not
 on how the drawn tokens happened to route; the price is padding,
@@ -79,7 +83,8 @@ from bigdl_tpu.telemetry.scopes import device_scope
 # Names a caller's ``jax.checkpoint`` policy may save
 # (``save_only_these_names``); under no such policy a tag is the identity.
 # MOE_H names ``h`` AND what lays its rows out: the experts chosen, each
-# row's token, which rows are used, each row's gate (a few hundred KB).
+# row's assignment (its token and use are read from it elementwise) and
+# each row's gate (a few hundred KB).
 # A backward that makes the choice a second time gets another one on
 # some tokens (``ExpertParallelMoE.route``) -- with 8,192 tokens on the
 # chip the experts' gradients then came out as noise (PERF.md, PR 33).
@@ -322,26 +327,29 @@ class ExpertParallelMoE(Module):
                 *(c.reshape(N * T, -1) for c in carried))
             row, sizes, n_fit, n_over = self.plan(experts, R)
         with device_scope("moe.dispatch"):
-            # row -> its token and gate (an index scatter, T*k scalars);
-            # then ONE gather of R rows.  Rows nobody claimed keep gate 0
-            # and token 0, and are zeroed
+            # the plan inverted by ONE index scatter of T*k scalars: each
+            # row's assignment, -1 on a row nobody claimed.  Its token and
+            # use are read from it elementwise, its gate (below) by a
+            # gather of R; then ONE gather of R rows.  An unclaimed row
+            # reads token 0 and gate 0, and is zeroed
             flat = row.reshape(-1)
-            token = jnp.zeros((R,), jnp.int32).at[flat].set(
-                jnp.repeat(jnp.arange(N * T, dtype=jnp.int32), self.top_k),
-                mode="drop")
-            used = jnp.zeros((R,), bool).at[flat].set(True, mode="drop")
-            # kept with ``h``: a scatter of T*k scalars is 0.4-0.5 ms on
-            # the v5e, half of what the row gather below takes
-            token, used = (checkpoint_name(a, MOE_H) for a in (token, used))
+            slot = checkpoint_name(jnp.full((R,), -1, jnp.int32).at[flat].set(
+                jnp.arange(flat.size, dtype=jnp.int32), mode="drop"), MOE_H)
+            used = slot >= 0
+            assignment = jnp.maximum(slot, 0)
+            token = assignment // self.top_k
             xs = jnp.where(used[:, None], x[token], 0)
         with device_scope("moe.experts"):
             h = checkpoint_name(
                 jax.lax.ragged_dot(xs, params["w_in"], sizes), MOE_H)
             ys = jax.lax.ragged_dot(gated_act(h), params["w_out"], sizes)
         with device_scope("moe.combine"):
-            # a token's rows weighted by their gates and added up, in f32
-            gate = checkpoint_name(jnp.zeros((R,), jnp.float32).at[flat].set(
-                gates.reshape(-1), mode="drop"), MOE_H)     # as token, used
+            # a token's rows weighted by their gates and added up, in f32.
+            # A used row has an assignment of its own (``plan``), so the
+            # gather's backward adds each gate's cotangent once, and an
+            # unused row's exact 0
+            gate = checkpoint_name(jnp.where(
+                used, gates.reshape(-1)[assignment], 0.0), MOE_H)
             out = jnp.zeros((N * T, D), jnp.float32).at[token].add(
                 ys.astype(jnp.float32) * gate[:, None])
         # the rows each held expert computed: its group, less the padding

@@ -421,6 +421,56 @@ def test_granite_expert_layer_keeps_the_grouped_kernels(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+# a scatter whose result is ONE vector: "= s32[15360]{...} scatter("
+_VECTOR_SCATTER_RE = re.compile(r"= (\w+)\[(\d+)\]\S* scatter\(")
+
+
+@pytest.mark.parametrize("cell", ["granite", "zaya"])
+def test_expert_layout_is_one_scalar_scatter(one_chip, cell):
+    """An expert layer of each language-model cell at the cell's widths
+    and tokens, under the layer's checkpoint, forward and backward in
+    bf16: the rows' layout is ONE scatter of the assignments' indices
+    into the R rows (``s32[R]``, kept by name), and the gates' backward
+    one scatter-add of R cotangents into the T x k assignments
+    (``f32[T*k]``, the transpose of the gather of R gates).  Each row's
+    token, use and gate scattered apart, with JAX's JVP of the gates'
+    scatter, compiled to five such vectors a layer, ``s32``, ``pred``,
+    two ``u32`` and an ``f32`` ``[R]``: 38 ms of granite's step
+    (PERF.md, PR 33 and 38).  A scalar scatter brought back fails here."""
+    from benchmarks import lib
+    from bigdl_tpu.models.share import checkpointed
+    if cell == "granite":
+        moe = nn.ExpertParallelMoE(GRANITE_D, 768, 72, 10, held=(0, 9))
+        tokens = GRANITE_T
+        x = _spec(one_chip, (1, tokens, GRANITE_D), jnp.bfloat16)
+    else:
+        from bigdl_tpu.models.zaya import ZayaLayer
+        cfg = lib.load_json("configs", "zaya1-8b-share2")
+        builder = lib.load_module("builders", "zaya")
+        moe = ZayaLayer(builder.whole_config(cfg), builder.share(cfg),
+                        row_factor=cfg["train"]["row_factor"]).experts
+        tokens, D = 2 * 8192, moe.hidden_size
+        x = (_spec(one_chip, (2, 8192, D), jnp.bfloat16),
+             _spec(one_chip, (2, 8192, moe.router.width), jnp.float32))
+    rows, assignments = moe.n_rows(tokens), tokens * moe.top_k
+    assert (rows, assignments) == {"granite": (15360, 81920),
+                                   "zaya": (16384, 16384)}[cell]
+    params, state = jax.eval_shape(moe.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, jnp.bfloat16), params)
+
+    def loss(p, v):
+        y, _ = checkpointed(moe)(p, jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), state), v)
+        return sum(jnp.sum(leaf.astype(jnp.float32) ** 2)
+                   for leaf in jax.tree_util.tree_leaves(y))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    vectors = sorted((dtype, int(n))
+                     for dtype, n in _VECTOR_SCATTER_RE.findall(text))
+    assert vectors == sorted([("s32", rows), ("f32", assignments)]), vectors
+
+
 def test_granite_mamba_mixer_chunked_scan_fits(one_chip):
     mixer = nn.Mamba2Mixer(GRANITE_D, 128, 64, 128, held=(0, 16),
                            chunk_size=256)

@@ -304,11 +304,12 @@ def test_model_against_reference(share):
 # ------------------------------------- what a layer's checkpoint keeps
 # what a layer tags, by the function that tags it: the last dimension of
 # each array at CFG's sizes.  The k experts chosen; rows W_in and each
-# row's token, use and gate (R = 256 rows for these 32 tokens: one
-# ROW_TILE); x W_in of the shared expert.  Neither mixer tags anything
+# row's assignment and gate (R = 256 rows for these 32 tokens: one
+# ROW_TILE; its token and use are read from the assignment, not kept);
+# x W_in of the shared expert.  Neither mixer tags anything
 _TAGGED = {"LinearTopKRouter.route": [CFG["num_experts_per_tok"]],
            "ExpertParallelMoE.apply": [2 * CFG["intermediate_size"],
-                                       256, 256, 256],
+                                       256, 256],
            "GatedMLP.apply": [2 * CFG["shared_intermediate_size"]]}
 
 

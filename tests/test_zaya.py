@@ -25,12 +25,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu import nn, optim
 from bigdl_tpu.models import zaya
 from bigdl_tpu.models.share import checkpointed
 from bigdl_tpu.models.zaya import ZayaLayer
+from bigdl_tpu.nn.moe import MOE_H, count_add, gated_act
 from bigdl_tpu.nn.moe import count_value as count
+from bigdl_tpu.telemetry.scopes import device_scope
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -298,6 +301,106 @@ def test_linear_router_is_granites_bit_for_bit():
     np.testing.assert_array_equal(
         jax.jit(lambda p: layer.apply(p, s, x)[0])(p),
         jax.jit(lambda p: given.apply(p, s, x)[0])(p))
+
+
+class _ThreeScatterMoE(nn.ExpertParallelMoE):
+    """``ExpertParallelMoE.apply`` as PR 37 left it, word for word: each
+    row's token, use and gate scattered apart, and the three kept."""
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        input, *carried = input if isinstance(input, tuple) else (input,)
+        N, T, D = input.shape
+        x = input.reshape(N * T, D)
+        R = self.n_rows(N * T)
+        with device_scope("moe.route"):
+            gates, experts, *carried = self.router.route(
+                params["router"], state["router"], x,
+                *(c.reshape(N * T, -1) for c in carried))
+            row, sizes, n_fit, n_over = self.plan(experts, R)
+        with device_scope("moe.dispatch"):
+            flat = row.reshape(-1)
+            token = jnp.zeros((R,), jnp.int32).at[flat].set(
+                jnp.repeat(jnp.arange(N * T, dtype=jnp.int32), self.top_k),
+                mode="drop")
+            used = jnp.zeros((R,), bool).at[flat].set(True, mode="drop")
+            token, used = (checkpoint_name(a, MOE_H) for a in (token, used))
+            xs = jnp.where(used[:, None], x[token], 0)
+        with device_scope("moe.experts"):
+            h = checkpoint_name(
+                jax.lax.ragged_dot(xs, params["w_in"], sizes), MOE_H)
+            ys = jax.lax.ragged_dot(gated_act(h), params["w_out"], sizes)
+        with device_scope("moe.combine"):
+            gate = checkpoint_name(jnp.zeros((R,), jnp.float32).at[flat].set(
+                gates.reshape(-1), mode="drop"), MOE_H)     # as token, used
+            out = jnp.zeros((N * T, D), jnp.float32).at[token].add(
+                ys.astype(jnp.float32) * gate[:, None])
+        by_expert = sizes.at[-1].add(n_fit - R)
+        new_state = {"rows_held": count_add(state["rows_held"], n_fit),
+                     "rows_overflow": count_add(state["rows_overflow"],
+                                                n_over),
+                     "rows_by_expert": jax.vmap(count_add)(
+                         state["rows_by_expert"], by_expert),
+                     "router": state["router"]}
+        outs = (out.astype(input.dtype).reshape(N, T, D),
+                *(c.reshape(N, T, -1) for c in carried))
+        return (outs if len(outs) > 1 else outs[0]), new_state
+
+
+def _linear_k4_dropping():
+    """Granite's router, k = 4, experts 4-7 of 16 held, rows for half
+    their load: R = 1,024 rows for the 2,048 tokens' 2,000-odd held
+    assignments, so some find no row."""
+    args = (32, 16, 16, 4)
+    kw = dict(held=(4, 8), row_factor=0.5)
+    x = jax.random.normal(key(1), (2, 1024, 32))
+    return args, kw, x
+
+
+def _mlp_k1_carrying():
+    """ZAYA's router, k = 1, a later layer's: it reads the state the
+    previous layer handed on and hands its own on."""
+    args = (32, 16, 8, 1)
+    kw = dict(held=(2, 6), router=_router()[0])
+    x = (jax.random.normal(key(1), (2, 160, 32)),
+         jax.random.normal(key(2), (2, 160, 12)))
+    return args, kw, x
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["bare", "checkpointed"])
+@pytest.mark.parametrize("case", [_linear_k4_dropping, _mlp_k1_carrying],
+                         ids=["linear_k4_dropping", "mlp_k1_carrying"])
+def test_one_scatter_layout_is_the_three_scatters_bit_for_bit(case, remat):
+    """The rows' layout read from ONE scatter of the assignments' indices
+    gives the three scatters' outputs, every parameter's and the input's
+    gradient and the counters, to the last bit: each used row has one
+    assignment of its own, and an unused row adds an exact 0."""
+    args, kw, x = case()
+    layer = nn.ExpertParallelMoE(*args, **kw)
+    old = _ThreeScatterMoE(*args, **kw)
+    p, s = layer.init(key(0))
+    outs = jax.eval_shape(lambda: layer.apply(p, s, x)[0])
+    probes = jax.tree_util.tree_map(
+        lambda o: jax.random.normal(key(99), o.shape), outs)
+
+    def run(m):
+        apply = checkpointed(m) if remat else m.apply
+
+        def loss(p, v):
+            out, new = apply(p, s, v)
+            total = sum(jnp.sum(o * w) for o, w in zip(
+                jax.tree_util.tree_leaves(out),
+                jax.tree_util.tree_leaves(probes)))
+            return total, (out, new)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+    got, want = run(layer), run(old)
+    if case is _linear_k4_dropping:
+        assert count(want[0][1][1]["rows_overflow"]) > 0
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(g, w)
 
 
 # --------------------------------------------------------------- experts
