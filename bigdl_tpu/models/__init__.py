@@ -14,3 +14,4 @@ from bigdl_tpu.models.granite_moe_hybrid import (
     GraniteMoeHybrid, GraniteMoeHybridLayer, granite_moe_hybrid,
 )
 from bigdl_tpu.models.zaya import Zaya, ZayaLayer, zaya
+from bigdl_tpu.models.qwen3_next import Qwen3Next, Qwen3NextLayer, qwen3_next

@@ -66,9 +66,11 @@ from bigdl_tpu.nn.quantized import (
     quantize, QuantizedLinear, QuantizedSpatialConvolution,
 )
 from bigdl_tpu.nn.attention import (
-    CompressedConvAttention, GroupedQueryAttention, LayerNorm,
-    MultiHeadAttention, RMSNorm, dot_product_attention, rms_norm, rope,
+    CompressedConvAttention, GatedAttention, GroupedQueryAttention,
+    LayerNorm, MultiHeadAttention, RMSNorm, dot_product_attention, rms_norm,
+    rope, zero_centred_rms_norm,
 )
+from bigdl_tpu.nn.gated_delta import GatedDeltaNet, chunked_gated_delta_rule
 from bigdl_tpu.nn.mamba import (
     Mamba2Mixer, causal_depthwise_conv1d, gated_rms_norm, ssd_chunked_scan,
 )

@@ -611,3 +611,91 @@ def test_zaya_layers_keep_the_grouped_kernels_and_no_full_scores(
     # read 652,063,232 bytes (v5e:2x2 compile, PR 36; 967,029,760 by the
     # block loop)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
+# ------------------------- Qwen3-Next-80B-A3B: one chip's share
+QWEN_T, QWEN_D = 8192, 2048
+
+
+def _qwen_layer(kind):
+    """A layer of ``benchmarks/configs/qwen3-next-80b-a3b-ep8.json`` at
+    the published widths, as the cell's model builds it."""
+    from benchmarks import lib
+    from bigdl_tpu.models.qwen3_next import Qwen3NextLayer
+    builder = lib.load_module("builders", "qwen3_next")
+    cfg = lib.load_json("configs", "qwen3-next-80b-a3b-ep8")
+    tr = cfg["train"]
+    return builder, Qwen3NextLayer(
+        builder.whole_config(cfg), kind, builder.expert_share(cfg),
+        q_block=tr["q_block"], row_factor=tr["row_factor"])
+
+
+def _qwen_grad(module, one_chip):
+    """Forward and backward of ``module`` under the model's own layer
+    checkpoint on the cell's 8,192 tokens, in bf16."""
+    from bigdl_tpu.models.share import checkpointed
+    shapes, state = jax.eval_shape(module.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, jnp.bfloat16
+                        if a.dtype == jnp.float32 else a.dtype), shapes)
+
+    def loss(p, x):
+        y, _ = checkpointed(module)(p, jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), state), x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    return _compile(jax.grad(loss, argnums=(0, 1)), params,
+                    _spec(one_chip, (1, QWEN_T, QWEN_D), jnp.bfloat16))
+
+
+def test_qwen3_next_linear_layer_compiles_at_published_widths(one_chip):
+    """A gated delta-rule layer with its expert block (every one of the
+    32 value heads, 64 of 512 experts top-10, the gated shared expert):
+    the chunked rule's triangular solve and its scan over 128 chunks of
+    64 compile for the chip, forward, again under the checkpoint, and
+    backward; the expert block keeps granite's seven grouped kernels;
+    every kernel lies under a named scope and the four ``bigdl.gdn.*``
+    scopes name their work.  No T x T array exists: the rule holds a
+    chunk's 64 x 64 at a time."""
+    from benchmarks import hlo_scopes
+    builder, layer = _qwen_layer("linear_attention")
+    compiled = _qwen_grad(layer, one_chip)
+    text = compiled.as_text()
+    grouped = re.findall(r"= (\S+) custom-call\([^\n]*ragged_dot_tiling",
+                         text)
+    assert len(grouped) == 7
+    placed = hlo_scopes.instruction_scopes(text, builder.COMPILER_OPS)
+    assert hlo_scopes.unscoped_kernels(text, placed) == []
+    assert {"bigdl.gdn.proj", "bigdl.gdn.conv", "bigdl.gdn.scan",
+            "bigdl.gdn.norm", "bigdl.moe.shared", "bigdl.moe.experts",
+            "bigdl.moe.route"} <= set(placed.values())
+    # (the 8,192 channels of [q; k; v] at 8,192 positions are
+    # ``[1,8192,8192]``: that is the convolution's, not a score matrix)
+    assert not re.search(r"\[[0-9,]*32,8192,8192\]", text)
+    # read 3,821,590,016 bytes (v5e:2x2 compile): the 128 chunk
+    # states of 32 x 128 x 128 f32 the scan hands its backward are 0.27 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
+def test_qwen3_next_attention_takes_the_block_loop(one_chip, flash_kernel):
+    """Heads of 256 at 8,192 positions are outside the flash kernel (its
+    backward keeps a group's dk and dv whole in VMEM: 2.1 M elements
+    over its 1.05 M), so the gated attention layer takes
+    ``dot_product_attention(q_block=1024)``'s XLA block loop, and says
+    why.  A block's f32 scores are ``[2, 8, 1024, 8192]``, never the
+    whole ``8192 x 8192``."""
+    _, layer = _qwen_layer("full_attention")
+    mixer = layer.mixer
+    q = (1, mixer.heads, QWEN_T, mixer.head_dim)
+    kv = (1, mixer.kv_heads, QWEN_T, mixer.head_dim)
+    assert pallas_attention.attention_path(
+        q, kv, jnp.bfloat16, True, None, mixer.q_block) == \
+        ("xla", "sequence past the backward's VMEM")
+    compiled = _qwen_grad(mixer, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[2,8,1024,8192\]", text)
+    assert not re.search(r"f32\[[0-9,]*8192,8192\]", text)
+    # read 1,508,624,896 bytes (v5e:2x2 compile): a block's f32
+    # scores are 0.54 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
