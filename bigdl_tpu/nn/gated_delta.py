@@ -31,11 +31,14 @@ under the diagonal, so it never exceeds 1.  Everything is f32 at the
 highest precision, whatever the layer's compute dtype (a decay is a
 product of up to Q factors below one, and the solve divides nothing but
 compounds its rows); the backward is autodiff's, the triangular solve's
-included.
+included.  On a TPU the same equations run through one Pallas kernel
+over the chunks (``ops/pallas_delta_rule.py``), the solve left to XLA.
 """
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 from typing import Optional
 
@@ -46,6 +49,8 @@ from bigdl_tpu.nn.attention import rms_norm
 from bigdl_tpu.nn.mamba import causal_depthwise_conv1d
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.telemetry.scopes import device_scope
+
+logger = logging.getLogger("bigdl_tpu.nn")
 
 _HI = jax.lax.Precision.HIGHEST
 # the unit length of queries and keys: x / sqrt(sum x^2 + L2_EPS)
@@ -59,11 +64,26 @@ CHUNK = 64
 def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int):
     """The gated delta rule, chunked (module docstring).
 
-    ``q``, ``k``: (N, T, H, K) per value head, already of unit length and
-    scaled as the caller wants; ``v``: (N, T, H, V); ``g``: (N, T, H),
-    the log-decay (<= 0); ``beta``: (N, T, H).  Returns ``o`` (N, T, H,
-    V) in f32.  T is padded up to a multiple of ``chunk`` with steps of
-    ``g = 0`` and ``beta = 0``, which leave the state as it is."""
+    ``q``, ``k``: (N, T, Hk, K) per KEY head, already of unit length and
+    scaled as the caller wants; ``v``: (N, T, H, V), value head ``h``
+    reading key head ``h // (H / Hk)``; ``g``: (N, T, H), the log-decay
+    (<= 0); ``beta``: (N, T, H).  Returns ``o`` (N, T, H, V) in f32.  T
+    is padded up to a multiple of ``chunk`` with steps of ``g = 0`` and
+    ``beta = 0``, which leave the state as it is.
+
+    One algorithm in the form the arguments allow
+    (``ops.pallas_delta_rule.delta_rule_path`` decides, statically, and
+    says why): on a TPU, heads of whole 128-lane groups, the Pallas
+    kernel, each chunk's work in VMEM; otherwise the vectorised form
+    below, one ``lax.scan`` over the chunks."""
+    from bigdl_tpu.ops import pallas_delta_rule
+    path, why = pallas_delta_rule.delta_rule_path(k.shape, v.shape, v.dtype,
+                                                  chunk)
+    _log_path(path, why)
+    if path == "kernel":
+        return pallas_delta_rule.gated_delta_rule(q, k, v, g, beta, chunk)
+    r = v.shape[2] // k.shape[2]
+    q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
     N, T, H, K = q.shape
     V = v.shape[-1]
     f32 = jnp.float32
@@ -123,6 +143,12 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int):
     return o[:, :T]
 
 
+@functools.lru_cache(maxsize=None)
+def _log_path(path: str, why: str):
+    """Say once a process which form the rule took, and why."""
+    logger.info("gated delta rule: %s (%s)", path, why)
+
+
 def unit_length(x, eps: float = L2_EPS):
     """``x * rsqrt(sum(x^2) + eps)`` over the last dim, in f32."""
     x = x.astype(jnp.float32)
@@ -147,7 +173,7 @@ class GatedDeltaNet(Module):
         b, a       = split(x in_proj_ba)       per key head [b | a]
         q, k, v    = silu(conv1d_causal_depthwise([q; k; v]))   no bias
         beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)   f32
-        q, k = unit_length(q) / sqrt(key_dim), unit_length(k)  (repeated)
+        q, k = unit_length(q) / sqrt(key_dim), unit_length(k)
         o    = gated delta rule (q, k, v, g, beta)  per value head
         out  = (rms(o) * norm_w * silu(z)) out_proj     per value head
 
@@ -214,9 +240,8 @@ class GatedDeltaNet(Module):
             beta = jax.nn.sigmoid(b.astype(f32))
             g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
                 a.astype(f32) + params["dt_bias"].astype(f32))
-            q = jnp.repeat(unit_length(q), r, axis=2) / math.sqrt(K)
-            k = jnp.repeat(unit_length(k), r, axis=2)
-            o = chunked_gated_delta_rule(q, k, v, g, beta, CHUNK)
+            o = chunked_gated_delta_rule(unit_length(q) / math.sqrt(K),
+                                         unit_length(k), v, g, beta, CHUNK)
         with device_scope("gdn.norm"):
             o = normed_then_gated(o, z, params["norm_w"], self.eps)
             o = o.astype(input.dtype).reshape(N, T, Hv * V)

@@ -79,9 +79,9 @@ def _assert_kernel(compiled, n=1):
 
 @pytest.fixture
 def flash_kernel(monkeypatch):
-    """``dot_product_attention`` asks the backend (the CPU here) whether
-    a kernel runs and whether it is interpreted: steer both in the test,
-    the program grows no option for this."""
+    """``dot_product_attention`` and the gated delta rule ask the backend
+    (the CPU here) whether a kernel runs and whether it is interpreted:
+    steer both in the test, the program grows no option for this."""
     monkeypatch.setattr(pallas_util, "interpret_default", lambda: False)
     prev = Engine._state.kernel_impl
     Engine.set_kernel_impl("pallas")
@@ -648,17 +648,26 @@ def _qwen_grad(module, one_chip):
                     _spec(one_chip, (1, QWEN_T, QWEN_D), jnp.bfloat16))
 
 
-def test_qwen3_next_linear_layer_compiles_at_published_widths(one_chip):
+def test_qwen3_next_linear_layer_compiles_at_published_widths(
+        one_chip, flash_kernel):
     """A gated delta-rule layer with its expert block (every one of the
     32 value heads, 64 of 512 experts top-10, the gated shared expert):
-    the chunked rule's triangular solve and its scan over 128 chunks of
-    64 compile for the chip, forward, again under the checkpoint, and
-    backward; the expert block keeps granite's seven grouped kernels;
-    every kernel lies under a named scope and the four ``bigdl.gdn.*``
-    scopes name their work.  No T x T array exists: the rule holds a
-    chunk's 64 x 64 at a time."""
+    the chunked rule's triangular solves and its Pallas kernel over 128
+    chunks of 64 compile for the chip, forward, again under the
+    checkpoint, and backward, all three under ``bigdl.gdn.scan``, with
+    no loop over the chunks and no stack of the chunks' states but the
+    kernel's own; the expert block keeps granite's seven grouped
+    kernels; every kernel lies under a named scope and the four
+    ``bigdl.gdn.*`` scopes name their work.  No T x T array exists: the
+    rule holds a chunk's 64 x 64 at a time."""
     from benchmarks import hlo_scopes
+    from bigdl_tpu.ops import pallas_delta_rule
     builder, layer = _qwen_layer("linear_attention")
+    mixer = layer.mixer
+    assert pallas_delta_rule.delta_rule_path(
+        (1, QWEN_T, mixer.key_heads, mixer.key_dim),
+        (1, QWEN_T, mixer.value_heads, mixer.value_dim), jnp.bfloat16,
+        64)[0] == "kernel"
     compiled = _qwen_grad(layer, one_chip)
     text = compiled.as_text()
     grouped = re.findall(r"= (\S+) custom-call\([^\n]*ragged_dot_tiling",
@@ -669,12 +678,19 @@ def test_qwen3_next_linear_layer_compiles_at_published_widths(one_chip):
     assert {"bigdl.gdn.proj", "bigdl.gdn.conv", "bigdl.gdn.scan",
             "bigdl.gdn.norm", "bigdl.moe.shared", "bigdl.moe.experts",
             "bigdl.moe.route"} <= set(placed.values())
+    assert len(_kernels_under(text, "bigdl.gdn.scan",
+                              builder.COMPILER_OPS)) == 3
+    assert not re.search(r"\swhile\(", text)
+    # the XLA form's scan stacked the 128 chunks' entering states chunks
+    # first; the kernel writes them (N, Hv, c, K, V), once
+    assert not re.search(r"f32\[128,1,32,128,128\]", text)
     # (the 8,192 channels of [q; k; v] at 8,192 positions are
     # ``[1,8192,8192]``: that is the convolution's, not a score matrix)
     assert not re.search(r"\[[0-9,]*32,8192,8192\]", text)
-    # read 3,821,590,016 bytes (v5e:2x2 compile): the 128 chunk
-    # states of 32 x 128 x 128 f32 the scan hands its backward are 0.27 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+    # read 2,805,732,864 bytes (v5e:2x2 compile; 3,821,590,016 by the
+    # XLA form's scan): the 128 chunk states of 32 x 128 x 128 f32 the
+    # forward kernel hands the backward are 0.27 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
 
 
 def test_qwen3_next_attention_takes_the_block_loop(one_chip, flash_kernel):

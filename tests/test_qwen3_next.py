@@ -92,38 +92,91 @@ def test_layer_types_follow_the_interval():
 
 
 # ------------------------------------------------------ the delta rule
-def _rule_inputs(decay):
-    N, T, H, K, V = 2, 37, 3, 8, 6
+# (N, T, key heads, value heads, K, V): the XLA form's small size, and
+# the kernel's: two value heads on one key head, heads of 128 lanes, four
+# chunks of 64 and a padded tail of 5
+SIZES = {"small": (2, 37, 3, 3, 8, 6), "aligned": (1, 4 * 64 + 5, 1, 2, 128,
+                                                   128)}
+
+
+def _rule_inputs(decay, size="small"):
+    """``equal_keys``: one key at every step, ``beta`` 1 and ``g`` 0, so
+    ``A`` is all ones under the diagonal: the case in which a doubling
+    product form of ``(I + A)^{-1}`` is off by 1e9 in f32 and a
+    substitution is not."""
+    N, T, Hk, H, K, V = SIZES[size]
     ks = jax.random.split(key(0), 5)
-    g = {"none": jnp.zeros((N, T, H)),
+    g = {"none": jnp.zeros((N, T, H)), "equal_keys": jnp.zeros((N, T, H)),
          "drawn": -jax.nn.softplus(jax.random.normal(ks[4], (N, T, H))),
          "total": jnp.full((N, T, H), -30.0)}[decay]
-    return (unit_length(jax.random.normal(ks[0], (N, T, H, K))) / np.sqrt(K),
-            unit_length(jax.random.normal(ks[1], (N, T, H, K))),
-            jax.random.normal(ks[2], (N, T, H, V)), g,
-            jax.random.uniform(ks[3], (N, T, H)))
+    k = unit_length(jax.random.normal(ks[1], (N, T, Hk, K)))
+    beta = jax.random.uniform(ks[3], (N, T, H))
+    if decay == "equal_keys":
+        k, beta = jnp.broadcast_to(k[:, :1], k.shape), jnp.ones_like(beta)
+    return (unit_length(jax.random.normal(ks[0], (N, T, Hk, K))) / np.sqrt(K),
+            k, jax.random.normal(ks[2], (N, T, H, V)), g, beta)
 
 
-@pytest.mark.parametrize("chunk", [4, 16])
-@pytest.mark.parametrize("decay", ["none", "drawn", "total"])
-def test_chunked_rule_against_the_recurrence(chunk, decay):
-    """T = 37 is no multiple of either chunk.  ``g = 0`` keeps every
+def _per_value_head(rule):
+    """``rule`` on ``q`` and ``k`` repeated to the value heads, as the
+    reference takes them."""
+    def run(q, k, v, g, beta):
+        r = v.shape[2] // k.shape[2]
+        return rule(jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v,
+                    g, beta)
+    return run
+
+
+@pytest.fixture
+def kernel_impl():
+    """``Engine.set_kernel_impl`` for one test, the backend's before and
+    after it."""
+    from bigdl_tpu.engine import Engine
+    prev = Engine._state.kernel_impl
+    yield Engine.set_kernel_impl
+    Engine._state.kernel_impl = prev
+
+
+def _out_and_grads(rule, args, probe):
+    return (jax.jit(rule)(*args),) + tuple(jax.jit(jax.grad(
+        lambda *a: jnp.sum(rule(*a) * probe), argnums=(0, 1, 2, 3, 4)))(*args))
+
+
+@pytest.mark.parametrize("chunk", [4, 16, 64])
+@pytest.mark.parametrize("decay", ["none", "drawn", "total", "equal_keys"])
+def test_chunked_rule_against_the_recurrence(chunk, decay, kernel_impl):
+    """T = 37 is no multiple of chunks of 4 or 16.  ``g = 0`` keeps every
     state whole (the triangular system is widest); ``g = -30`` forgets
-    all but the last step (every decay across a step is 1e-13)."""
-    args = _rule_inputs(decay)
-    got = jax.jit(functools.partial(chunked_gated_delta_rule,
-                                    chunk=chunk))(*args)
-    want = jax.jit(ref.delta_rule)(*args)
-    assert got.shape == want.shape and got.dtype == jnp.float32
-    close(got, want)
-    probe = jax.random.normal(key(9), want.shape)
-    grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * probe),
-                              argnums=(0, 1, 2, 3, 4)))(*args)
-             for f in (functools.partial(chunked_gated_delta_rule,
-                                         chunk=chunk), ref.delta_rule)]
+    all but the last step (every decay across a step is 1e-13).  At
+    chunks of 64 the size is the kernel's (``SIZES["aligned"]``): the
+    Pallas kernel, interpreted here, against the recurrence and against
+    the XLA form, in values and in all five inputs' gradients."""
+    from bigdl_tpu.ops import pallas_delta_rule
+    size = "aligned" if chunk == 64 else "small"
+    args = _rule_inputs(decay, size)
+    probe = jax.random.normal(key(9), args[2].shape)
+    rule = lambda *a: chunked_gated_delta_rule(*a, chunk=chunk)  # noqa
+    kernel_impl("pallas")
+    assert pallas_delta_rule.delta_rule_path(
+        args[1].shape, args[2].shape, args[2].dtype, chunk)[0] == \
+        ("kernel" if size == "aligned" else "xla")
+    got = _out_and_grads(rule, args, probe)
+    assert got[0].shape == args[2].shape and got[0].dtype == jnp.float32
+    want = _out_and_grads(_per_value_head(ref.delta_rule), args, probe)
     # atol: under total decay the true gradient of g is 6e-14, what the
-    # chunk's O(1) terms cancel to; the chunked form reads their rounding
-    close(grads[0], grads[1], atol=1e-9)
+    # chunk's O(1) terms cancel to; the chunked form reads their
+    # rounding.  Under equal keys each step's state is k v_t^T whatever
+    # g was, so the gradient of g is 0, read as the rounding of terms of
+    # up to 11 (2.9e-6 by the recurrence, 5.7e-6 off it by the kernel)
+    atol = 2e-5 if decay == "equal_keys" else 1e-9
+    close(got[0], want[0])
+    close(got[1:], want[1:], atol=atol)
+    if size == "aligned":
+        kernel_impl("xla")
+        xla = _out_and_grads(
+            lambda *a: chunked_gated_delta_rule(*a, chunk=chunk), args, probe)
+        close(got[0], xla[0])
+        close(got[1:], xla[1:], atol=atol)
 
 
 def test_padded_steps_leave_the_state_as_it_is():
@@ -133,6 +186,40 @@ def test_padded_steps_leave_the_state_as_it_is():
     whole = chunked_gated_delta_rule(q, k, v, g, beta, chunk=37)
     padded = chunked_gated_delta_rule(q, k, v, g, beta, chunk=8)
     close(padded, whole)
+
+
+# reason: (k shape, v shape, v dtype, chunk, kernel_impl); beside the
+# kernel's own (1, 128, 1, 128) keys on (1, 128, 2, 128) values, f32, 64
+DELTA_RULE_XLA = {
+    "shapes": ((1, 128, 1, 128), (1, 64, 2, 128), jnp.float32, 64,
+               "pallas"),
+    "value heads not a multiple of key heads": (
+        (1, 128, 2, 128), (1, 128, 3, 128), jnp.float32, 64, "pallas"),
+    "value heads a key head": ((1, 128, 1, 128), (1, 128, 16, 128),
+                               jnp.float32, 64, "pallas"),
+    "narrow head": ((1, 128, 1, 64), (1, 128, 2, 128), jnp.float32, 64,
+                    "pallas"),
+    "chunk not a multiple of 16": ((1, 128, 1, 128), (1, 128, 2, 128),
+                                   jnp.float32, 24, "pallas"),
+    "dtype": ((1, 128, 1, 128), (1, 128, 2, 128), jnp.float16, 64,
+              "pallas"),
+    "kernel_impl xla": ((1, 128, 1, 128), (1, 128, 2, 128), jnp.float32,
+                        64, "xla"),
+}
+
+
+@pytest.mark.parametrize("reason", sorted(DELTA_RULE_XLA))
+def test_delta_rule_path_says_why_it_takes_xla(reason, kernel_impl):
+    """Every reason ``delta_rule_path`` gives for the XLA form; the
+    kernel's own shapes in bf16 take the kernel."""
+    from bigdl_tpu.ops import pallas_delta_rule
+    k_shape, v_shape, dtype, chunk, impl = DELTA_RULE_XLA[reason]
+    kernel_impl(impl)
+    assert pallas_delta_rule.delta_rule_path(
+        k_shape, v_shape, dtype, chunk) == ("xla", reason)
+    kernel_impl("pallas")
+    assert pallas_delta_rule.delta_rule_path(
+        (1, 128, 1, 128), (1, 128, 2, 128), jnp.bfloat16, 64)[0] == "kernel"
 
 
 def _gdn():
