@@ -15,3 +15,4 @@ from bigdl_tpu.models.granite_moe_hybrid import (
 )
 from bigdl_tpu.models.zaya import Zaya, ZayaLayer, zaya
 from bigdl_tpu.models.qwen3_next import Qwen3Next, Qwen3NextLayer, qwen3_next
+from bigdl_tpu.models.ling_flash import LingFlash, LingFlashLayer, ling_flash

@@ -105,3 +105,6 @@ from bigdl_tpu.nn.tensor_extras import (
     GaussianDropout, GaussianNoise, GaussianSampler, L1Penalty,
     NegativeEntropyPenalty, ActivityRegularization, BinaryThreshold,
 )
+from bigdl_tpu.nn.kda import KimiDeltaAttention, chunked_kda_rule
+from bigdl_tpu.nn.mla import MultiHeadLatentAttention
+from bigdl_tpu.nn.moe import GroupLimitedSigmoidRouter

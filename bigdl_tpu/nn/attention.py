@@ -95,7 +95,7 @@ def _attend(q, k, v, mask, *, causal: bool, scale: float, q_pos0: int):
         mask = jnp.broadcast_to(mask, (N, H, Tq, Tk)).reshape(scores.shape)
         scores = jnp.where(mask, scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("ngrqk,ngkd->ngrqd", w, v).reshape(N, H, Tq, Dh)
+    return jnp.einsum("ngrqk,ngkd->ngrqd", w, v).reshape(N, H, Tq, -1)
 
 
 def dot_product_attention(q, k, v, *, causal: bool = False,

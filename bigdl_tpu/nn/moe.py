@@ -376,3 +376,96 @@ class ExpertParallelMoE(Module):
                 f"to the held experts found no row and read 0 "
                 f"(row_factor {self.row_factor}): the layer was not "
                 f"dropless in this run; raise row_factor"]
+
+
+# the expert bias's update speed (DeepSeek-V3's)
+BIAS_RATE = 1e-3
+
+
+class GroupLimitedSigmoidRouter(Module):
+    """Ling-3.0-flash's router (DeepSeek-V3's, arXiv:2412.19437, section
+    2.1.2): sigmoid scores over all experts, a bias that only the CHOICE
+    reads, the choice limited to the best groups of experts::
+
+        s    = sigmoid(x W_r)                  over ALL experts, f32
+        c    = s + bias                        for the choice only
+        keep the ``top_groups`` best of ``n_groups`` groups of experts,
+             a group ranked by the sum of its two best c
+        e    = top_k(c) inside the kept groups
+        gate = scale * s[e] / (sum s[e] + 1e-20)
+
+    ``bias`` (state, zeros at the start) is moved OUTSIDE the gradient
+    by the auxiliary-loss-free rule, ``bias += BIAS_RATE * sign(mean
+    load - load_e)`` from one step's count of the tokens each expert took
+    (:meth:`after_step`; DeepSeek-V3's rate, which Ling-3.0-flash's
+    configuration does not give).
+    The router hands its choice out beside the expert layer's output (the
+    ``(out, carried)`` form of :class:`ExpertParallelMoE`): the layer
+    that owns the state counts it and calls :meth:`after_step`, as a
+    training step ends.  Its parameters are the bare (hidden, n_experts)
+    matrix, drawn N(0, 0.02)."""
+
+    def __init__(self, hidden_size: int, n_experts: int, top_k: int, *,
+                 n_groups: int, top_groups: int, scale: float,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        if n_experts % n_groups or not 0 < top_groups <= n_groups:
+            raise ValueError(f"{n_experts} experts in {n_groups} groups, "
+                             f"{top_groups} kept: not a partition")
+        if top_k > top_groups * (n_experts // n_groups):
+            raise ValueError(f"top {top_k} of {top_groups} groups of "
+                             f"{n_experts // n_groups}")
+        self.hidden_size, self.n_experts, self.top_k = hidden_size, \
+            n_experts, top_k
+        self.n_groups, self.top_groups = n_groups, top_groups
+        self.scale = scale
+
+    def init(self, rng):
+        D, E = self.hidden_size, self.n_experts
+        return 0.02 * jax.random.normal(rng, (D, E), jnp.float32), \
+            {"bias": jnp.zeros((E,), jnp.float32)}
+
+    def choose(self, scores, bias):
+        """The experts (T, k) int32 for sigmoid ``scores`` (T, E) under
+        ``bias`` (E,): the kept groups by their two best biased scores,
+        then the top k of those groups (a tie goes to the lower index)."""
+        T, E = scores.shape
+        c = scores + jax.lax.stop_gradient(bias)
+        grouped = c.reshape(T, self.n_groups, E // self.n_groups)
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)   # (T, G)
+        _, kept = jax.lax.top_k(best, self.top_groups)
+        keep = jnp.any(kept[..., None] == jnp.arange(self.n_groups), axis=1)
+        c = jnp.where(jnp.repeat(keep, E // self.n_groups, axis=1), c,
+                      -jnp.inf)
+        return jax.lax.top_k(c, self.top_k)[1]
+
+    def route(self, router, state, x):
+        """``(gates (T, k) f32, experts (T, k) int32, experts)`` of
+        tokens ``x`` (T, D): the scores in f32 at the highest precision
+        (a tie broken the other way is another expert); the choice
+        twice, the second time to be handed out."""
+        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                        router.astype(jnp.float32),
+                                        precision=_HI))
+        experts = checkpoint_name(self.choose(scores, state["bias"]), MOE_H)
+        chosen = _read_at(scores, experts)
+        gates = self.scale * chosen / (jnp.sum(chosen, axis=-1,
+                                               keepdims=True) + 1e-20)
+        return gates, experts, experts
+
+    def after_step(self, state, experts):
+        """The state after one training step whose choice was
+        ``experts`` (any shape, ids of all ``n_experts``)."""
+        load = jnp.zeros((self.n_experts,), jnp.float32).at[
+            experts.reshape(-1)].add(1.0)
+        mean = experts.size / self.n_experts
+        return {"bias": state["bias"] + BIAS_RATE * jnp.sign(mean - load)}
+
+    def state_warnings(self, state) -> list:
+        """A bias that has run to 1 or further, either way: next to
+        sigmoid scores in (0, 1) it decides the choice alone."""
+        far = float(jnp.max(jnp.abs(state["bias"])))
+        if far < 1.0:
+            return []
+        return [f"{self.name}: an expert bias of {far:.3g}: the bias, "
+                f"not the scores, chooses the experts"]

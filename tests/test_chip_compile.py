@@ -715,3 +715,93 @@ def test_qwen3_next_attention_takes_the_block_loop(one_chip, flash_kernel):
     # read 1,508,624,896 bytes (v5e:2x2 compile): a block's f32
     # scores are 0.54 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
+# ------------------------- Ling-3.0-flash: one chip's share
+LING_D = 2560
+
+
+def _ling_layer(index):
+    """Published layer ``index`` of
+    ``benchmarks/configs/ling-3.0-flash-vl-ep32.json`` at the published
+    widths, as the cell's model builds it."""
+    from benchmarks import lib
+    from bigdl_tpu.models.ling_flash import LingFlashLayer
+    builder = lib.load_module("builders", "ling_flash")
+    cfg = lib.load_json("configs", "ling-3.0-flash-vl-ep32")
+    tr = cfg["train"]
+    return builder, LingFlashLayer(
+        builder.whole_config(cfg), index, builder.expert_share(cfg),
+        q_block=tr["q_block"], row_factor=tr["row_factor"])
+
+
+def _ling_grad(module, one_chip):
+    """Forward and backward of ``module`` under the model's own layer
+    checkpoint, in training (the bias moves), on the cell's 8,192
+    tokens in bf16."""
+    from bigdl_tpu.models.share import checkpointed
+    shapes, state = jax.eval_shape(module.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, jnp.bfloat16
+                        if a.dtype == jnp.float32 else a.dtype), shapes)
+
+    def loss(p, x):
+        y, _ = checkpointed(module, True)(p, jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), state), x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    return _compile(jax.grad(loss, argnums=(0, 1)), params,
+                    _spec(one_chip, (1, QWEN_T, LING_D), jnp.bfloat16))
+
+
+def test_ling_kda_layer_compiles_at_published_widths(one_chip,
+                                                     flash_kernel):
+    """A Kimi delta-attention layer with its expert block (32 heads of
+    128, a decay a key channel; 16 of 512 experts top-8 behind the
+    group-limited router, the shared expert): the chunked rule's blocks
+    of 16 and its triangular solves compile for the chip, in XLA (no
+    kernel takes a vector decay), under ``bigdl.kda.scan``; the expert
+    block keeps granite's seven grouped kernels; every kernel lies under
+    a named scope and the five ``bigdl.kda.*`` scopes name their work.
+    No T x T array exists: a chunk's 64 x 64 at a time."""
+    from benchmarks import hlo_scopes
+    builder, layer = _ling_layer(2)
+    assert (layer.kind, layer.dense) == ("kda", False)
+    compiled = _ling_grad(layer, one_chip)
+    text = compiled.as_text()
+    grouped = re.findall(r"= (\S+) custom-call\([^\n]*ragged_dot_tiling",
+                         text)
+    assert len(grouped) == 7
+    placed = hlo_scopes.instruction_scopes(text, builder.COMPILER_OPS)
+    assert hlo_scopes.unscoped_kernels(text, placed) == []
+    assert _kernels_under(text, "bigdl.kda.scan", builder.COMPILER_OPS) == []
+    assert {"bigdl.kda.project", "bigdl.kda.conv", "bigdl.kda.gate",
+            "bigdl.kda.scan", "bigdl.kda.norm", "bigdl.moe.shared",
+            "bigdl.moe.experts", "bigdl.moe.route"} <= set(placed.values())
+    assert re.search(r"f32\[[0-9,]*64,64\]", text)
+    assert not re.search(r"\[[0-9,]*8192,8192\]", text)
+    # read 6.31 GB (v5e:2x2 compile; 7.98 GB with the blocks' factors
+    # kept for the backward)
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
+
+
+def test_ling_mla_takes_the_block_loop(one_chip, flash_kernel):
+    """Keys of 192 channels and values of 128: no flash kernel, and
+    ``attention_path`` is handed no single dtype for them, so the layer
+    takes ``dot_product_attention(q_block=512)``'s XLA block loop.  A
+    block's f32 scores are 32 heads x 512 x 8,192, never the whole
+    ``8192 x 8192``."""
+    _, layer = _ling_layer(5)
+    mixer = layer.mixer
+    assert (layer.kind, mixer.q_block) == ("mla", 512)
+    q = (1, mixer.heads, QWEN_T, mixer.qk_dim)
+    assert pallas_attention.attention_path(
+        q, q, None, True, None, mixer.q_block) == ("xla", "dtype")
+    compiled = _ling_grad(mixer, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[[0-9,]*32,[0-9,]*512,8192\]", text)
+    assert not re.search(r"f32\[[0-9,]*8192,8192\]", text)
+    # read 5,309,977,088 bytes (v5e:2x2 compile): a block's f32 scores
+    # are 0.54 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.0e9
